@@ -45,8 +45,8 @@ from __future__ import annotations
 import os
 
 # One XLA host device per core so the sweep planner can shard lane groups,
-# and the legacy (non-thunk) CPU runtime (see repro.xla_env).  MUST run
-# before any jax import: jax locks these on first init.
+# and JAX's persistent compile cache placed (see repro.xla_env).  MUST run
+# before any jax import: jax reads these on first init.
 from repro.xla_env import configure as _configure_xla
 
 _configure_xla()
@@ -383,7 +383,10 @@ def tab4_overheads(csv_dir):
     assert abs(link_area_total - 0.44) < 0.01  # matches the paper's §6.6
 
 
-def sec31_example(csv_dir):
+def sec31_example(csv_dir=None):
+    """The §3.1 two-read example; returns the two completion times in
+    ticks (same channel, different channels).  ``csv_dir=None`` writes no
+    CSV."""
     from repro.ssd import simulate
 
     cfg = perf_optimized(bus_protocol_ovh_ns=0.0, chan_gbps=1.024)
@@ -399,14 +402,17 @@ def sec31_example(csv_dir):
             "req": np.arange(n, dtype=np.int64),
         }
 
-    conflict = simulate(cfg, mk([0, 2]), "baseline").exec_ticks / 100
-    free = simulate(cfg, mk([0, 16]), "baseline").exec_ticks / 100
+    ticks = (simulate(cfg, mk([0, 2]), "baseline").exec_ticks,
+             simulate(cfg, mk([0, 16]), "baseline").exec_ticks)
+    conflict, free = (t / 100 for t in ticks)
     print(f"[sec3.1] same-channel two reads: {conflict:.2f}us (paper 11.01)")
     print(f"[sec3.1] diff-channel two reads: {free:.2f}us (paper 7.01)")
-    _rows_to_csv(os.path.join(csv_dir, "sec31_example.csv"),
-                 ["case", "us", "paper_us"],
-                 [["same_channel", f"{conflict:.2f}", 11.01],
-                  ["different_channels", f"{free:.2f}", 7.01]])
+    if csv_dir is not None:
+        _rows_to_csv(os.path.join(csv_dir, "sec31_example.csv"),
+                     ["case", "us", "paper_us"],
+                     [["same_channel", f"{conflict:.2f}", 11.01],
+                      ["different_channels", f"{free:.2f}", 7.01]])
+    return ticks
 
 
 def _parse_designs(arg: str | None):

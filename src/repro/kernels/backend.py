@@ -24,13 +24,21 @@ def default_interpret(override: bool | None = None) -> bool:
 
     Priority: explicit ``override`` > ``REPRO_PALLAS_INTERPRET`` env var
     ("0"/"1") > the actual JAX backend (interpret everywhere except a real
-    accelerator).
+    accelerator).  Interpret mode on an accelerator is refused, whichever
+    way it is asked for: it would run the kernel's math as plain XLA ops
+    and hide the kernel from the device.
     """
-    if override is not None:
-        return bool(override)
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
     import jax
 
-    return jax.default_backend() not in _ACCELERATORS
+    on_accel = jax.default_backend() in _ACCELERATORS
+    if override is None:
+        env = os.environ.get("REPRO_PALLAS_INTERPRET")
+        if env is not None and env != "":
+            override = env not in ("0", "false", "False")
+    if override is None:
+        return not on_accel
+    if override and on_accel:
+        raise ValueError(
+            "Pallas interpret mode requested on a "
+            f"{jax.default_backend()} device; kernels run compiled there")
+    return bool(override)

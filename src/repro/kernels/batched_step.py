@@ -4,8 +4,8 @@ The batched runner in ``ssd.sim`` scans a per-tick step function
 ``step(sp, state, xs) -> (state', out)`` over time-major transaction
 tables, where every pytree leaf carries the lane batch ``B`` as its
 leading axis and all math is per-lane (element-wise plus reductions over
-trailing axes only — the one-hot/bit-unpack lookups from
-``kernels.onehot`` replace every gather).  That shape is exactly a Pallas
+trailing axes only — one-hot lookups and shift-unpacked mask words
+replace every gather).  That shape is exactly a Pallas
 grid program: tile the lane axis over the grid, hand each program
 instance a ``(b_tile, ...)`` block of every operand (scalars, carried
 state, and the pre-gathered bit-packed node tables from
@@ -34,19 +34,41 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.backend import default_interpret
 
-# Default lane tile.  The step math is purely per-lane, so any tiling of
+# Largest lane tile.  The step math is purely per-lane, so any tiling of
 # the batch axis is value-identical; 256 lanes keeps the per-instance
 # working set (state + one tick of tables) comfortably inside VMEM-scale
 # scratch for every geometry in the registry.
 B_TILE = 256
 
 
+def lane_tile(B: int) -> int:
+    """The largest power-of-two lane tile of at most ``B_TILE`` and at
+    least 8 rows (the TPU sublane count) that divides ``B``; ``B`` itself
+    — a grid of 1, still a valid (and bit-exact) layout — when none does.
+    The occupancy planner pads its batches to a multiple of 8."""
+    bt = B_TILE
+    while bt >= 8:
+        if B % bt == 0:
+            return bt
+        bt //= 2
+    return B
+
+
 def _pick_tile(B: int, b_tile: int | None) -> int:
-    if b_tile is not None and b_tile > 0 and B % b_tile == 0:
+    if b_tile is None:
+        return lane_tile(B)
+    if b_tile > 0 and B % b_tile == 0:
         return b_tile
-    if b_tile is None and B % B_TILE == 0:
-        return B_TILE
-    return B  # grid of 1 — still a valid (and bit-exact) layout
+    return B
+
+
+def lanes_2d(tree):
+    """Lane-major 2D int32 view of a pytree whose leaves lead with the
+    lane axis: ``[B, ...] -> [B, prod(...)]`` (``[B] -> [B, 1]``), bools
+    as 0/1.  Per-lane scalars become columns and no block has rank 1 or
+    3+ — the layouts the TPU kernel compiler lowers."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape(a.shape[0], -1).astype(jnp.int32), tree)
 
 
 def lane_tiled_step(step_fn, *, b_tile: int | None = None,
@@ -55,7 +77,8 @@ def lane_tiled_step(step_fn, *, b_tile: int | None = None,
     ``pl.pallas_call``.
 
     Every leaf of ``(sp, state, xs)`` and of the result must carry the
-    lane batch as its leading axis.  ``interpret=None`` resolves via
+    lane batch as its leading axis; for the TPU compiler, in the
+    :func:`lanes_2d` form.  ``interpret=None`` resolves via
     :func:`repro.kernels.backend.default_interpret`.
     """
     interp = default_interpret(interpret)

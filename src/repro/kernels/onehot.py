@@ -9,15 +9,16 @@ pure elementwise/reduce work (VPU-friendly, no scatter/gather kernels),
 and it is *exact*: precisely one slot of the one-hot is set, so the integer
 sum returns that slot's value bit-for-bit.
 
-These helpers are the building blocks of the batched small-lane runner in
-``repro.ssd.sim`` (``_make_batched_static_step``); the Pallas kernel keeps
-its own fused formulation (its value is layout/tiling, see its docstring).
+These helpers are the building blocks of the batched scout runner in
+``repro.ssd.sim`` (``_make_batched_scout_step``); the Pallas kernels keep
+their own column-form formulations, the layouts the TPU compiler lowers.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["onehot", "take", "unpack_bits"]
+__all__ = ["argmin", "onehot", "take"]
 
 
 def onehot(idx, size: int):
@@ -37,11 +38,14 @@ def take(table, idx):
     return jnp.sum(jnp.where(sel, table, 0), axis=1)
 
 
-def unpack_bits(words, nbits: int):
-    """bool [..., nbits] from little-endian packed bytes [..., W].
+def argmin(x, keepdims: bool = False):
+    """``jnp.argmin(x, axis=1)`` for an integer [B, K] array, as int32.
 
-    Inverse of ``np.packbits(..., axis=-1, bitorder="little")`` for
-    ``W = ceil(nbits / 8)``.
+    Min, compare, then the min of a masked iota: the first occurrence, as
+    ``jnp.argmin`` picks, without the index reduction Mosaic lowers only
+    for float32.
     """
-    bits = (words[..., None].astype(jnp.int32) >> jnp.arange(8)) & 1
-    return bits.reshape(words.shape[:-1] + (-1,))[..., :nbits].astype(bool)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    first = x == jnp.min(x, axis=1, keepdims=True)
+    return jnp.min(jnp.where(first, iota, x.shape[1]), axis=1,
+                   keepdims=keepdims)

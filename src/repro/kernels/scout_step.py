@@ -79,31 +79,41 @@ def step_math(state, busy, tried, port_link, port_neighbor, cols, allow_nonminim
     immediately and off-mesh ports (link id -1) are never free.
     Returns (state', busy', tried').
     """
-    cur = state[:, 0]
-    dst = state[:, 1]
-    entry = state[:, 2]
-    rng = state[:, 3]
-    B = cur.shape[0]
+    # per-scout scalars stay [B, 1] columns and every bool is combined
+    # with select, never cast or stacked: the layouts Mosaic lowers
+    cur = state[:, 0:1]
+    dst = state[:, 1:2]
+    entry = state[:, 2:3]
+    rng = state[:, 3:4]
+    B = state.shape[0]
     n_nodes = port_link.shape[0]
     iota_n = jax.lax.broadcasted_iota(jnp.int32, (B, n_nodes), 1)
-    one_hot_cur = (iota_n == cur[:, None]).astype(jnp.int32)  # [B, N]
+    one_hot_cur = jnp.where(iota_n == cur, 1.0, 0.0).astype(jnp.float32)
+
+    def mxu_take(table):
+        # the MXU has no int32 matmul; a one-hot f32 product at full
+        # precision is exact for table ids (|id| < 2^24)
+        return jax.lax.dot(one_hot_cur, table.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST
+                           ).astype(jnp.int32)
+
     # MXU gathers: per-port link ids / neighbor ids for each scout's node
-    links4 = jax.lax.dot(one_hot_cur, port_link.astype(jnp.int32))  # [B, 4]
-    nbrs4 = jax.lax.dot(one_hot_cur, port_neighbor.astype(jnp.int32))
+    links4 = mxu_take(port_link)  # [B, 4]
+    nbrs4 = mxu_take(port_neighbor)
+    link_p = [links4[:, p:p + 1] for p in range(4)]
+    nbr_p = [nbrs4[:, p:p + 1] for p in range(4)]
 
-    # per-port busy: does links4[b,p] index a set bit of busy[b]?
-    L = busy.shape[1]
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (B, 4, L), 2)
-    sel_l = iota_l == links4[:, :, None]
-    busy4 = jnp.any(sel_l & busy[:, None, :].astype(bool), axis=2)
-    # per-port tried: bit cur*4+p
-    T = tried.shape[1]
-    tried_idx = cur[:, None] * 4 + jax.lax.broadcasted_iota(jnp.int32, (B, 4), 1)
-    iota_t = jax.lax.broadcasted_iota(jnp.int32, (B, 4, T), 2)
-    sel_t = iota_t == tried_idx[:, :, None]
-    tried4 = jnp.any(sel_t & tried[:, None, :].astype(bool), axis=2)
+    iota_l = jax.lax.broadcasted_iota(jnp.int32, busy.shape, 1)
+    iota_t = jax.lax.broadcasted_iota(jnp.int32, tried.shape, 1)
 
-    free4 = (links4 >= 0) & ~busy4 & ~tried4  # [B, 4]
+    def bit(bitmap, iota, col):
+        """bitmap[b, col[b]] != 0 (False for an out-of-range col)."""
+        hit = jnp.where((iota == col) & (bitmap != 0), 1, 0)
+        return jnp.max(hit, axis=1, keepdims=True) > 0
+
+    # per port: a link exists, is not busy, and was not tried from here
+    free = [(link_p[p] >= 0) & ~bit(busy, iota_l, link_p[p])
+            & ~bit(tried, iota_t, cur * 4 + p) for p in range(4)]
 
     at_dst = cur == dst
     diffx = dst % cols - cur % cols
@@ -111,60 +121,70 @@ def step_math(state, busy, tried, port_link, port_neighbor, cols, allow_nonminim
     px = jnp.where(diffx > 0, RIGHT, jnp.where(diffx < 0, LEFT, -1))
     py = jnp.where(diffy > 0, UP, jnp.where(diffy < 0, DOWN, -1))
 
-    iota4 = jax.lax.broadcasted_iota(jnp.int32, (B, 4), 1)
-    fmin0 = (px[:, None] == iota4) & free4
-    fmin1 = (py[:, None] == iota4) & free4
-    fmin = jnp.stack([jnp.any(fmin0, 1), jnp.any(fmin1, 1)], axis=1)  # [B, 2]
-    n_min = jnp.sum(fmin.astype(jnp.int32), axis=1)
-    fmis = free4 & (iota4 != entry[:, None])
-    allow = jnp.asarray(allow_nonminimal)
-    fmis &= allow.reshape(-1, 1)  # scalar or per-scout [B] flag
-    n_mis = jnp.sum(fmis.astype(jnp.int32), axis=1)
+    def any_port(d):
+        out = (d == 0) & free[0]
+        for p in range(1, 4):
+            out = out | ((d == p) & free[p])
+        return out
+
+    def count(flags):
+        n = jnp.zeros_like(cur)
+        for f in flags:
+            n = n + jnp.where(f, 1, 0)
+        return n
+
+    fmin_x, fmin_y = any_port(px), any_port(py)
+    n_min = count((fmin_x, fmin_y))
+    # scalar or per-scout [B] flag
+    allow = jnp.asarray(allow_nonminimal).reshape(-1, 1)
+    fmis = [free[p] & (entry != p) & allow for p in range(4)]
+    n_mis = count(fmis)
 
     use_min = n_min > 0
-    count = jnp.where(use_min, n_min, n_mis)
-    need_rng = (~at_dst) & (count > 1)
+    n_cand = jnp.where(use_min, n_min, n_mis)
+    need_rng = (~at_dst) & (n_cand > 1)
     rng_next = jnp.where(need_rng, xorshift32_i32(rng), rng)
-    idx = umod(rng_next, jnp.maximum(count, 1))
+    idx = umod(rng_next, jnp.maximum(n_cand, 1))
 
-    cand_ports = jnp.concatenate([px[:, None], py[:, None], iota4], axis=1)  # [B,6]
-    cand_flags = jnp.concatenate(
-        [fmin & use_min[:, None], fmis & ~use_min[:, None]], axis=1
-    )
-    cum = jnp.cumsum(cand_flags.astype(jnp.int32), axis=1)
-    sel = cand_flags & (cum - 1 == idx[:, None])
-    pick = jnp.sum(jnp.where(sel, cand_ports, 0), axis=1)
-    has_pick = (count > 0) & ~at_dst
+    # the idx-th set flag of the 6 candidates [px, py, port 0..3], as an
+    # explicit running count over the columns (Mosaic has no cumsum)
+    cand = [(fmin_x & use_min, px), (fmin_y & use_min, py)] + [
+        (fmis[p] & ~use_min, p) for p in range(4)
+    ]
+    run = jnp.zeros_like(idx)
+    pick = jnp.zeros_like(idx)
+    for flag, port in cand:
+        run = run + jnp.where(flag, 1, 0)
+        pick = pick + jnp.where(flag & (run - 1 == idx), port, 0)
+    has_pick = (n_cand > 0) & ~at_dst
 
     # advance
-    iota4b = iota4
-    link_pick = jnp.sum(jnp.where(iota4b == pick[:, None], links4, 0), axis=1)
-    nbr_pick = jnp.sum(jnp.where(iota4b == pick[:, None], nbrs4, 0), axis=1)
+    link_pick = jnp.zeros_like(pick)
+    nbr_pick = jnp.zeros_like(pick)
+    for p in range(4):
+        link_pick = link_pick + jnp.where(pick == p, link_p[p], 0)
+        nbr_pick = nbr_pick + jnp.where(pick == p, nbr_p[p], 0)
     opposite = (pick + 2) % 4
 
     new_cur = jnp.where(has_pick, nbr_pick, cur)
     new_entry = jnp.where(has_pick, opposite, entry)
     # flags: 0 = backtrack, 1 = advanced, 2 = at destination
-    flags = jnp.where(at_dst, 2, jnp.where(has_pick, 1, 0)).astype(jnp.int32)
+    flags = jnp.where(at_dst, 2, jnp.where(has_pick, 1, 0))
     out_pick = jnp.where(has_pick, pick, -1)
-    is_mis = (has_pick & ~use_min).astype(jnp.int32)
+    is_mis = jnp.where(has_pick & ~use_min, 1, 0)
 
-    state_out = jnp.stack(
-        [new_cur, dst, new_entry, rng_next, flags, out_pick, is_mis,
-         jnp.where(has_pick, link_pick, 0)],
-        axis=1,
-    )
+    out_cols = (new_cur, dst, new_entry, rng_next, flags, out_pick, is_mis,
+                jnp.where(has_pick, link_pick, 0))
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (B, STATE_W), 1)
+    state_out = jnp.zeros((B, STATE_W), jnp.int32)
+    for k, col in enumerate(out_cols):
+        state_out = jnp.where(iota_s == k, col, state_out)
     # set busy/tried bits for the traversed port
-    L_iota = jax.lax.broadcasted_iota(jnp.int32, busy.shape, 1)
-    busy_out = busy.astype(bool) | (
-        has_pick[:, None] & (L_iota == link_pick[:, None])
-    )
-    T_iota = jax.lax.broadcasted_iota(jnp.int32, tried.shape, 1)
-    tried_bit = cur * 4 + pick
-    tried_out = tried.astype(bool) | (
-        has_pick[:, None] & (T_iota == tried_bit[:, None])
-    )
-    return state_out, busy_out.astype(jnp.int32), tried_out.astype(jnp.int32)
+    busy_out = jnp.where(
+        (busy != 0) | (has_pick & (iota_l == link_pick)), 1, 0)
+    tried_out = jnp.where(
+        (tried != 0) | (has_pick & (iota_t == cur * 4 + pick)), 1, 0)
+    return state_out, busy_out, tried_out
 
 
 def _kernel(state_ref, busy_ref, tried_ref, tables_ref, state_o, busy_o, tried_o,
@@ -193,7 +213,7 @@ def _kernel_vec(state_ref, busy_ref, tried_ref, tables_ref, allow_ref,
     busy = busy_ref[...]
     tried = tried_ref[...]
     tables = tables_ref[...]
-    allow = allow_ref[...][:, 0].astype(bool)
+    allow = allow_ref[...] != 0
     port_link = tables[:n_nodes, 0:4]
     port_neighbor = tables[:n_nodes, 4:8]
     s, b, t = step_math(

@@ -498,9 +498,15 @@ def resolve_specs(designs: Sequence[str]) -> tuple:
 # as sliced inputs.  Only state-dependent lookups (plane free-at, live FC
 # selection) remain in the step, as one-hot compare-and-reduce
 # (``repro.kernels.onehot``).  Candidate masks are bit-packed along the
-# resource axis (uint8, little-endian) to keep the [n, F_pad, 2, R] blow-up
-# at R/8 bytes; the step unpacks them with shifts (no gather either).
+# resource axis into little-endian 32-bit words (bit r is bit r % 32 of
+# word r // 32) to keep the [n, F_pad, 2, R] blow-up at R/8 bytes; the
+# step unpacks them with shifts (no gather either).
 # ---------------------------------------------------------------------------
+
+
+def mask_words_per_row(n_bits: int) -> int:
+    """int32 words that hold one packed mask of ``n_bits`` resources."""
+    return -(-n_bits // 32)
 
 
 def pregather_node_tables(tables_row, nodes: np.ndarray) -> dict:
@@ -510,14 +516,17 @@ def pregather_node_tables(tables_row, nodes: np.ndarray) -> dict:
     axis); ``nodes``: int array [n] of the lane's transaction nodes.
     Returns numpy arrays (lane-major, length n; the planner stacks them
     time-major per batch):
-      ``mask_words`` uint8 [n, F_pad, 2, ceil(R_pad/8)], ``hops`` int32
+      ``mask_words`` int32 [n, F_pad, 2, ceil(R_pad/32)], ``hops`` int32
       [n, F_pad, 2], ``dist`` int32 [n, F_pad], ``cand2`` bool [n],
       ``fc_fixed`` int32 [n, 2].
     """
     cmask = np.asarray(tables_row.cmask)  # [F0, N, 2, R]
     packed = np.packbits(cmask, axis=-1, bitorder="little")
+    n_bytes = 4 * mask_words_per_row(cmask.shape[-1])
+    packed = np.pad(packed, [(0, 0)] * 3 + [(0, n_bytes - packed.shape[-1])])
+    words = np.ascontiguousarray(packed).view("<i4").astype(np.int32)
     return dict(
-        mask_words=np.ascontiguousarray(packed.transpose(1, 0, 2, 3)[nodes]),
+        mask_words=np.ascontiguousarray(words.transpose(1, 0, 2, 3)[nodes]),
         hops=np.ascontiguousarray(
             np.asarray(tables_row.hops).transpose(1, 0, 2)[nodes]
         ),

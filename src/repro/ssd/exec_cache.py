@@ -6,11 +6,10 @@ keyed on geometry/capacity/cost class — see ``sim._build_group_fn``), so a
 warm ``benchmarks/run.py`` was still paying the full cold-compile tax every
 process.  This module serializes compiled executables with
 ``jax.experimental.serialize_executable`` (true AOT: loading skips
-tracing, lowering AND XLA compilation) into a versioned on-disk store, and
-``repro.xla_env`` additionally enables JAX's native persistent compilation
-cache as a second tier (that tier still re-traces and re-lowers, but skips
-the XLA backend compile — it catches programs this cache does not know
-about, e.g. one-off jits in tests).
+tracing, lowering AND XLA compilation) into a versioned on-disk store.
+It is an opt-in alternative to JAX's native persistent compilation cache
+(which ``repro.xla_env`` places by default, and which still re-traces and
+re-lowers, but skips the XLA backend compile); the two never run together.
 
 Store layout
     ``$REPRO_XC_DIR/<digest>.xc`` — one file per executable, written
@@ -21,7 +20,7 @@ Store layout
 
     * ``jax.__version__`` + ``jaxlib.__version__``,
     * the XLA backend platform and its runtime version,
-    * ``XLA_FLAGS`` (device count, thunk-runtime choice, ...),
+    * ``XLA_FLAGS`` (device count, ...),
     * the *source digest* of the modules that define the programs
       (``ssd/sim.py``, ``ssd/designs.py``, ``ssd/config.py``,
       ``core/scout.py``, ``core/topology.py``, ``core/routing.py``),
@@ -39,9 +38,9 @@ Failure model
     version-skewed pickle, missing device topology — degrades to a cache
     miss (the caller compiles) and bumps ``STATS["errors"]``; the broken
     entry is deleted so it cannot fail twice.  The cache is disabled when
-    ``REPRO_XC_DIR`` is unset/empty (library default: entry points that
-    want persistence — ``benchmarks/run.py``, the test conftest — opt in
-    via ``repro.xla_env.configure``).
+    ``REPRO_XC_DIR`` is unset/empty, which is the default: a user opts in
+    by exporting it, with JAX's persistent cache turned off
+    (``JAX_ENABLE_COMPILATION_CACHE=false``; see :func:`cache_dir`).
 """
 from __future__ import annotations
 
@@ -86,9 +85,23 @@ def reset_stats() -> None:
 
 
 def cache_dir() -> str | None:
-    """The store directory, or None when the cache is disabled."""
+    """The store directory, or None when the store is disabled.
+
+    Disabled unless ``REPRO_XC_DIR`` names a directory, and disabled while
+    JAX's persistent compilation cache is on: an executable JAX loaded
+    from its own cache does not survive this store's round trip on
+    XLA:CPU (it deserializes, then fails at run time with "Function ...
+    not found"), so the two caches exclude each other and JAX's wins.
+    """
     d = os.environ.get("REPRO_XC_DIR", "")
-    return d or None
+    if not d:
+        return None
+    import jax
+
+    if (jax.config.jax_enable_compilation_cache
+            and jax.config.jax_compilation_cache_dir):
+        return None
+    return d
 
 
 @functools.lru_cache(maxsize=None)
@@ -142,8 +155,13 @@ def has(logical_key: tuple) -> bool:
             and os.path.exists(_entry_path(entry_digest(logical_key))))
 
 
-def lookup(logical_key: tuple):
+def lookup(logical_key: tuple, devices=None):
     """Load a compiled executable for ``logical_key``, or None.
+
+    ``devices`` are the devices the program was compiled for, in order
+    (None: every device of the backend).  The loaded executable expects
+    its inputs placed exactly there — a one-device program loaded onto
+    every device would demand one shard per device.
 
     Any failure (absent, corrupted, version-mismatched, wrong topology)
     returns None so the caller falls back to compiling; corruption also
@@ -166,7 +184,8 @@ def lookup(logical_key: tuple):
             STATS["misses"] += 1
             return None
         payload, in_tree, out_tree = entry
-        compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+        compiled = se.deserialize_and_load(payload, in_tree, out_tree,
+                                           execution_devices=devices)
     except Exception:  # noqa: BLE001 — any breakage degrades to a miss
         STATS["errors"] += 1
         STATS["misses"] += 1
@@ -195,7 +214,7 @@ def _write_entry(path: str, blob: bytes) -> None:
         raise
 
 
-def _store_now(logical_key: tuple, compiled) -> None:
+def _store_now(logical_key: tuple, compiled, devices) -> None:
     try:
         from jax.experimental import serialize_executable as se
 
@@ -214,7 +233,8 @@ def _store_now(logical_key: tuple, compiled) -> None:
         # at load time by the parent's error->tombstone fallback instead.
         if os.environ.get("REPRO_XC_VERIFY", "1") != "0":
             try:
-                se.deserialize_and_load(payload, in_tree, out_tree)
+                se.deserialize_and_load(payload, in_tree, out_tree,
+                                        execution_devices=devices)
             except Exception:  # noqa: BLE001
                 _write_entry(path, pickle.dumps({"tombstone": _FORMAT}))
                 STATS["tombstones"] += 1
@@ -230,8 +250,9 @@ _STORE_POOL = None
 _PENDING = []
 
 
-def store(logical_key: tuple, compiled) -> None:
-    """Queue ``compiled`` for serialization under ``logical_key``.
+def store(logical_key: tuple, compiled, devices=None) -> None:
+    """Queue ``compiled`` for serialization under ``logical_key``
+    (``devices`` as in :func:`lookup`).
 
     Stores run on a single background writer (serialize + the round-trip
     verification are not free, and the compile workers should be
@@ -249,7 +270,8 @@ def store(logical_key: tuple, compiled) -> None:
             max_workers=1, thread_name_prefix="xc-store"
         )
         atexit.register(flush)
-    _PENDING.append(_STORE_POOL.submit(_store_now, logical_key, compiled))
+    _PENDING.append(_STORE_POOL.submit(_store_now, logical_key, compiled,
+                                       devices))
 
 
 def flush() -> None:

@@ -52,13 +52,13 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.scout import make_tables, scout_route
 from repro.core.topology import build_mesh
 from repro.obs import spans as obs_spans
 from repro.kernels import onehot
+from repro.kernels.batched_step import lane_tile, lanes_2d, lane_tiled_step
 from repro.kernels.ops import route_dfs
 from repro.kernels.scout_step import pack_tables, scout_step_pallas, step_math
 from repro.ssd.config import SSDConfig, TICK_NS
@@ -66,6 +66,7 @@ from repro.ssd.designs import (
     DESIGNS,
     REGISTRY,
     LaneTables,
+    mask_words_per_row,
     resolve_specs,
     sweep_layout_geom,
 )
@@ -90,15 +91,16 @@ _MAX_TRIES = 64  # scout retry bound per reservation
 # accounting, not silent loss.  ~10.5 ms at the 10 ns tick.
 FAIL_TIMEOUT = np.int32(1 << 20)
 
-# Lane-step kernel backend for the batched static runner.  "xla" keeps
-# the one-hot XLA step (the CPU default — interpret-mode Pallas lowers
-# to the same ops plus per-step call scaffolding, so on CPU it is pure
-# overhead); "pallas" compiles the lane-tiled pallas_call from
-# ``kernels.batched_step`` (GPU/TPU), degrading honestly to
-# "pallas-interpret" on CPU where Pallas has no compiler; "auto" picks
-# pallas on an accelerator and xla on CPU.  Settable via the
+# Lane-step kernel backend for the batched runners.  "auto" (the
+# default) compiles the Pallas kernels (``kernels.batched_step``,
+# ``kernels.scout_step``) on an accelerator and keeps the one-hot XLA step
+# on CPU, where Pallas has no compiler and interpret mode lowers to the
+# same ops plus per-step call scaffolding.  "xla" forces the XLA step;
+# "pallas" asks for the kernels, which on CPU can only run interpreted
+# ("pallas-interpret", which tests pin against the XLA step).  An
+# accelerator never runs a kernel interpreted.  Settable via the
 # REPRO_LANE_BACKEND env var or ``benchmarks/run.py --lane-backend``.
-LANE_BACKEND = os.environ.get("REPRO_LANE_BACKEND", "xla")
+LANE_BACKEND = os.environ.get("REPRO_LANE_BACKEND", "auto")
 _LANE_BACKENDS = ("xla", "pallas", "pallas-interpret", "auto")
 _ACCEL_BACKENDS = ("gpu", "tpu", "cuda", "rocm")
 
@@ -112,6 +114,10 @@ def resolve_lane_backend(setting: str | None = None) -> str:
         raise ValueError(
             f"unknown lane backend {s!r}; pick from {_LANE_BACKENDS}")
     on_accel = jax.default_backend() in _ACCEL_BACKENDS
+    if s == "pallas-interpret" and on_accel:
+        raise ValueError(
+            "lane backend 'pallas-interpret' would hide the kernels from "
+            f"the {jax.default_backend()} device; use 'pallas' or 'auto'")
     if s == "auto":
         return "pallas" if on_accel else "xla"
     if s == "pallas" and not on_accel:
@@ -636,16 +642,6 @@ def _skip_out(tx: TxnArrays) -> StepOut:
 # ``shard_map`` group — one lane per host CPU device; the sweep planner
 # sorts lanes from many workloads/configs/channel-rows by length so the
 # lanes sharing a group's barrier are of similar cost.
-#
-# NOTE on the XLA CPU runtime: this program shape — nested while-loops
-# (scout retry -> DFS -> scan chunk -> fori over chunks) — is pathological
-# for XLA's *thunk* CPU runtime: per-iteration executor synchronization
-# makes a scout step ~10x slower single-threaded, compiles ~4x slower,
-# and concurrent executions contend (measured 3-4x mutual slowdown).
-# ``benchmarks/run.py`` and the test conftest therefore force
-# ``--xla_cpu_use_thunk_runtime=false`` (the legacy runtime) alongside the
-# host device count; both are no-ops for correctness, which the parity
-# suite pins either way.
 # ---------------------------------------------------------------------------
 
 CHUNK = 1024  # scan-chunk granularity; trims pad waste to < one chunk
@@ -789,8 +785,8 @@ def _build_group_fn(sig: tuple, capacity: int, k_max: int,
 
     if n_shards > 1:
         spec = (P("lanes"),) * 4
-        fn = shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
-                       out_specs=P("lanes"), check_rep=False)
+        fn = jax.shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
+                           out_specs=P("lanes"), check_vma=False)
     else:
         fn = one
     return jax.jit(fn)
@@ -824,8 +820,8 @@ def _build_group_fn_carry(sig: tuple, capacity: int, k_max: int,
 
     if n_shards > 1:
         spec = (P("lanes"),) * 4
-        fn = shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
-                       out_specs=P("lanes"), check_rep=False)
+        fn = jax.shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
+                           out_specs=P("lanes"), check_vma=False)
     else:
         fn = one
     return jax.jit(fn)
@@ -859,8 +855,8 @@ def _build_stack_fn(sig: tuple, capacity: int, K: int, k_max: int,
 
     if n_shards > 1:
         spec = (P("lanes"),) * 4
-        fn = shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
-                       out_specs=P("lanes"), check_rep=False)
+        fn = jax.shard_map(one, mesh=_lane_mesh(n_shards), in_specs=spec,
+                           out_specs=P("lanes"), check_vma=False)
     else:
         fn = one
     return jax.jit(fn)
@@ -920,7 +916,7 @@ class BatchTxnTables(NamedTuple):
     """Per-transaction pre-gathered node tables, time-major [cap, B, ...]
     (see ``designs.pregather_node_tables``)."""
 
-    mask_words: jnp.ndarray  # uint8 [cap, B, F_pad, 2, ceil(R_pad/8)]
+    mask_words: jnp.ndarray  # int32 [cap, B, F_pad, 2, ceil(R_pad/32)]
     hops: jnp.ndarray  # int32 [cap, B, F_pad, 2]
     dist: jnp.ndarray  # int32 [cap, B, F_pad]
     cand2: jnp.ndarray  # bool  [cap, B]
@@ -933,15 +929,23 @@ def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
     Mirrors ``static_step`` in ``_make_step`` operation for operation
     (all int32 — the one-hot reductions and masked selects are exact, so
     batched == unbatched bit-for-bit); consult that function for the
-    modeling semantics.  ``xs`` is ``(TxnArrays, BatchTxnTables)`` with
-    every field carrying a leading [B] axis for this step.
+    modeling semantics.  Every input is in the lane-major 2D form of
+    :func:`repro.kernels.batched_step.lanes_2d` — per-lane scalars are
+    [B, 1] columns, bools are 0/1 int32 — and so is every output, which
+    is the layout the TPU kernel compiler lowers.  ``xs`` is
+    ``(TxnArrays, BatchTxnTables)`` for this step.
     """
     L0, F0, R = lay.L_pad, lay.F_pad, lay.R_pad
+    W = mask_words_per_row(R)
     fixed = dict(zip(_PROMOTABLE, fixed))
 
     def fx(sp, name):
         v = fixed[name]
         return getattr(sp, name) if v is None else v
+
+    def flag(sp, name):
+        v = fx(sp, name)
+        return v if isinstance(v, bool) else v != 0
 
     def cmd_ticks(sp, hops):
         ns = fx(sp, "cmd_base_ns") + hops * fx(sp, "hop_ns")
@@ -952,34 +956,46 @@ def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
         ns = ns + hops * fx(sp, "hop_ns")
         return _ceil_div(ns, TICK_NS).astype(jnp.int32)
 
+    def iota(B, n):
+        return jax.lax.broadcasted_iota(jnp.int32, (B, n), 1)
+
+    def any_(mask):
+        return jnp.max(jnp.where(mask, 1, 0), axis=1, keepdims=True) > 0
+
+    def take(table, idx):
+        """table[b, idx[b]] as a [B, 1] column (0 when out of range)."""
+        hit = iota(*table.shape) == idx
+        return jnp.sum(jnp.where(hit, table, 0), axis=1, keepdims=True)
+
     def path_sched(res, mask, e, d):
         free, gap_s, gap_e = res
-        avail = _gap_avail(gap_s, gap_e, free, e[:, None], d[:, None])
-        s1 = jnp.max(jnp.where(mask, avail, 0), axis=1)
+        avail = _gap_avail(gap_s, gap_e, free, e, d)
+        s1 = jnp.max(jnp.where(mask, avail, 0), axis=1, keepdims=True)
         s1 = jnp.maximum(s1, e)
-        busy = _busy_at(res, s1[:, None], d[:, None])
-        ok = ~jnp.any(busy & mask, axis=1)
-        s_tail = jnp.maximum(e, jnp.max(jnp.where(mask, free, 0), axis=1))
+        ok = ~any_(_busy_at(res, s1, d) & mask)
+        s_tail = jnp.maximum(
+            e, jnp.max(jnp.where(mask, free, 0), axis=1, keepdims=True))
         return jnp.where(ok, s1, s_tail)
 
     def commit_mask(res, mask, s, e2, enable):
         free, gap_s, gap_e = res
-        gs, ge, fa = _gap_commit(gap_s, gap_e, free, s[:, None], e2[:, None])
-        take = mask & enable[:, None]
+        gs, ge, fa = _gap_commit(gap_s, gap_e, free, s, e2)
+        take_ = mask & enable
         return (
-            jnp.where(take, fa, free),
-            jnp.where(take, gs, gap_s),
-            jnp.where(take, ge, gap_e),
+            jnp.where(take_, fa, free),
+            jnp.where(take_, gs, gap_s),
+            jnp.where(take_, ge, gap_e),
         )
 
     def step(sp: BatchScalars, state, xs):
         tx, tt = xs
         plane_free, res = state
-        valid = tx.valid
+        B = plane_free.shape[0]
+        valid = tx.valid != 0
         is_read = tx.kind == KIND_READ
-        tcand = jnp.maximum(tx.arrival, onehot.take(plane_free, tx.plane))
-        fc_nearest = fx(sp, "fc_nearest")
-        hold = fx(sp, "hold")
+        tcand = jnp.maximum(tx.arrival, take(plane_free, tx.plane))
+        fc_nearest = flag(sp, "fc_nearest")
+        hold = flag(sp, "hold")
 
         d_est = (xfer_ticks(sp, tx.nbytes, fx(sp, "d_est_hops"))
                  + fx(sp, "d_est_pad"))
@@ -989,29 +1005,33 @@ def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
             )
         free, gs, ge = res
         sl = slice(L0, L0 + F0)
-        avail = _gap_avail(gs[:, sl], ge[:, sl], free[:, sl],
-                           tcand[:, None], d_est[:, None])
-        avail = jnp.where(sp.fc_valid, avail, _BIG)
-        free_now = avail <= tcand[:, None]
-        any_free = jnp.any(free_now, axis=1)
-        by_dist = jnp.argmin(jnp.where(free_now, tt.dist, _BIG), axis=1)
-        by_time = jnp.argmin(avail, axis=1)
-        fc_near = jnp.where(any_free, by_dist, by_time).astype(jnp.int32)
-        t0_near = jnp.maximum(tcand, onehot.take(avail, fc_near))
+        avail = _gap_avail(gs[:, sl], ge[:, sl], free[:, sl], tcand, d_est)
+        avail = jnp.where(sp.fc_valid != 0, avail, _BIG)
+        free_now = avail <= tcand
+        by_dist = onehot.argmin(jnp.where(free_now, tt.dist, _BIG),
+                                keepdims=True)
+        by_time = onehot.argmin(avail, keepdims=True)
+        fc_near = jnp.where(any_(free_now), by_dist, by_time)
+        t0_near = jnp.maximum(tcand, take(avail, fc_near))
         t0 = jnp.where(fc_nearest, t0_near, tcand)
 
-        fcA = jnp.where(fc_nearest, fc_near, tt.fc_fixed[:, 0])
-        fcB = jnp.where(fc_nearest, fc_near, tt.fc_fixed[:, 1])
-        cand2 = tt.cand2
+        fcA = jnp.where(fc_nearest, fc_near, tt.fc_fixed[:, 0:1])
+        fcB = jnp.where(fc_nearest, fc_near, tt.fc_fixed[:, 1:2])
+        cand2 = tt.cand2 != 0
+        # bit r of a candidate mask is bit r % 32 of its word r // 32
+        iota_r = iota(B, R)
+        word_of, bit_of = iota_r >> 5, iota_r & 31
 
         def eval_cand(res, cand, fc, enable):
-            words = onehot.take(
-                tt.mask_words[:, :, cand, :].astype(jnp.int32), fc
-            )
-            mask = onehot.unpack_bits(words, R)
-            dead = jnp.any(mask & sp.res_dead, axis=1)
+            slot = fc * 2 + cand  # (fc, cand) slot of the [F0, 2] tables
+            mask = jnp.zeros((B, R), bool)
+            for w in range(W):
+                word = take(tt.mask_words, slot * W + w)
+                bits = jax.lax.shift_right_logical(word, bit_of) & 1
+                mask = mask | ((word_of == w) & (bits != 0))
+            dead = any_(mask & (sp.res_dead != 0))
             enable = enable & ~dead
-            hops = onehot.take(tt.hops[:, :, cand], fc)
+            hops = take(tt.hops, slot)
             cmd = cmd_ticks(sp, hops)
             xfer = xfer_ticks(sp, tx.nbytes, hops)
             ovh = fx(sp, "ovh")
@@ -1036,7 +1056,7 @@ def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
         )
         failed = deadA & (deadB | ~cand2)
         res = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(useA[:, None], a, b), resA, resB
+            lambda a, b: jnp.where(useA, a, b), resA, resB
         )
         done = jnp.where(useA, doneA, doneB)
         wait = jnp.where(useA, waitA, waitB)
@@ -1046,22 +1066,22 @@ def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
         wait = jnp.where(failed, FAIL_TIMEOUT, wait)
         occ = jnp.where(failed, 0, occ)
         hops_o = jnp.where(failed, 0, hops_o)
-        upd = onehot.onehot(tx.plane, n_planes) & valid[:, None]
-        plane_free = jnp.where(upd, done[:, None], plane_free)
-        cb = jnp.logical_and(fx(sp, "count_bus"), True)
+        upd = (iota(B, n_planes) == tx.plane) & valid
+        plane_free = jnp.where(upd, done, plane_free)
+        cb = jnp.logical_and(flag(sp, "count_bus"), True)
         zero = jnp.zeros_like(done)
         out = StepOut(
             completion=jnp.where(valid, done, tx.arrival),
             wait=jnp.where(valid, wait, 0),
-            conflict=valid & (wait > 0),
+            conflict=jnp.where(valid & (wait > 0), 1, 0),
             hops=jnp.where(valid, hops_o, 0),
-            tries=jnp.where(valid, 1, 0).astype(jnp.int32),
+            tries=jnp.where(valid, 1, 0),
             scout_steps=zero,
             misroutes=zero,
             bus_hold=jnp.where(valid & cb, occ, 0),
             link_hold=jnp.where(valid & jnp.logical_not(cb),
                                 hops_o * occ, 0),
-            failed=valid & failed,
+            failed=jnp.where(valid & failed, 1, 0),
         )
         return (plane_free, res), out
 
@@ -1081,14 +1101,23 @@ def _zero_out_tm(capacity: int, B: int) -> StepOut:
 def _make_batched_run(step, capacity: int, n_planes: int, R: int):
     """Chunked batched scan: trip count = the batch's max chunk count
     (shorter lanes' excess steps are masked — valid=False leaves state and
-    outputs exactly as the unbatched skip does)."""
+    outputs exactly as the unbatched skip does).  ``step`` takes and
+    returns the lane-major 2D form (:func:`kernels.batched_step.lanes_2d`);
+    outputs go back to [B] vectors, the two flags to bool."""
 
     def batch_run(sp, txns: TxnArrays, tt: BatchTxnTables, n_chunks):
         B = n_chunks.shape[0]
+        sp = lanes_2d(sp)
         state = (
             jnp.zeros((B, n_planes), jnp.int32),
             tuple(jnp.zeros((B, R), jnp.int32) for _ in range(3)),
         )
+
+        def scan_step(s, x):
+            s, out = step(sp, s, lanes_2d(x))
+            out = StepOut(*(o[:, 0] for o in out))
+            return s, out._replace(conflict=out.conflict != 0,
+                                   failed=out.failed != 0)
 
         def chunk_body(c, carry):
             st, buf = carry
@@ -1096,7 +1125,7 @@ def _make_batched_run(step, capacity: int, n_planes: int, R: int):
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, CHUNK, 0)
             xs = (jax.tree_util.tree_map(sl, txns),
                   jax.tree_util.tree_map(sl, tt))
-            st, outs = jax.lax.scan(lambda s, x: step(sp, s, x), st, xs)
+            st, outs = jax.lax.scan(scan_step, st, xs)
             buf = jax.tree_util.tree_map(
                 lambda b, o: jax.lax.dynamic_update_slice_in_dim(b, o, off, 0),
                 buf, outs,
@@ -1124,15 +1153,13 @@ def _build_batched_fn(sig: tuple, capacity: int, fixed: tuple,
         # lane-tiled Pallas wrapper around the SAME step closure: the
         # kernel body is the step itself, so the pallas path is bit-exact
         # by construction (and pinned so by tests/test_batched_pallas.py)
-        from repro.kernels.batched_step import lane_tiled_step
-
         step = lane_tiled_step(step, interpret=(backend != "pallas"))
     brun = _make_batched_run(step, capacity, n_planes, lay.R_pad)
 
     if n_shards > 1:
         spec = (P("lanes"), P(None, "lanes"), P(None, "lanes"), P("lanes"))
-        fn = shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
-                       out_specs=P(None, "lanes"), check_rep=False)
+        fn = jax.shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
+                           out_specs=P(None, "lanes"), check_vma=False)
     else:
         fn = brun
     return jax.jit(fn)
@@ -1281,7 +1308,7 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
                 return step_math(state, busy, tried, pl_, pn_, cols, allow)
 
             return step_fn, b_tile
-        b_tile = 256 if B % 256 == 0 else -(-B // 8) * 8
+        b_tile = lane_tile(-(-B // 8) * 8)
         interpret = backend != "pallas"
         if isinstance(allow, (bool, np.bool_)):
             def step_fn(state, busy, tried):
@@ -1389,9 +1416,9 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
         dist_row = tt.dist[:, :n_fcs]
         free_now = avail <= tcand[:, None]
         any_free = jnp.any(free_now, axis=1)
-        by_dist = jnp.argmin(jnp.where(free_now, dist_row, _BIG), axis=1)
-        by_time = jnp.argmin(avail, axis=1)
-        fc = jnp.where(any_free, by_dist, by_time).astype(jnp.int32)
+        by_dist = onehot.argmin(jnp.where(free_now, dist_row, _BIG))
+        by_time = onehot.argmin(avail)
+        fc = jnp.where(any_free, by_dist, by_time)
         t0 = jnp.maximum(tcand, onehot.take(avail, fc))
         src = onehot.take(sp.fc_node[:, :n_fcs], fc)
         min_hops = onehot.take(dist_row, fc)
@@ -1510,8 +1537,8 @@ def _build_batched_scout_fn(sig: tuple, capacity: int, k_max: int,
     if n_shards > 1:
         spec = (P("lanes"), P("lanes"), P(None, "lanes"), P(None, "lanes"),
                 P("lanes"))
-        fn = shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
-                       out_specs=P(None, "lanes"), check_rep=False)
+        fn = jax.shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
+                           out_specs=P(None, "lanes"), check_vma=False)
     else:
         fn = brun
     return jax.jit(fn)
@@ -1693,7 +1720,7 @@ def _avatars_for_key(key: tuple):
     B = per_shard * n_shards
     lay = sweep_layout_geom(sig[0], sig[1])
     F0, R = lay.F_pad, lay.R_pad
-    W = -(-R // 8)
+    W = mask_words_per_row(R)
     L, T = P("lanes"), P(None, "lanes")
     scal = BatchScalars(
         *(_sds((B,), _TABLE_SCALAR_DTYPES[name], L, n_shards)
@@ -1702,7 +1729,7 @@ def _avatars_for_key(key: tuple):
         res_dead=_sds((B, R), bool, L, n_shards),
     )
     bt = BatchTxnTables(
-        mask_words=_sds((capacity, B, F0, 2, W), np.uint8, T, n_shards),
+        mask_words=_sds((capacity, B, F0, 2, W), np.int32, T, n_shards),
         hops=_sds((capacity, B, F0, 2), np.int32, T, n_shards),
         dist=_sds((capacity, B, F0), np.int32, T, n_shards),
         cand2=_sds((capacity, B), bool, T, n_shards),
@@ -1714,6 +1741,21 @@ def _avatars_for_key(key: tuple):
         bt,
         _sds((B,), np.int32, L, n_shards),
     )
+
+
+def _key_devices(key: tuple) -> list:
+    """The devices the program of ``key`` runs on, in mesh order: its
+    shard count's lane mesh, or the default device for one shard."""
+    kind = key[0]
+    if kind in ("lane", "lanec", "stack"):
+        n_shards = key[7]
+    elif kind == "bscout":
+        n_shards = key[6]
+    else:
+        n_shards = key[5]
+    if n_shards > 1:
+        return list(_lane_mesh(n_shards).devices.flat)
+    return jax.devices()[:1]
 
 
 def _fn_for_key(key: tuple):
@@ -1770,7 +1812,8 @@ def ensure_compiled(key: tuple, lowered=None):
     from repro.ssd import bench, exec_cache
 
     t0 = time.perf_counter()
-    compiled = exec_cache.lookup(key)
+    devices = _key_devices(key)
+    compiled = exec_cache.lookup(key, devices)
     if compiled is not None:
         _EXEC_CACHE[key] = compiled
         dt = time.perf_counter() - t0
@@ -1784,23 +1827,7 @@ def ensure_compiled(key: tuple, lowered=None):
     if lowered is None:
         lowered = _fn_for_key(key).lower(*_avatars_for_key(key))
     t0 = time.perf_counter()
-    # tier separation: planner programs are tier-1-managed, so they
-    # compile with JAX's native persistent cache (tier 2) DISABLED — an
-    # executable deserialized from tier 2 serializes with stale symbol
-    # names and the stored tier-1 entry fails to reload ("Symbols not
-    # found"); bypassing tier 2 here also avoids writing every big
-    # program to disk twice.  Tier 2 keeps serving everything that
-    # doesn't go through this function.  The bypass is perf-only, so a
-    # jax that moved the (private) config state just compiles without it.
-    try:
-        from jax._src.config import enable_compilation_cache as _no_t2
-        ctx = _no_t2(False)
-    except ImportError:
-        import contextlib
-
-        ctx = contextlib.nullcontext()
-    with ctx:
-        compiled = lowered.compile()
+    compiled = lowered.compile()  # JAX's persistent cache serves repeats
     dt = time.perf_counter() - t0
     with _TALLY_LOCK:
         bench.PERF["compile_s"] += dt
@@ -1808,7 +1835,7 @@ def ensure_compiled(key: tuple, lowered=None):
     if tr is not None:
         tr.complete("compile", f"compile:{key[0]}", tr.now_us() - dt * 1e6,
                     dt * 1e6, {"source": "build"})
-    exec_cache.store(key, compiled)
+    exec_cache.store(key, compiled, devices)
     _EXEC_CACHE[key] = compiled
     return compiled, dt, "build"
 

@@ -32,9 +32,7 @@ Planning + multi-core sharding (tentpole 2)
     UNBATCHED in its shard (vmap-batching lanes measured ~50x slower per
     scout step on CPU — see ``sim._build_group_fn``), and the sorting
     keeps a group's barrier cheap.  Every group of a pool shares one
-    executable (tables/seed/txns/chunk-count are arguments).  XLA's thunk
-    CPU runtime is disabled for this program shape (~10x per-step, see
-    the runtime note in ``sim``).
+    executable (tables/seed/txns/chunk-count are arguments).
 
 Trimmed scans
     After grouping, each lane's scan runs only ``ceil(n / CHUNK)`` chunks
@@ -67,6 +65,7 @@ from repro.ssd.designs import (
     KIND_SCOUT,
     LaneTables,
     lower_designs,
+    mask_words_per_row,
     pregather_node_tables,
     pregather_scout_tables,
     resolve_specs,
@@ -300,8 +299,14 @@ def _fail_server(reason: str) -> int:
 
 
 def _proc_mode() -> bool:
+    """Compile on the out-of-process server?  Only with the AOT store
+    opted into, and only on a CPU host: an accelerator belongs to one
+    process, so a second process importing JAX there would fail or hang."""
+    import jax
+
     return (exec_cache.cache_dir() is not None
-            and os.environ.get("REPRO_COMPILE_PROC", "1") != "0")
+            and os.environ.get("REPRO_COMPILE_PROC", "1") != "0"
+            and jax.default_backend() not in S._ACCEL_BACKENDS)
 
 
 def _proc_alive() -> bool:
@@ -593,7 +598,9 @@ def _plan_pool_occupancy(sig: tuple, lanes: list, has_scout: bool) -> list:
             j += 1
         blk = order[i:j]
         i = j
-        per = -(-len(blk) // n_shards)
+        # a multiple of 8 lanes per device: the kernels tile the batch in
+        # blocks of 8 TPU sublanes (``kernels.batched_step.lane_tile``)
+        per = -(-len(blk) // (8 * n_shards)) * 8
         plans.append(_GroupPlan(
             variant, sig, _pad_block(blk, n_shards * per), cap,
             n_shards, per, k_max, has_scout, fixed, backend=backend,
@@ -780,9 +787,9 @@ def _dispatch(plan: _GroupPlan) -> dict:
         ))
         F0 = np.asarray(lanes[0].tables_row.fc_valid).shape[0]
         R = np.asarray(lanes[0].tables_row.cmask).shape[-1]
-        W = -(-R // 8)
+        W = mask_words_per_row(R)
         bt = S.BatchTxnTables(
-            mask_words=np.zeros((cap, B, F0, 2, W), np.uint8),
+            mask_words=np.zeros((cap, B, F0, 2, W), np.int32),
             hops=np.zeros((cap, B, F0, 2), np.int32),
             dist=np.zeros((cap, B, F0), np.int32),
             cand2=np.zeros((cap, B), bool),

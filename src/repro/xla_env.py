@@ -1,63 +1,48 @@
-"""XLA CPU runtime flags + persistent-cache env for the sweep planner.
+"""Process environment for the simulator's entry points, set before JAX
+initializes.
 
-Set BEFORE jax imports: jax locks the host platform device count and the
-CPU runtime choice on first init, so every entry point that wants the
-planner's multi-core sharded execution (``benchmarks/run.py``, the test
-conftest) must append these to ``XLA_FLAGS`` before anything imports jax.
-This module is deliberately import-free of jax (``repro`` is a namespace
-package, so importing it pulls in nothing else).
+Every entry point (``benchmarks/run.py``, ``chip_smoke.py``, the test
+conftest) calls :func:`configure` before anything imports jax: jax reads
+these variables once, on first use.  This module is deliberately free of
+jax imports (``repro`` is a namespace package, so importing it pulls in
+nothing else).
 
-Why the legacy (non-thunk) runtime: the simulator's nested-while program
-shape (scout retry -> DFS -> scan chunk -> fori over chunks) is
-pathological for XLA's thunk CPU executor — ~10x slower scout steps, ~4x
-slower compiles, and 3-4x mutual slowdown of concurrent executions (see
-the runtime note in ``repro.ssd.sim``).  Both flags are perf-only;
-correctness is runtime-independent and pinned by the parity suite.
-
-Warm-path caches (perf-only as well; see ``repro.ssd.exec_cache``):
-``configure`` also opts the process into the two persistent compilation
-tiers so a warm run has ``compile_s_total`` ~ 0 —
-
-* tier 1, ``REPRO_XC_DIR`` (default ``results/.xc``): the repo's AOT
-  executable store — loading skips tracing, lowering and XLA compilation;
-* tier 2, ``JAX_COMPILATION_CACHE_DIR`` (default ``<xc_dir>/jax``): JAX's
-  native persistent compilation cache — still re-traces and re-lowers but
-  skips the backend compile, catching programs tier 1 doesn't manage.
-
-Both respect values the caller/user already exported; setting
-``REPRO_XC_DIR=""`` disables tier 1.
+* Host device count: ``--xla_force_host_platform_device_count`` gives the
+  CPU backend one device per core, so the sweep planner can shard lane
+  groups across them.  It only shapes the CPU platform; on an accelerator
+  the planner shards over the accelerator's devices.
+* Compile cache: JAX's persistent compilation cache, in exactly one
+  place.  A ``JAX_COMPILATION_CACHE_DIR`` set by the caller is used as it
+  is; otherwise the cache lives at :data:`CACHE_DIR`, a fixed path inside
+  the checkout (git-ignored), so every process that runs this checkout —
+  from any working directory — finds what the previous one compiled.
 """
 from __future__ import annotations
 
 import os
 
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))),
+    ".cache", "jax",
+)
 
-def configure(device_count: int | str | None = None,
-              cache_dir: str | None = None) -> None:
-    """Append the planner's XLA flags to ``XLA_FLAGS`` and default the
-    persistent-cache env vars (each only if the caller/user hasn't
-    already set it).  ``device_count`` defaults to the ``BENCH_DEVICES``
-    env var, then the machine's core count; ``cache_dir`` defaults the
-    tier-1 store location (``REPRO_XC_DIR``)."""
+
+def configure(device_count: int | str | None = None) -> None:
+    """Append the host device-count flag to ``XLA_FLAGS`` and place JAX's
+    persistent compilation cache, each only where the caller/user has not
+    already chosen.  ``device_count`` defaults to the ``BENCH_DEVICES``
+    env var, then the machine's core count."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         n = device_count or os.environ.get(
             "BENCH_DEVICES", str(os.cpu_count() or 1)
         )
         flags = f"{flags} --xla_force_host_platform_device_count={n}"
-    if "--xla_cpu_use_thunk_runtime" not in flags:
-        flags = f"{flags} --xla_cpu_use_thunk_runtime=false"
     os.environ["XLA_FLAGS"] = flags.strip()
 
-    # ---- persistent compile caches (both tiers are opt-out via env) ----
-    xc = os.environ.setdefault(
-        "REPRO_XC_DIR", cache_dir or os.path.join("results", ".xc")
-    )
-    if xc and "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(xc, "jax")
-        # cache every entry: the simulator's many small executables are
-        # individually below jax's default 1s/small-entry thresholds
-        os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                              "0")
-        os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
-                              "-1")
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", CACHE_DIR)
+    # cache every entry: the simulator's many small executables are
+    # individually below jax's default 1s/small-entry thresholds
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")

@@ -7,25 +7,10 @@ default run (see pytest.ini).
 """
 # Two virtual XLA host devices so the whole tier runs against the sweep
 # planner's sharded (shard_map) execution path — the multi-core layout the
-# benchmarks use — and the legacy (non-thunk) CPU runtime the benchmarks
-# run under (see repro.xla_env).  The single-device environment is covered
-# by the subprocess parity test in tests/test_sweep_plan.py.  MUST run
-# before any jax import: jax locks these on first init.
-#
-# The persistent executable cache (repro.ssd.exec_cache) is pointed at a
-# repo-local dir that SURVIVES pytest sessions: the tier compiles dozens of
-# tiny-geometry programs, and re-runs load them instead (the cache key
-# covers jax/jaxlib versions, XLA flags and the simulator sources, so a
-# code change invalidates exactly the affected entries).  Tests that need
-# cold-cache behaviour point REPRO_XC_DIR elsewhere (tests/test_exec_cache).
-import os as _os
-
-_os.environ.setdefault(
-    "REPRO_XC_DIR",
-    _os.path.join(_os.path.dirname(__file__), "..", ".pytest_cache",
-                  "repro-xc"),
-)
-
+# benchmarks use.  The single-device environment is covered by the
+# subprocess parity test in tests/test_sweep_plan.py.  MUST run before any
+# jax import: jax reads these on first init.  Tests that exercise the AOT
+# executable store opt in with their own REPRO_XC_DIR (tests/test_exec_cache).
 from repro.xla_env import configure as _configure_xla
 
 _configure_xla(device_count=2)
@@ -35,6 +20,24 @@ import pytest
 
 from repro.ssd import decompose_trace, perf_optimized
 from repro.traces.generator import gen_trace, to_pages
+
+
+@pytest.fixture()
+def no_jax_cache():
+    """JAX's persistent compilation cache off for one test: the AOT
+    executable store (``repro.ssd.exec_cache``) only runs without it, and
+    a compile for a TPU that is not attached cannot be read back.  JAX's
+    in-memory caches are dropped first: an executable an earlier test
+    loaded from the persistent cache would otherwise be handed out again."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
 
 
 @pytest.fixture(scope="session")

@@ -188,3 +188,22 @@ def test_kernel_dispatch_counters(tiny_cfg, tiny_txns, monkeypatch):
     # the lone scout lane runs flat here and tallies into the SCOUT
     # split (ISSUE 10), not the static unbatched counter
     assert bench.PERF["steps_scout_unbatched"] > su0
+
+
+def test_interpret_refused_on_accelerator(monkeypatch):
+    """An accelerator never runs a kernel interpreted: asking for it
+    through the lane backend, the env var or the argument raises, and
+    "auto" resolves to the compiled kernels."""
+    from repro.kernels.backend import default_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert S.resolve_lane_backend("auto") == "pallas"
+    assert S.resolve_lane_backend("pallas") == "pallas"
+    with pytest.raises(ValueError):
+        S.resolve_lane_backend("pallas-interpret")
+    assert default_interpret() is False
+    with pytest.raises(ValueError):
+        default_interpret(True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError):
+        default_interpret()

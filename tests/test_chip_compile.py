@@ -1,0 +1,94 @@
+"""The main path's lane kernels compile for a TPU v5e, without the chip.
+
+Interpret mode (every other kernel test) runs the kernels' math as XLA
+ops on the CPU, so it cannot see what the TPU kernel compiler refuses:
+primitives Mosaic has no lowering for, integer matmuls and index
+reductions, block shapes it cannot lay out.  These tests compile the
+kernels and both batched runners for a described v5e chip at the paper's
+8x8 geometry, with argument shapes placed on one of its devices, and check
+that a Pallas kernel (``tpu_custom_call``) is in the compiled program.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library.  JAX's persistent compilation
+cache is off around these compiles, since an entry compiled for a chip
+that is not attached cannot be read back here.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.topology import build_mesh
+from repro.kernels.scout_step import (LINK_PAD, STATE_W, pack_tables,
+                                      scout_step_pallas)
+from repro.ssd import perf_optimized
+from repro.ssd import sim as S
+from repro.ssd.designs import lower_designs
+
+B = 512  # two lane tiles of the kernels' largest block
+CAPACITY = S.CHUNK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _place(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("per_scout_allow", [False, True])
+def test_scout_kernel_compiles(one_chip, no_jax_cache,
+                               per_scout_allow):
+    """Both kernel variants: a compile-time and a per-scout
+    ``allow_nonminimal``."""
+    tables = pack_tables(build_mesh(8, 8))
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((B, STATE_W), (B, LINK_PAD), (B, 4 * tables.shape[0]),
+                      tables.shape)]
+    if per_scout_allow:
+        args.append(jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip))
+
+    def step(*a):
+        return scout_step_pallas(*a, cols=8, n_nodes=64, interpret=False)
+
+    _assert_kernel(jax.jit(step).lower(*args).compile())
+
+
+@pytest.mark.parametrize("variant", ["batched", "bscout"])
+def test_batched_runner_compiles(one_chip, no_jax_cache, variant):
+    """The batched static and scout runners the occupancy planner
+    dispatches on an accelerator, with the compiled Pallas backend."""
+    cfg = perf_optimized()
+    sig = S._geom_sig(cfg)
+    per_shard = B // 2
+    if variant == "batched":
+        fixed = (None,) * len(S._PROMOTABLE)
+        key = S.batched_group_key(sig, CAPACITY, per_shard, fixed, 1,
+                                  "pallas")
+    else:
+        fixed = S._promotions(lower_designs(cfg, ("venice",)))
+        key = S.bscout_group_key(sig, CAPACITY, per_shard, 1, fixed, 1,
+                                 "pallas")
+    avatars = _place(S._avatars_for_key(key), one_chip)
+    _assert_kernel(S._fn_for_key(key).lower(*avatars).compile())

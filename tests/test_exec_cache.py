@@ -16,6 +16,7 @@ import pickle
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -36,8 +37,8 @@ def _digest(sweep) -> str:
 
 
 @pytest.fixture()
-def xc_dir(tmp_path, monkeypatch):
-    """A fresh store for this test only (the session dir stays warm)."""
+def xc_dir(tmp_path, monkeypatch, no_jax_cache):
+    """A fresh store for this test only."""
     d = str(tmp_path / "xc")
     monkeypatch.setenv("REPRO_XC_DIR", d)
     exec_cache.flush()  # other tests' queued stores keep out of STATS
@@ -46,6 +47,14 @@ def xc_dir(tmp_path, monkeypatch):
     yield d
     S.clear_exec_cache()
     exec_cache.reset_stats()
+
+
+def test_store_yields_to_jax_cache(tmp_path, monkeypatch):
+    """With JAX's persistent cache on (the default), the opted-in store
+    stays disabled: the two caches exclude each other."""
+    monkeypatch.setenv("REPRO_XC_DIR", str(tmp_path / "xc"))
+    assert jax.config.jax_enable_compilation_cache
+    assert exec_cache.cache_dir() is None
 
 
 def test_store_roundtrip_bit_identical(tiny_cfg, tiny_txns, xc_dir):
@@ -163,7 +172,8 @@ print("RESULT", json.dumps({
     "digest": h.hexdigest(), "speedups": speedups,
     "stats": exec_cache.STATS}))
 """
-    env = dict(os.environ, REPRO_XC_DIR=xc, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, REPRO_XC_DIR=xc, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false")
 
     def run_once():
         out = subprocess.run([sys.executable, "-c", script], env=env,

@@ -147,7 +147,8 @@ def test_straggler_rule_flags_wedged_key():
 
 
 def test_run_completes_after_worker_sigkill(tiny_cfg, tmp_path,
-                                            monkeypatch, server_state):
+                                            monkeypatch, server_state,
+                                            no_jax_cache):
     """End-to-end acceptance: kill the real compile server the moment it
     is spawned mid-preset; the streamed run must complete and be
     bit-identical to the clean rerun."""

@@ -174,10 +174,9 @@ for lane in sweep:
 print("DIGEST", h.hexdigest())
 """
     env = dict(os.environ)
-    env["XLA_FLAGS"] = " ".join(  # a stock environment: 1 device, default
-        f for f in env.get("XLA_FLAGS", "").split()  # (thunk) CPU runtime
+    env["XLA_FLAGS"] = " ".join(  # a stock environment: 1 device
+        f for f in env.get("XLA_FLAGS", "").split()
         if "--xla_force_host_platform_device_count" not in f
-        and "--xla_cpu_use_thunk_runtime" not in f
     )
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
