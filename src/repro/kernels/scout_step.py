@@ -298,4 +298,5 @@ def scout_step_pallas(
             jax.ShapeDtypeStruct((B, T), jnp.int32),
         ],
         interpret=interpret,
+        name="scout_step_pallas",
     )(*operands)

@@ -1,9 +1,9 @@
-"""Unified observability: device flight recorder + harness telemetry.
+"""Unified observability: SSD flight recorder + harness telemetry.
 
 Two layers exporting into ONE Chrome-trace-event JSON (Perfetto /
 ``chrome://tracing`` loadable):
 
-* **Layer 1 — device flight recorder** (``events.py``): per-transaction
+* **Layer 1 — SSD flight recorder** (``events.py``): per-transaction
   timelines and per-resource occupancy intervals, reconstructed *host-side*
   from the scan's existing ``StepOut`` arrays after execution.  The jitted
   step carries nothing new — executables, cache keys and every figure CSV
@@ -43,21 +43,27 @@ XC_SPANS_ENV = "REPRO_XC_SPANS"
 
 
 def enable_tracing(max_txn_events: int | None = None,
-                   xc_sidecar: str | None = None) -> None:
-    """Arm both layers: install the global device recorder and span tracer.
+                   xc_sidecar: str | None = None,
+                   recorder: bool = True) -> _spans.SpanTracer:
+    """Arm both layers: install the global SSD recorder and span tracer,
+    and return the span tracer.
 
-    ``max_txn_events`` caps the number of per-transaction device events
+    ``max_txn_events`` caps the number of per-transaction SSD events
     retained (runs past the cap are recorded as dropped, never silently
     truncated mid-run).  ``xc_sidecar`` (a file path) additionally asks any
     compile server spawned after this call to log its compile spans there.
+    ``recorder=False`` arms the span tracer alone: timing a sweep against
+    a profiler trace must not also pay for per-transaction recording.
     """
-    kwargs = {}
-    if max_txn_events is not None:
-        kwargs["max_txns"] = max_txn_events
-    _events.RECORDER = _events.DeviceRecorder(**kwargs)
+    if recorder:
+        kwargs = {}
+        if max_txn_events is not None:
+            kwargs["max_txns"] = max_txn_events
+        _events.RECORDER = _events.DeviceRecorder(**kwargs)
     _spans.TRACER = _spans.SpanTracer()
     if xc_sidecar is not None:
         os.environ[XC_SPANS_ENV] = xc_sidecar
+    return _spans.TRACER
 
 
 def disable_tracing() -> None:
@@ -73,7 +79,7 @@ def tracing_enabled() -> bool:
 
 def export_trace(path: str, heatmap_csv: str | None = None,
                  bucket_us: float | None = None) -> dict:
-    """Write the combined trace JSON (device + harness tracks) to ``path``.
+    """Write the combined trace JSON (SSD + harness tracks) to ``path``.
 
     Returns a summary dict (event/track counts).  ``heatmap_csv`` also
     writes the resource x time-bucket utilization/conflict matrices
@@ -90,7 +96,7 @@ def export_trace(path: str, heatmap_csv: str | None = None,
     recorder = _events.RECORDER
     runs = recorder.finalized_runs() if recorder is not None else []
     for run in runs:
-        builder.add_device_run(run)
+        builder.add_ssd_run(run)
     summary = builder.write(path)
     if heatmap_csv is not None:
         _heatmap.write_heatmap_csv(heatmap_csv, runs, bucket_us=bucket_us)

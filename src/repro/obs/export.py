@@ -6,11 +6,12 @@ One file carries both layers:
   so B/E pairs nest properly): plan/compile/dispatch/stream/scenario/
   watchdog events, plus compile-server spans merged from the ``xc_worker``
   sidecar (epoch-stamped, rebased onto the tracer's wall-clock anchor).
-* **pid 10+k, one per device run** — the flight recorder's reconstruction:
-  per-plane transaction slices (``tid = plane``; the scan serializes each
-  plane, so slices never overlap within a row), chip-occupancy tracks
-  (``tid = 10000 + node``) and, for shared-bus designs, channel-bus tracks
-  (``tid = 20000 + row``).  Device timestamps are ticks converted to
+* **pid 10+k, one per simulated SSD run** (``ssd: <design>``) — the
+  flight recorder's reconstruction: per-plane transaction slices
+  (``tid = plane``; the scan serializes each plane, so slices never
+  overlap within a row), chip-occupancy tracks (``tid = 10000 + node``)
+  and, for shared-bus designs, channel-bus tracks
+  (``tid = 20000 + row``).  SSD timestamps are ticks converted to
   microseconds (``ticks * TICK_NS / 1e3``).
 
 ``validate_trace`` is the schema checker shared by the test suite and the
@@ -31,7 +32,7 @@ from repro.ssd.config import TICK_NS
 __all__ = ["TraceBuilder", "validate_trace", "main"]
 
 HARNESS_PID = 1
-DEVICE_PID0 = 10
+SSD_PID0 = 10
 _TID_CHIP = 10_000
 _TID_CHAN = 20_000
 
@@ -39,10 +40,10 @@ _US_PER_TICK = TICK_NS / 1e3
 
 
 class TraceBuilder:
-    def __init__(self, max_device_events: int = 2_000_000):
+    def __init__(self, max_ssd_events: int = 2_000_000):
         self.events: list[dict] = []
-        self.max_device_events = max_device_events
-        self._device_pid = DEVICE_PID0
+        self.max_ssd_events = max_ssd_events
+        self._ssd_pid = SSD_PID0
         self._harness_tids: dict = {}
         self._meta: list[dict] = []
 
@@ -145,20 +146,20 @@ class TraceBuilder:
             n += 1
         return n
 
-    # ---- layer 1: device runs ------------------------------------------
-    def add_device_run(self, run: dict) -> None:
+    # ---- layer 1: SSD runs ------------------------------------------
+    def add_ssd_run(self, run: dict) -> None:
         """One finalized flight-recorder run -> transaction + occupancy
         tracks (see ``events.derive_timeline`` for the reconstruction)."""
-        pid = self._device_pid
-        self._device_pid += 1
-        label = f"device: {run['design']}"
+        pid = self._ssd_pid
+        self._ssd_pid += 1
+        label = f"ssd: {run['design']}"
         if run["label"]:
             label += f" [{run['label']}]"
         self._name(pid, 0, label, None)
         n = run["n"]
         if n == 0:
             return
-        if n > self.max_device_events:
+        if n > self.max_ssd_events:
             self._instant(pid, 1, "run_dropped", 0.0, "meta",
                           {"n_txns": int(n)})
             return
@@ -252,7 +253,7 @@ class TraceBuilder:
             "path": path,
             "n_events": len(events),
             "n_txn": sum(1 for e in self.events if e.get("cat") == "txn"),
-            "n_device_pids": self._device_pid - DEVICE_PID0,
+            "n_ssd_pids": self._ssd_pid - SSD_PID0,
             "n_harness_tracks": len(self._harness_tids),
         }
 

@@ -12,12 +12,15 @@ speedup magnitudes are validated on the accelerated replays.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
+import time
 from typing import Dict, Iterable
 
 import numpy as np
 
+from repro.obs import spans as obs_spans
 from repro.obs.registry import MetricsRegistry, PerfDict
 from repro.ssd.config import SSDConfig
 from repro.ssd.ftl import Transactions, decompose_trace
@@ -99,6 +102,15 @@ METRICS.gauge("devices_used", 0)
 METRICS.timer("compile_s")
 METRICS.timer("exec_s")
 METRICS.object("groups", [])
+# host stages of a planned batch around the chip's work, each also a
+# program span (``stage``; ``sim._run_compiled``): lowering runs to lanes
+# and planning groups, packing a group's arguments, enqueueing their copy
+# to the device (``put_bytes``: the bytes placed; the copy itself runs
+# inside ``exec_s``), reading the outputs back once the chip is done
+# (inside ``exec_s``), unpacking lane outputs into results
+for _t in ("lower_s", "pack_s", "put_s", "fetch_s", "unpack_s"):
+    METRICS.timer(_t)
+METRICS.counter("put_bytes")
 # warm-path execution backend (DESIGN.md §2.2): persistent-executable
 # store telemetry (hits/misses/errors/stores mirrored from
 # ``exec_cache.STATS``, plus deserialize wall-clock) and the overlapped
@@ -143,12 +155,21 @@ METRICS.object("phase_cache", {})
 # scaling (satellite: the factor used to be computed and dropped by
 # ``run_workload`` callers, leaving replays unauditable).
 METRICS.object("accel", {})
-# workload ingestion (ISSUE 9 satellite): rows skipped by
-# ``ingest.load_trace(on_error="skip")`` across the process — nonzero
-# counts also emit a warning naming the file (see ``workloads/ingest.py``)
-METRICS.counter("ingest_skipped_rows")
 
 PERF: PerfDict = METRICS.view()
+
+
+@contextlib.contextmanager
+def stage(timer: str, name: str):
+    """Time a host stage of the planner into ``PERF[timer]`` and record
+    it as the program span ``name`` (main thread only: no lock)."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        t1 = time.perf_counter()
+        PERF[timer] += t1 - t0
+        obs_spans.interval("plan", name, t0, t1)
 
 # The FTL engine the harness decomposes with ("auto" | "vector" | "scalar");
 # benchmarks/run.py --ftl-engine flips this for A/B perf runs.

@@ -1854,18 +1854,37 @@ def _put_args(args, specs, n_shards: int):
 
 def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
                   capacity: int, n_shards: int, has_scout: bool,
-                  steps: int) -> tuple:
-    """Shared execute-and-report body of the three group runners:
-    resolve the executable, place the arguments, dispatch, and record the
-    per-group attribution (variant/cache source/compile-load-exec split;
-    ``steps`` is the executed-step count incl. padding waste)."""
+                  steps: int, t_pack: float | None = None) -> tuple:
+    """Shared execute-and-report body of the group runners: resolve the
+    executable, place the arguments, dispatch, and record the per-group
+    attribution (variant/cache source/compile-load-exec split; ``steps``
+    is the executed-step count incl. padding waste; ``put_bytes`` the
+    bytes placed on the devices).
+
+    ``t_pack`` is when the caller began packing the arguments
+    (``time.perf_counter``): packing ends here.  The put only enqueues
+    the copies to the devices (``put_s``); the copy itself, the run until
+    the outputs are ready, and the fetch of the outputs to the host make
+    up ``exec_s``."""
+    t_enter = time.perf_counter()
+    if t_pack is not None:
+        obs_spans.interval("plan", "pack", t_pack, t_enter)
     compiled, dt, src = ensure_compiled(key)
+    t_put = time.perf_counter()
     args = _put_args(args, specs, n_shards)
     t0 = time.perf_counter()
+    obs_spans.interval("exec", "put", t_put, t0)
     with obs_spans.span("exec", f"exec:{key[0]}", lanes=lanes,
                         shards=n_shards, steps=steps * CHUNK):
-        outs = jax.device_get(compiled(*args))
+        with obs_spans.span("exec", "run"):
+            outs = jax.block_until_ready(compiled(*args))
+        t_ready = time.perf_counter()
+        with obs_spans.span("exec", "fetch"):
+            outs = jax.device_get(outs)
+        fetch_s = time.perf_counter() - t_ready
     exec_s = time.perf_counter() - t0
+    put_s = t0 - t_put
+    put_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(args))
     kb = kernel_backend_of_key(key)
     perf = {
         "variant": key[0], "lanes": lanes, "capacity": capacity,
@@ -1875,6 +1894,7 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
         "compile_s": round(dt if src == "build" else 0.0, 3),
         "load_s": round(dt if src == "disk" else 0.0, 3),
         "exec_s": round(exec_s, 3),
+        "put_bytes": put_bytes,
     }
     from repro.ssd import bench
 
@@ -1892,18 +1912,25 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
             share_key = ("steps_batched" if key[0] == "batched"
                          else "steps_unbatched")
         bench.PERF[share_key] += steps * CHUNK
+        if t_pack is not None:
+            bench.PERF["pack_s"] += t_enter - t_pack
+        bench.PERF["put_s"] += put_s
+        bench.PERF["fetch_s"] += fetch_s
+        bench.PERF["put_bytes"] += put_bytes
     return outs, perf
 
 
 def run_group(sig: tuple, tables, seeds, txns: TxnArrays, n_chunks,
               k_max: int, has_scout: bool, fixed: tuple,
-              n_shards: int, K: int = 0) -> tuple:
+              n_shards: int, K: int = 0,
+              t_pack: float | None = None) -> tuple:
     """Execute one lane group; returns (StepOut [G, cap], perf).
 
     ``tables``/``txns`` carry a leading lane axis [G] (numpy trees);
     ``seeds``/``n_chunks`` are [G] arrays.  ``K == 0``: one unbatched
     lane per shard (G == n_shards); ``K > 0``: the stacked layout, K
-    sequential lanes per shard (G == n_shards*K).
+    sequential lanes per shard (G == n_shards*K).  ``t_pack``: see
+    :func:`_run_compiled`.
     """
     G = int(len(seeds))
     capacity = int(np.asarray(txns.arrival).shape[1])
@@ -1917,7 +1944,7 @@ def run_group(sig: tuple, tables, seeds, txns: TxnArrays, n_chunks,
     return _run_compiled(
         key, (tables, np.asarray(seeds, np.uint32), txns, ncs),
         (P("lanes"),) * 4, lanes=G, capacity=capacity, n_shards=n_shards,
-        has_scout=has_scout, steps=int(ncs.sum()),
+        has_scout=has_scout, steps=int(ncs.sum()), t_pack=t_pack,
     )
 
 
@@ -2001,7 +2028,8 @@ def run_group_carry(sig: tuple, tables, state, txns: TxnArrays, n_chunks,
 def run_batched_group(sig: tuple, scal: BatchScalars, txns: TxnArrays,
                       bt: BatchTxnTables, n_chunks, fixed: tuple,
                       n_shards: int, per_shard: int,
-                      backend: str = "xla") -> tuple:
+                      backend: str = "xla",
+                      t_pack: float | None = None) -> tuple:
     """Execute one batched static group; returns (StepOut [cap, B], perf).
 
     ``txns``/``bt`` are time-major numpy trees [cap, B, ...]; ``scal`` and
@@ -2010,6 +2038,7 @@ def run_batched_group(sig: tuple, scal: BatchScalars, txns: TxnArrays,
     batch's padding waste, kept visible in ``steps``).  ``backend`` picks
     the lane-step kernel (a resolved name from
     :func:`resolve_lane_backend`); every backend is bit-exact.
+    ``t_pack``: see :func:`_run_compiled`.
     """
     B = int(np.asarray(n_chunks).shape[0])
     capacity = int(np.asarray(txns.arrival).shape[0])
@@ -2024,7 +2053,7 @@ def run_batched_group(sig: tuple, scal: BatchScalars, txns: TxnArrays,
         (scal, txns, bt, ncs),
         (P("lanes"), P(None, "lanes"), P(None, "lanes"), P("lanes")),
         lanes=B, capacity=capacity, n_shards=n_shards, has_scout=False,
-        steps=shard_steps,
+        steps=shard_steps, t_pack=t_pack,
     )
 
 
@@ -2032,13 +2061,15 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
                             txns: TxnArrays, tt: ScoutBatchTxnTables,
                             n_chunks, k_max: int, fixed: tuple,
                             n_shards: int, per_shard: int,
-                            backend: str = "xla") -> tuple:
+                            backend: str = "xla",
+                            t_pack: float | None = None) -> tuple:
     """Execute one batched scout group; returns (StepOut [cap, B], perf).
 
     Same layout contract as :func:`run_batched_group` plus the per-lane
     rng ``seeds`` [B] (the scout state's fifth leg) and ``k_max`` (the
     group's raced-scout ceiling — lanes below it are masked per their
-    ``n_scouts``).  Every backend is bit-exact.
+    ``n_scouts``).  Every backend is bit-exact.  ``t_pack``: see
+    :func:`_run_compiled`.
     """
     B = int(np.asarray(n_chunks).shape[0])
     capacity = int(np.asarray(txns.arrival).shape[0])
@@ -2054,7 +2085,7 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
         (P("lanes"), P("lanes"), P(None, "lanes"), P(None, "lanes"),
          P("lanes")),
         lanes=B, capacity=capacity, n_shards=n_shards, has_scout=True,
-        steps=shard_steps,
+        steps=shard_steps, t_pack=t_pack,
     )
 
 

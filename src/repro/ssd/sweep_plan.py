@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import os
 import threading
 import time
@@ -704,7 +705,12 @@ def _plan_pool_cpu(sig: tuple, lanes: list, has_scout: bool) -> list:
 
 
 def _dispatch(plan: _GroupPlan) -> dict:
-    """Stack one plan's arguments, execute it, and scatter lane outputs."""
+    """Stack one plan's arguments, execute it, and scatter lane outputs.
+
+    Packing (``pack_s``) runs from here until ``sim._run_compiled`` is
+    entered (``t_pack``); unpacking (``unpack_s``) from the runner's
+    return."""
+    t_pack = time.perf_counter()
     lanes, cap = plan.lanes, plan.cap
     if plan.variant in ("lane", "stack"):
         tables = LaneTables(
@@ -722,13 +728,9 @@ def _dispatch(plan: _GroupPlan) -> dict:
             plan.sig, tables, seeds, txns, ncs, plan.k_max,
             plan.has_scout, plan.fixed, plan.n_shards,
             K=(plan.per_shard if plan.variant == "stack" else 0),
+            t_pack=t_pack,
         )
-        seen = set()
-        for j, ln in enumerate(lanes):
-            if id(ln) in seen:  # padding duplicate — outputs discarded
-                continue
-            seen.add(id(ln))
-            ln.out = S.StepOut(*(np.asarray(a)[j] for a in outs))
+        lane_axis = 0
     elif plan.variant == "bscout":
         B = len(lanes)
         scal = S.ScoutBatchScalars(
@@ -763,13 +765,9 @@ def _dispatch(plan: _GroupPlan) -> dict:
         outs, perf = S.run_batched_scout_group(
             plan.sig, scal, seeds, txns, tt, ncs, plan.k_max,
             plan.fixed, plan.n_shards, plan.per_shard, plan.backend,
+            t_pack=t_pack,
         )
-        seen = set()
-        for j, ln in enumerate(lanes):
-            if id(ln) in seen:
-                continue
-            seen.add(id(ln))
-            ln.out = S.StepOut(*(np.asarray(a)[:, j] for a in outs))
+        lane_axis = 1
     else:
         B = len(lanes)
         scal = S.BatchScalars(
@@ -812,13 +810,17 @@ def _dispatch(plan: _GroupPlan) -> dict:
         ncs = np.asarray([ln.n_chunks for ln in lanes], np.int32)
         outs, perf = S.run_batched_group(plan.sig, scal, txns, bt, ncs,
                                          plan.fixed, plan.n_shards,
-                                         plan.per_shard, plan.backend)
+                                         plan.per_shard, plan.backend,
+                                         t_pack=t_pack)
+        lane_axis = 1
+    with bench.stage("unpack_s", "unpack"):
+        fields = [np.moveaxis(np.asarray(a), lane_axis, 0) for a in outs]
         seen = set()
         for j, ln in enumerate(lanes):
-            if id(ln) in seen:
+            if id(ln) in seen:  # padding duplicate — outputs discarded
                 continue
             seen.add(id(ln))
-            ln.out = S.StepOut(*(np.asarray(a)[:, j] for a in outs))
+            ln.out = S.StepOut(*(f[j] for f in fields))
     perf["lanes"] = len(seen)
     return perf
 
@@ -976,10 +978,11 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
     ``sim.simulate`` of that lane alone.
     """
     runs = list(runs)
-    prepared, pools = _lower_runs(runs)
-    plans = []
-    for (sig, scout), lanes in pools.items():
-        plans.extend(_plan_pool(sig, lanes, scout))
+    with bench.stage("lower_s", "lower"):
+        prepared, pools = _lower_runs(runs)
+        plans = []
+        for (sig, scout), lanes in pools.items():
+            plans.extend(_plan_pool(sig, lanes, scout))
     all_groups = _execute_plans(plans)
 
     # ---- PERF accounting (bench.PERF is the process-wide scoreboard) ----
@@ -1000,8 +1003,13 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
     # mirror the persistent-store telemetry (absolute, process-wide)
     for k, v in exec_cache.STATS.items():
         perf[f"xc_{k}"] = v
+    with bench.stage("unpack_s", "unpack"):
+        return _merge_runs(runs, prepared, pools)
 
-    # ---- merge lanes back into per-run SimResults ----
+
+def _merge_runs(runs: list, prepared: list, pools: dict) -> list:
+    """Merge lane outputs back into per-run SimResults."""
+    perf = bench.PERF
     results: list = []
     by_run: dict = {}
     for lanes in pools.values():
@@ -1045,6 +1053,10 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
     return results
 
 
+# ids of planned batches (``execute_requests`` calls), for their spans
+_BATCH_IDS = itertools.count(1)
+
+
 def _request_key(rq: RunRequest) -> tuple:
     return (rq.name, rq.cfg, rq.designs, rq.n_requests, rq.target_util,
             rq.seed)
@@ -1062,17 +1074,19 @@ def _sims_for(requests: Sequence[RunRequest]) -> tuple:
     sims, meta = [], []
     for rq in requests:
         n_req = rq.n_requests or default_n_requests(rq.name)
-        trace = trace_for(rq.name, n_req, rq.seed)
-        accel = 1.0
-        offered = bench.offered_utilization(trace, rq.cfg)
-        if rq.target_util is not None:
-            trace, accel = bench.accelerate(trace, rq.cfg, rq.target_util)
-        bench.record_accel(rq.name, rq.cfg, accel, offered, rq.target_util)
-        pages = to_pages(trace, rq.cfg.page_bytes)
-        t0 = time.perf_counter()
-        txns = bench.decompose_cached(rq.cfg, pages,
-                                      int(pages["footprint_pages"]))
-        bench.PERF["ftl_s"] += time.perf_counter() - t0
+        with obs_spans.span("plan", "trace"):
+            trace = trace_for(rq.name, n_req, rq.seed)
+            accel = 1.0
+            offered = bench.offered_utilization(trace, rq.cfg)
+            if rq.target_util is not None:
+                trace, accel = bench.accelerate(trace, rq.cfg,
+                                                rq.target_util)
+            bench.record_accel(rq.name, rq.cfg, accel, offered,
+                               rq.target_util)
+            pages = to_pages(trace, rq.cfg.page_bytes)
+        with bench.stage("ftl_s", "ftl"):
+            txns = bench.decompose_cached(rq.cfg, pages,
+                                          int(pages["footprint_pages"]))
         seeds = ((rq.seed + 7),) * len(rq.designs)
         sims.append((rq.cfg, txns, rq.designs, seeds, "auto"))
         meta.append((accel, txns))
@@ -1082,11 +1096,13 @@ def _sims_for(requests: Sequence[RunRequest]) -> tuple:
 def execute_requests(requests: Sequence[RunRequest]) -> list:
     """Trace + decompose + simulate a batch of workload requests as one
     planned execution; results are inserted into ``bench._RUN_CACHE`` under
-    the exact keys ``bench.run_workload`` uses."""
-    sims, meta = _sims_for(requests)
-    t0 = time.perf_counter()
-    all_results = execute_sim_runs(sims)
-    bench.PERF["sim_s"] += time.perf_counter() - t0
+    the exact keys ``bench.run_workload`` uses.  Every program span of the
+    batch carries its ``batch`` id."""
+    with obs_spans.batch(next(_BATCH_IDS)):
+        sims, meta = _sims_for(requests)
+        t0 = time.perf_counter()
+        all_results = execute_sim_runs(sims)
+        bench.PERF["sim_s"] += time.perf_counter() - t0
     out = []
     # a prefetched phase reads the whole batch back AFTER this returns, so
     # the batch must survive in the LRU together — insert with a cap at

@@ -253,13 +253,8 @@ _WARNED_SKIPS: set = set()
 
 
 def _report_skipped(path: str, count: int) -> None:
-    """Surface silently-dropped rows: one warning per file per process,
-    plus the ``ingest_skipped_rows`` counter in ``bench.PERF`` (always
-    incremented, so harness telemetry sees every drop even after the
-    warning deduplicates)."""
-    from repro.ssd import bench  # lazy: keep ingest importable without jax
-
-    bench.PERF["ingest_skipped_rows"] += count
+    """Surface silently-dropped rows: one warning per file per process
+    (the loaded trace's ``skipped_rows`` counts every drop)."""
     if path not in _WARNED_SKIPS:
         _WARNED_SKIPS.add(path)
         warnings.warn(

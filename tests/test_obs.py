@@ -25,7 +25,7 @@ import pytest
 from repro import obs
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
-from repro.obs.export import DEVICE_PID0, HARNESS_PID, TraceBuilder, validate_trace
+from repro.obs.export import SSD_PID0, HARNESS_PID, TraceBuilder, validate_trace
 from repro.obs.heatmap import bucket_matrix, run_heatmaps
 from repro.obs.registry import MetricsRegistry
 from repro.ssd import bench, decompose_trace
@@ -87,7 +87,8 @@ class TestRegistry:
         # BENCH_*.json schema reads these directly
         for key in ("ftl_s", "sim_s", "compile_s", "exec_s", "groups",
                     "xc_hits", "stream_windows", "kernel_backends",
-                    "phase", "accel", "ingest_skipped_rows"):
+                    "phase", "accel", "lower_s", "pack_s", "put_s",
+                    "fetch_s", "unpack_s", "put_bytes"):
             assert key in bench.PERF, key
         snap = bench.PERF.snapshot()
         bench.PERF["decomp_hits"] += 1
@@ -170,7 +171,7 @@ class TestTraceExport:
         summary = validate_trace(path)  # raises on any schema violation
         n_txns = len(tiny_txns["arrival"])
         assert summary["n_txn"] == n_txns * len(designs)
-        assert info["n_device_pids"] == len(designs)
+        assert info["n_ssd_pids"] == len(designs)
         # the planner emits its own dispatch spans on top of the two
         # explicit ones; pairs always balance
         assert summary["counts"]["B"] == summary["counts"]["E"] >= 2
@@ -178,7 +179,12 @@ class TestTraceExport:
             doc = json.load(fh)
         pids = {e["pid"] for e in doc["traceEvents"] if e["ph"] != "M"}
         assert HARNESS_PID in pids
-        assert {DEVICE_PID0, DEVICE_PID0 + 1} <= pids
+        assert {SSD_PID0, SSD_PID0 + 1} <= pids
+        # the simulated SSD's tracks say so: "device" is left to the chip
+        names = {e["pid"]: e["args"]["name"] for e in doc["traceEvents"]
+                 if e["ph"] == "M" and e["name"] == "process_name"}
+        assert {names[SSD_PID0], names[SSD_PID0 + 1]} == {
+            f"ssd: {d} [run0]" for d in designs}
         # heatmap CSV: header + at least one nonzero utilization cell
         lines = (tmp_path / "h.csv").read_text().strip().split("\n")
         assert lines[0] == ("run,design,metric,resource,bucket,"
@@ -242,23 +248,23 @@ class TestTraceExport:
         for f in obs_events._ARRAY_FIELDS:
             assert np.array_equal(mono[f], streamed[f]), f
         assert mono["scalars"] == streamed["scalars"]
-        # and the rendered device events agree too
+        # and the rendered SSD events agree too
         path_m = str(tmp_path / "m.json")
         bm = TraceBuilder()
-        bm.add_device_run(mono)
+        bm.add_ssd_run(mono)
         bm.write(path_m)
         path_s = str(tmp_path / "s.json")
         bs = TraceBuilder()
-        bs.add_device_run(streamed)
+        bs.add_ssd_run(streamed)
         bs.write(path_s)
 
-        def device_events(path):
+        def ssd_events(path):
             with open(path) as fh:
                 evs = json.load(fh)["traceEvents"]
             return [{k: v for k, v in e.items() if k != "pid"}
                     for e in evs if e["ph"] != "M"]
 
-        assert device_events(path_m) == device_events(path_s)
+        assert ssd_events(path_m) == ssd_events(path_s)
 
 
 # ---------------------------------------------------------------------------
@@ -318,6 +324,140 @@ class TestScenarioPerfIsolation:
         assert first == second  # records stay bit-identical (no perf keys)
 
 
+# ---------------------------------------------------------------------------
+# program spans and stage timers of a planned batch
+# ---------------------------------------------------------------------------
+
+STAGE_TIMERS = ("lower_s", "pack_s", "put_s", "fetch_s", "unpack_s")
+
+
+def _requests(cfg):
+    from repro.ssd.sweep_plan import RunRequest
+
+    return [RunRequest("hm_0", cfg, ("baseline", "pssd", "venice"), 40,
+                       1.5, seed) for seed in (0, 1)]
+
+
+def _execute(cfg):
+    """One planned batch from cold run caches: (results, PERF delta, the
+    batch's new group records)."""
+    from repro.ssd.sweep_plan import execute_requests
+
+    bench.clear_caches()
+    before = bench.PERF.snapshot()
+    runs = execute_requests(_requests(cfg))
+    groups = bench.PERF["groups"][len(before["groups"]):]
+    return runs, bench.PERF.delta(before), groups
+
+
+class TestProgramSpans:
+    def test_tracer_clock_maps_to_epoch_ns(self):
+        import time
+
+        lo = time.time_ns()
+        tr = obs_spans.SpanTracer()
+        hi = time.time_ns()
+        assert lo <= tr.t0_wall_ns <= hi
+        assert tr.t0_wall == pytest.approx(tr.t0_wall_ns * 1e-9)
+        assert tr.epoch_ns(0.0) == tr.t0_wall_ns
+        assert tr.epoch_ns(1.5) == tr.t0_wall_ns + 1500
+        assert tr.perf_us(tr.t0_perf + 2e-3) == pytest.approx(2e3)
+
+    def test_interval_and_batch_tags(self):
+        tr = obs.enable_tracing(recorder=False)
+        assert obs_events.RECORDER is None and obs_spans.TRACER is tr
+        with obs_spans.batch(7):
+            obs_spans.interval("plan", "pack", tr.t0_perf + 1e-3,
+                               tr.t0_perf + 3e-3, lanes=2)
+            with obs_spans.batch(8):
+                with obs_spans.span("exec", "run"):
+                    pass
+        obs_spans.instant("t", "outside")
+        evs = {e[2]: e for e in tr.drain()}
+        assert evs["pack"][3] == pytest.approx(1e3)
+        assert evs["pack"][4] == pytest.approx(2e3)
+        assert evs["pack"][5] == {"lanes": 2, "batch": 7}
+        assert evs["run"][5] == {"batch": 8}
+        assert evs["outside"][5] is None
+        obs.disable_tracing()
+        obs_spans.interval("plan", "pack", 0.0, 1.0)  # disarmed: no-op
+
+    def test_stage_timers_lie_inside_sim_s(self, tiny_cfg):
+        _, d, groups = _execute(tiny_cfg)
+        assert groups
+        for k in STAGE_TIMERS + ("ftl_s", "exec_s"):
+            assert d[k] >= 0.0, k
+        assert d["lower_s"] > 0 and d["put_s"] > 0 and d["unpack_s"] > 0
+        # exec_s sums group times rounded to the millisecond
+        slack = 0.0005 * len(groups)
+        inside = sum(d[k] for k in ("lower_s", "pack_s", "put_s",
+                                    "unpack_s", "exec_s"))
+        assert inside <= d["sim_s"] + slack
+        assert d["fetch_s"] <= d["exec_s"] + slack
+
+    def test_put_bytes_is_the_bytes_of_each_groups_arguments(
+            self, tiny_cfg, monkeypatch):
+        import jax
+        from repro.ssd import sim as S
+
+        placed = []
+        put = S._put_args
+
+        def counting_put(args, specs, n_shards):
+            placed.append(sum(
+                np.asarray(a).size
+                * jax.dtypes.canonicalize_dtype(np.asarray(a).dtype).itemsize
+                for a in jax.tree_util.tree_leaves(args)))
+            return put(args, specs, n_shards)
+
+        monkeypatch.setattr(S, "_put_args", counting_put)
+        _, d, groups = _execute(tiny_cfg)
+        assert [g["put_bytes"] for g in groups] == placed
+        assert d["put_bytes"] == sum(placed) > 0
+
+    def test_spans_nest_and_carry_the_batch(self, tiny_cfg):
+        tr = obs.enable_tracing(recorder=False)
+        _, _, groups = _execute(tiny_cfg)
+        spans = [e for e in tr.drain() if e[0] == "span"]
+        names = [e[2] for e in spans]
+        for stage in ("trace", "ftl", "lower", "pack", "put", "run",
+                      "fetch", "unpack"):
+            assert stage in names, stage
+        assert names.count("run") == names.count("fetch") == len(groups)
+        batches = {e[5]["batch"] for e in spans}
+        assert len(batches) == 1  # one planned batch, one id
+
+        def inside(inner, outer):
+            return (outer[3] <= inner[3] and inner[3] + inner[4]
+                    <= outer[3] + outer[4] and inner[6] == outer[6])
+
+        execs = [e for e in spans if e[2].startswith("exec:")]
+        assert len(execs) == len(groups)
+        for stage in ("run", "fetch"):
+            for e in (e for e in spans if e[2] == stage):
+                assert sum(inside(e, x) for x in execs) == 1, stage
+        # pack, put, exec and the lane unpack follow each other in a group
+        for g in (e for e in spans if e[2].startswith("group:")):
+            within = [e[2] for e in spans
+                      if e is not g and inside(e, g)]
+            order = [n for n in within if n in ("pack", "put", "unpack")
+                     or n.startswith("exec:")]
+            assert order[0] == "pack" and order[1] == "put"
+            assert order[2].startswith("exec:") and order[3] == "unpack"
+
+    def test_outputs_identical_with_span_tracer_armed(self, tiny_cfg):
+        off, _, _ = _execute(tiny_cfg)
+        obs.enable_tracing(recorder=False)
+        on, _, _ = _execute(tiny_cfg)
+        for a, b in zip(off, on):
+            for d in a.results:
+                ra, rb = a.results[d], b.results[d]
+                for f in ("completion", "latency", "wait", "conflict",
+                          "hops", "tries"):
+                    assert np.array_equal(getattr(ra, f), getattr(rb, f))
+                assert ra.exec_ticks == rb.exec_ticks
+
+
 class TestIngestSkipWarning:
     def _write_fixture(self, path, n_bad=1):
         base = 129_000_000_000_000_000
@@ -332,18 +472,16 @@ class TestIngestSkipWarning:
         self._write_fixture(path, n_bad=2)
         from repro.workloads.ingest import load_trace
 
-        before = bench.PERF["ingest_skipped_rows"]
         with pytest.warns(UserWarning, match="skipped 2 corrupted rows"):
             tr = load_trace(path, on_error="skip")
         assert tr["skipped_rows"] == 2
-        assert bench.PERF["ingest_skipped_rows"] == before + 2
-        # second ingest of the same file: counter still moves, warning
-        # deduplicates
+        # second ingest of the same file: the trace still counts its
+        # drops, the warning deduplicates
         with warnings.catch_warnings(record=True) as caught:
             warnings.simplefilter("always")
-            load_trace(path, on_error="skip")
+            again = load_trace(path, on_error="skip")
         assert not [w for w in caught if "corrupt.csv" in str(w.message)]
-        assert bench.PERF["ingest_skipped_rows"] == before + 4
+        assert again["skipped_rows"] == 2
 
     def test_raise_mode_untouched(self, tmp_path):
         path = str(tmp_path / "corrupt2.csv")

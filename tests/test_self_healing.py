@@ -45,6 +45,9 @@ def server_state():
         SP._PROC = None
         SP._PROC_KEYS.clear()
         SP._WATCHDOG = None
+        # compiles an earlier test left in flight would keep their keys
+        # from ever reaching a server
+        SP._INFLIGHT.clear()
 
     reset()
     yield
