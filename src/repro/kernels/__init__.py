@@ -5,7 +5,7 @@
   jnp oracle, and the jitted batched-DFS driver around them.
 - ``batched_step``: the lane-tiled wrapper that runs the batched static
   step from ``ssd.sim`` as a ``pl.pallas_call`` (lanes on the grid,
-  pre-gathered node tables in per-instance blocks).
+  each tick's gathered node tables in per-instance blocks).
 - ``onehot``: gather-free one-hot compare-and-reduce lookups shared by
   the XLA and Pallas paths.
 - ``backend``: interpret-mode selection (Pallas has no CPU compiler, so

@@ -8,9 +8,9 @@ trailing axes only — one-hot lookups and shift-unpacked mask words
 replace every gather).  That shape is exactly a Pallas
 grid program: tile the lane axis over the grid, hand each program
 instance a ``(b_tile, ...)`` block of every operand (scalars, carried
-state, and the pre-gathered bit-packed node tables from
-``designs.pregather_node_tables``), and run the *same* step closure on
-the block.
+state, and one tick of the bit-packed node tables, which the batched run
+gathers per chunk, outside this kernel, from each lane's
+``designs.node_tables``), and run the *same* step closure on the block.
 
 ``lane_tiled_step`` is deliberately generic: it takes the step function
 built by ``sim._make_batched_static_step`` (or any step with the same

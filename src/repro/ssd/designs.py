@@ -489,17 +489,18 @@ def resolve_specs(designs: Sequence[str]) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# per-transaction pre-gathered tables (batched small-lane runner)
+# node-indexed tables of the batched small-lane runner
 #
 # The node-indexed tables (cmask/hops/dist/cand2_ok/fc_fixed) are static
-# data, and a lane's transaction stream is known before the scan — so the
-# batched runner never gathers them at runtime: every node lookup is
-# resolved HERE, host-side, into per-transaction arrays that ride the scan
-# as sliced inputs.  Only state-dependent lookups (plane free-at, live FC
+# data, so the batched static step never looks them up itself: the planner
+# ships each lane's tables node-major (``node_tables``, N rows), and the
+# batched run gathers one chunk's rows per transaction on the device,
+# outside the step (``sim._gather_node_rows``), from the chunk's
+# transaction nodes.  Only state-dependent lookups (plane free-at, live FC
 # selection) remain in the step, as one-hot compare-and-reduce
 # (``repro.kernels.onehot``).  Candidate masks are bit-packed along the
 # resource axis into little-endian 32-bit words (bit r is bit r % 32 of
-# word r // 32) to keep the [n, F_pad, 2, R] blow-up at R/8 bytes; the
+# word r // 32) to keep the [N, F_pad, 2, R] tables at R/8 bytes; the
 # step unpacks them with shifts (no gather either).
 # ---------------------------------------------------------------------------
 
@@ -509,16 +510,13 @@ def mask_words_per_row(n_bits: int) -> int:
     return -(-n_bits // 32)
 
 
-def pregather_node_tables(tables_row, nodes: np.ndarray) -> dict:
-    """Resolve one lane's node-indexed tables per transaction.
+def node_tables(tables_row) -> dict:
+    """One lane's node-indexed tables, node-major (numpy, N rows).
 
     ``tables_row``: one design's view of :class:`LaneTables` (no lane
-    axis); ``nodes``: int array [n] of the lane's transaction nodes.
-    Returns numpy arrays (lane-major, length n; the planner stacks them
-    time-major per batch):
-      ``mask_words`` int32 [n, F_pad, 2, ceil(R_pad/32)], ``hops`` int32
-      [n, F_pad, 2], ``dist`` int32 [n, F_pad], ``cand2`` bool [n],
-      ``fc_fixed`` int32 [n, 2].
+    axis).  Returns ``mask_words`` int32 [N, F_pad, 2, ceil(R_pad/32)]
+    (``cmask`` bit-packed), ``hops`` int32 [N, F_pad, 2], ``dist`` int32
+    [N, F_pad], ``cand2`` bool [N], ``fc_fixed`` int32 [N, 2].
     """
     cmask = np.asarray(tables_row.cmask)  # [F0, N, 2, R]
     packed = np.packbits(cmask, axis=-1, bitorder="little")
@@ -526,16 +524,21 @@ def pregather_node_tables(tables_row, nodes: np.ndarray) -> dict:
     packed = np.pad(packed, [(0, 0)] * 3 + [(0, n_bytes - packed.shape[-1])])
     words = np.ascontiguousarray(packed).view("<i4").astype(np.int32)
     return dict(
-        mask_words=np.ascontiguousarray(words.transpose(1, 0, 2, 3)[nodes]),
+        mask_words=np.ascontiguousarray(words.transpose(1, 0, 2, 3)),
         hops=np.ascontiguousarray(
-            np.asarray(tables_row.hops).transpose(1, 0, 2)[nodes]
-        ),
-        dist=np.ascontiguousarray(np.asarray(tables_row.dist).T[nodes]),
-        cand2=np.ascontiguousarray(np.asarray(tables_row.cand2_ok)[nodes]),
-        fc_fixed=np.ascontiguousarray(
-            np.asarray(tables_row.fc_fixed)[nodes]
-        ),
+            np.asarray(tables_row.hops).transpose(1, 0, 2)),
+        dist=np.ascontiguousarray(np.asarray(tables_row.dist).T),
+        cand2=np.asarray(tables_row.cand2_ok),
+        fc_fixed=np.asarray(tables_row.fc_fixed),
     )
+
+
+def pregather_node_tables(tables_row, nodes: np.ndarray) -> dict:
+    """One lane's node tables resolved per transaction on the host: the
+    rows of :func:`node_tables` at ``nodes`` (int [n]), each of length n.
+    The reference the device-side gather is tested against."""
+    return {k: np.ascontiguousarray(v[nodes])
+            for k, v in node_tables(tables_row).items()}
 
 
 def pregather_scout_tables(tables_row, nodes: np.ndarray) -> dict:

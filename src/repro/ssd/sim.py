@@ -871,10 +871,11 @@ def _build_stack_fn(sig: tuple, capacity: int, K: int, k_max: int,
 # batched scatters, and the validity ``cond`` a both-branches select.  The
 # batched step below contains none of those:
 #
-#   * node-indexed design tables (cmask/hops/dist/cand2/fc_fixed) are
-#     resolved per transaction HOST-SIDE (``designs.pregather_node_tables``
-#     — the stream is known before the scan) and ride the scan as sliced
-#     inputs, bit-packed along the resource axis;
+#   * node-indexed design tables (cmask/hops/dist/cand2/fc_fixed) ride in
+#     per lane, node-major and bit-packed along the resource axis
+#     (``designs.node_tables``); the run gathers each chunk's rows per
+#     transaction once, outside the inner scan (``_gather_node_rows``), so
+#     the step itself reads them as sliced inputs;
 #   * the two state-dependent lookups (plane free-at, live FC choice) are
 #     one-hot compare-and-reduce (``repro.kernels.onehot``, the scout-
 #     kernel trick) — exact for int32, no gather;
@@ -912,15 +913,45 @@ class BatchScalars(NamedTuple):
     res_dead: jnp.ndarray
 
 
-class BatchTxnTables(NamedTuple):
-    """Per-transaction pre-gathered node tables, time-major [cap, B, ...]
-    (see ``designs.pregather_node_tables``)."""
+class BatchNodeTables(NamedTuple):
+    """Per-lane node-indexed tables of a batched static group, lane-major
+    [B, N, ...] (each lane's ``designs.node_tables``)."""
 
-    mask_words: jnp.ndarray  # int32 [cap, B, F_pad, 2, ceil(R_pad/32)]
-    hops: jnp.ndarray  # int32 [cap, B, F_pad, 2]
-    dist: jnp.ndarray  # int32 [cap, B, F_pad]
-    cand2: jnp.ndarray  # bool  [cap, B]
-    fc_fixed: jnp.ndarray  # int32 [cap, B, 2]
+    mask_words: jnp.ndarray  # int32 [B, N, F_pad, 2, ceil(R_pad/32)]
+    hops: jnp.ndarray  # int32 [B, N, F_pad, 2]
+    dist: jnp.ndarray  # int32 [B, N, F_pad]
+    cand2: jnp.ndarray  # bool  [B, N]
+    fc_fixed: jnp.ndarray  # int32 [B, N, 2]
+
+
+class BatchTxnTables(NamedTuple):
+    """One chunk's node tables resolved per transaction, time-major
+    [CHUNK, B, K]: the rows of :class:`BatchNodeTables` at each
+    transaction's node, trailing axes flattened (the step reads the
+    lane-major 2D form of ``kernels.batched_step.lanes_2d``)."""
+
+    mask_words: jnp.ndarray  # int32 [CHUNK, B, F_pad * 2 * ceil(R_pad/32)]
+    hops: jnp.ndarray  # int32 [CHUNK, B, F_pad * 2]
+    dist: jnp.ndarray  # int32 [CHUNK, B, F_pad]
+    cand2: jnp.ndarray  # bool  [CHUNK, B, 1]
+    fc_fixed: jnp.ndarray  # int32 [CHUNK, B, 2]
+
+
+def _node_rows(nt: BatchNodeTables) -> BatchNodeTables:
+    """Each table [B, N, ...] as flat rows [B * N, K], lane-major."""
+    return BatchNodeTables(*(t.reshape(t.shape[0] * t.shape[1], -1)
+                             for t in nt))
+
+
+def _gather_node_rows(rows: BatchNodeTables, node) -> BatchTxnTables:
+    """``out[t, b] = rows[b * N + node[t, b]]`` for every table of
+    :func:`_node_rows`, with ``node`` [C, B] a chunk's transaction nodes:
+    one XLA gather per table.  Padding slots (node 0) read row 0, which
+    the step's validity masking ignores."""
+    B = node.shape[1]
+    idx = node + (rows.cand2.shape[0] // B) * jnp.arange(B, dtype=node.dtype)
+    return BatchTxnTables(*(jnp.take(r, idx, axis=0, mode="clip")
+                            for r in rows))
 
 
 def _make_batched_static_step(lay, n_planes: int, fixed: tuple):
@@ -1105,9 +1136,10 @@ def _make_batched_run(step, capacity: int, n_planes: int, R: int):
     returns the lane-major 2D form (:func:`kernels.batched_step.lanes_2d`);
     outputs go back to [B] vectors, the two flags to bool."""
 
-    def batch_run(sp, txns: TxnArrays, tt: BatchTxnTables, n_chunks):
+    def batch_run(sp, txns: TxnArrays, nt: BatchNodeTables, n_chunks):
         B = n_chunks.shape[0]
         sp = lanes_2d(sp)
+        rows = _node_rows(nt)
         state = (
             jnp.zeros((B, n_planes), jnp.int32),
             tuple(jnp.zeros((B, R), jnp.int32) for _ in range(3)),
@@ -1123,8 +1155,8 @@ def _make_batched_run(step, capacity: int, n_planes: int, R: int):
             st, buf = carry
             off = c * CHUNK
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, CHUNK, 0)
-            xs = (jax.tree_util.tree_map(sl, txns),
-                  jax.tree_util.tree_map(sl, tt))
+            tx = jax.tree_util.tree_map(sl, txns)
+            xs = (tx, _gather_node_rows(rows, tx.node))
             st, outs = jax.lax.scan(scan_step, st, xs)
             buf = jax.tree_util.tree_map(
                 lambda b, o: jax.lax.dynamic_update_slice_in_dim(b, o, off, 0),
@@ -1157,7 +1189,7 @@ def _build_batched_fn(sig: tuple, capacity: int, fixed: tuple,
     brun = _make_batched_run(step, capacity, n_planes, lay.R_pad)
 
     if n_shards > 1:
-        spec = (P("lanes"), P(None, "lanes"), P(None, "lanes"), P("lanes"))
+        spec = (P("lanes"), P(None, "lanes"), P("lanes"), P("lanes"))
         fn = jax.shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
                            out_specs=P(None, "lanes"), check_vma=False)
     else:
@@ -1719,26 +1751,26 @@ def _avatars_for_key(key: tuple):
     _, sig, capacity, per_shard, fixed, n_shards = key[:6]
     B = per_shard * n_shards
     lay = sweep_layout_geom(sig[0], sig[1])
-    F0, R = lay.F_pad, lay.R_pad
+    F0, R, N = lay.F_pad, lay.R_pad, lay.n_nodes
     W = mask_words_per_row(R)
-    L, T = P("lanes"), P(None, "lanes")
+    L = P("lanes")
     scal = BatchScalars(
         *(_sds((B,), _TABLE_SCALAR_DTYPES[name], L, n_shards)
           for name in _PROMOTABLE),
         fc_valid=_sds((B, F0), bool, L, n_shards),
         res_dead=_sds((B, R), bool, L, n_shards),
     )
-    bt = BatchTxnTables(
-        mask_words=_sds((capacity, B, F0, 2, W), np.int32, T, n_shards),
-        hops=_sds((capacity, B, F0, 2), np.int32, T, n_shards),
-        dist=_sds((capacity, B, F0), np.int32, T, n_shards),
-        cand2=_sds((capacity, B), bool, T, n_shards),
-        fc_fixed=_sds((capacity, B, 2), np.int32, T, n_shards),
+    nt = BatchNodeTables(
+        mask_words=_sds((B, N, F0, 2, W), np.int32, L, n_shards),
+        hops=_sds((B, N, F0, 2), np.int32, L, n_shards),
+        dist=_sds((B, N, F0), np.int32, L, n_shards),
+        cand2=_sds((B, N), bool, L, n_shards),
+        fc_fixed=_sds((B, N, 2), np.int32, L, n_shards),
     )
     return (
         scal,
         _txns_avatar(B, capacity, n_shards, time_major=True),
-        bt,
+        nt,
         _sds((B,), np.int32, L, n_shards),
     )
 
@@ -2026,16 +2058,17 @@ def run_group_carry(sig: tuple, tables, state, txns: TxnArrays, n_chunks,
 
 
 def run_batched_group(sig: tuple, scal: BatchScalars, txns: TxnArrays,
-                      bt: BatchTxnTables, n_chunks, fixed: tuple,
+                      nt: BatchNodeTables, n_chunks, fixed: tuple,
                       n_shards: int, per_shard: int,
                       backend: str = "xla",
                       t_pack: float | None = None) -> tuple:
     """Execute one batched static group; returns (StepOut [cap, B], perf).
 
-    ``txns``/``bt`` are time-major numpy trees [cap, B, ...]; ``scal`` and
-    ``n_chunks`` carry the [B] lane axis.  Executed steps are charged at
-    the per-shard max chunk count (the masked tail of shorter lanes is the
-    batch's padding waste, kept visible in ``steps``).  ``backend`` picks
+    ``txns`` is a time-major numpy tree [cap, B]; ``scal``, the per-lane
+    node tables ``nt`` [B, N, ...] and ``n_chunks`` lead with the [B]
+    lane axis.  Executed steps are charged at the per-shard max chunk
+    count (the masked tail of shorter lanes is the batch's padding waste,
+    kept visible in ``steps``).  ``backend`` picks
     the lane-step kernel (a resolved name from
     :func:`resolve_lane_backend`); every backend is bit-exact.
     ``t_pack``: see :func:`_run_compiled`.
@@ -2050,8 +2083,8 @@ def run_batched_group(sig: tuple, scal: BatchScalars, txns: TxnArrays,
     return _run_compiled(
         batched_group_key(sig, capacity, per_shard, fixed, n_shards,
                           backend),
-        (scal, txns, bt, ncs),
-        (P("lanes"), P(None, "lanes"), P(None, "lanes"), P("lanes")),
+        (scal, txns, nt, ncs),
+        (P("lanes"), P(None, "lanes"), P("lanes"), P("lanes")),
         lanes=B, capacity=capacity, n_shards=n_shards, has_scout=False,
         steps=shard_steps, t_pack=t_pack,
     )

@@ -66,8 +66,7 @@ from repro.ssd.designs import (
     KIND_SCOUT,
     LaneTables,
     lower_designs,
-    mask_words_per_row,
-    pregather_node_tables,
+    node_tables,
     pregather_scout_tables,
     resolve_specs,
     rows_confined,
@@ -491,6 +490,20 @@ def _pad_txns(txns: S.TxnArrays, cap: int) -> S.TxnArrays:
     return S.TxnArrays(*out)
 
 
+def _stack_txns_time_major(lanes: list, cap: int) -> S.TxnArrays:
+    """The lanes' transactions zero-padded to ``cap``, time-major
+    [cap, B], each field written into one array in place (no padded copy
+    per lane to stack)."""
+    cols = []
+    for f in range(len(S.TxnArrays._fields)):
+        a = np.zeros((cap, len(lanes)), lanes[0].txns[f].dtype)
+        for j, ln in enumerate(lanes):
+            col = ln.txns[f]
+            a[:len(col), j] = col
+        cols.append(a)
+    return S.TxnArrays(*cols)
+
+
 def _pool_promotions(lanes: list) -> tuple:
     """Common value of each promotable scalar across the POOL (not per
     group): every group of the pool must share one executable, so the
@@ -538,6 +551,10 @@ class _GroupPlan:
                                           self.fixed, self.n_shards,
                                           self.backend)
         else:
+            # the per-lane node tables ship N rows a lane, never more than
+            # the cap per-slot rows the run gathers them into
+            assert self.cap >= self.sig[0] * self.sig[1], (self.cap,
+                                                           self.sig)
             self.key = S.batched_group_key(self.sig, self.cap,
                                            self.per_shard, self.fixed,
                                            self.n_shards, self.backend)
@@ -745,10 +762,7 @@ def _dispatch(plan: _GroupPlan) -> dict:
                                for ln in lanes]),
         )
         seeds = np.asarray([ln.seed for ln in lanes], np.uint32)
-        txns = S.TxnArrays(*(
-            np.stack([np.asarray(a) for a in cols], axis=1)
-            for cols in zip(*(_pad_txns(ln.txns, cap) for ln in lanes))
-        ))
+        txns = _stack_txns_time_major(lanes, cap)
         F0 = np.asarray(lanes[0].tables_row.fc_valid).shape[0]
         tt = S.ScoutBatchTxnTables(
             dist=np.zeros((cap, B, F0), np.int32),
@@ -769,7 +783,6 @@ def _dispatch(plan: _GroupPlan) -> dict:
         )
         lane_axis = 1
     else:
-        B = len(lanes)
         scal = S.BatchScalars(
             *(np.asarray([np.asarray(getattr(ln.tables_row, name))
                           for ln in lanes])
@@ -779,36 +792,18 @@ def _dispatch(plan: _GroupPlan) -> dict:
             res_dead=np.stack([np.asarray(ln.tables_row.res_dead)
                                for ln in lanes]),
         )
-        txns = S.TxnArrays(*(
-            np.stack([np.asarray(a) for a in cols], axis=1)
-            for cols in zip(*(_pad_txns(ln.txns, cap) for ln in lanes))
+        txns = _stack_txns_time_major(lanes, cap)
+        tabs = {}
+        for ln in lanes:
+            key = id(ln.tables_row)
+            if key not in tabs:  # row and padding lanes share one copy
+                tabs[key] = node_tables(ln.tables_row)
+        nt = S.BatchNodeTables(*(
+            np.stack([tabs[id(ln.tables_row)][f] for ln in lanes])
+            for f in S.BatchNodeTables._fields
         ))
-        F0 = np.asarray(lanes[0].tables_row.fc_valid).shape[0]
-        R = np.asarray(lanes[0].tables_row.cmask).shape[-1]
-        W = mask_words_per_row(R)
-        bt = S.BatchTxnTables(
-            mask_words=np.zeros((cap, B, F0, 2, W), np.int32),
-            hops=np.zeros((cap, B, F0, 2), np.int32),
-            dist=np.zeros((cap, B, F0), np.int32),
-            cand2=np.zeros((cap, B), bool),
-            fc_fixed=np.zeros((cap, B, 2), np.int32),
-        )
-        done = {}
-        for j, ln in enumerate(lanes):
-            key = id(ln)
-            if key not in done:  # dup padding lanes share the pregather
-                done[key] = pregather_node_tables(
-                    ln.tables_row, np.asarray(ln.txns.node)
-                )
-            pg = done[key]
-            n = ln.n
-            bt.mask_words[:n, j] = pg["mask_words"]
-            bt.hops[:n, j] = pg["hops"]
-            bt.dist[:n, j] = pg["dist"]
-            bt.cand2[:n, j] = pg["cand2"]
-            bt.fc_fixed[:n, j] = pg["fc_fixed"]
         ncs = np.asarray([ln.n_chunks for ln in lanes], np.int32)
-        outs, perf = S.run_batched_group(plan.sig, scal, txns, bt, ncs,
+        outs, perf = S.run_batched_group(plan.sig, scal, txns, nt, ncs,
                                          plan.fixed, plan.n_shards,
                                          plan.per_shard, plan.backend,
                                          t_pack=t_pack)

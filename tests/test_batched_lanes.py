@@ -2,8 +2,9 @@
 
 PR 3 recorded "vmap-batching lanes is ~50x slower and therefore unused";
 this PR revisits that with the gather-free formulation (one-hot
-compare-and-reduce state lookups, host-side pre-gathered node tables,
-masked-arithmetic validity — see ``sim._make_batched_static_step``).  The
+compare-and-reduce state lookups, node tables gathered per transaction
+once a chunk, outside the step, masked-arithmetic validity — see
+``sim._make_batched_static_step``).  The
 runner must be bit-identical to the flat unbatched scan for EVERY
 statically-routed design — including nossd, whose live FC selection takes
 the one-hot F-axis path — and the stacked layout (K sequential unbatched
@@ -11,13 +12,18 @@ lanes per shard) must be bit-identical for every design incl. scouts.
 The planner's layout choice is pure policy; these tests force each layout
 regardless of the measured-threshold policy in ``sweep_plan``.
 """
+import types
+
+import jax
 import numpy as np
 import pytest
 
-from repro.ssd import DESIGNS, bench, simulate
+from repro.ssd import DESIGNS, bench, perf_optimized, simulate
 from repro.ssd import sim as S
 from repro.ssd import sweep_plan as SP
-from repro.ssd.designs import REGISTRY, KIND_SCOUT
+from repro.ssd.designs import (REGISTRY, KIND_SCOUT, LaneTables,
+                               lower_designs, node_tables,
+                               pregather_node_tables)
 
 PARITY_FIELDS = ("completion", "wait", "conflict", "hops", "tries",
                  "misroutes")
@@ -144,3 +150,104 @@ def test_default_policy_collapses_small_pools(tiny_cfg, tiny_txns):
     for lane, design, seed in zip(sweep, designs, range(15)):
         _assert_parity(lane, simulate(tiny_cfg, tiny_txns, design,
                                       seed=seed), design)
+
+
+def _table_rows(cfg, designs):
+    """Per-design :class:`LaneTables` rows (numpy, no lane axis)."""
+    tables = lower_designs(cfg, designs)
+    return [LaneTables(*(np.asarray(a)[i] for a in tables))
+            for i in range(len(designs))]
+
+
+@pytest.mark.parametrize("geom", [(2, 2), (3, 4)])
+@pytest.mark.parametrize("design", STATIC_DESIGNS)
+def test_device_gather_equals_host_pregather(tiny_cfg, tiny_txns, design,
+                                             geom):
+    """The batched run's per-chunk gather of the per-lane node tables
+    reads, for every valid slot, exactly the rows the host pregather
+    resolves (all five tables).  The lanes mix designs so a wrong lane
+    offset reads another lane's tables; padding slots read node 0."""
+    cfg = (tiny_cfg if geom == (2, 2)
+           else perf_optimized(rows=geom[0], cols=geom[1],
+                               pages_per_block=64))
+    others = tuple(d for d in STATIC_DESIGNS if d != design)
+    designs = (design,) + others[:2]
+    rows = _table_rows(cfg, designs)
+    n_nodes = cfg.rows * cfg.cols
+    rng = np.random.default_rng(7)
+    C = 256
+    lengths = (C, C // 3, 0)  # a full lane, a short one, an empty one
+    node = np.zeros((C, len(designs)), np.int32)
+    valid = np.zeros((C, len(designs)), bool)
+    for b, n in enumerate(lengths):
+        node[:n, b] = rng.integers(0, n_nodes, n)
+        valid[:n, b] = True
+    if geom == (2, 2):  # the planner's own nodes on the tiny config
+        n0 = min(C, len(tiny_txns["node"]))
+        node[:n0, 0] = np.asarray(tiny_txns["node"])[:n0]
+    nt = S.BatchNodeTables(*(
+        np.stack([node_tables(r)[f] for r in rows])
+        for f in S.BatchNodeTables._fields))
+    got = jax.jit(lambda nt, node: S._gather_node_rows(S._node_rows(nt),
+                                                       node))(nt, node)
+    for b, r in enumerate(rows):
+        want = pregather_node_tables(r, node[:, b])
+        row0 = pregather_node_tables(r, np.zeros(C, np.int32))
+        for f in S.BatchNodeTables._fields:
+            g = np.asarray(getattr(got, f))[:, b].reshape(want[f].shape)
+            v = valid[:, b]
+            assert np.array_equal(g[v], want[f][v]), (design, geom, b, f)
+            assert np.array_equal(g[~v], row0[f][~v]), (design, geom, b, f)
+
+
+def test_put_bytes_are_the_per_lane_tables(tiny_cfg, tiny_txns, monkeypatch):
+    """Short lanes at a larger capacity: a batched group places its
+    scalars, its time-major transactions, its per-lane node tables (N
+    rows a lane, never ``cap``) and its chunk counts, and ``put_bytes``
+    counts exactly those leaves.  That is below what the node tables
+    alone weigh pregathered per slot ``[cap, B, ...]``."""
+    from repro.ssd.designs import mask_words_per_row, sweep_layout
+
+    _variants(monkeypatch, "batched")
+    sig = S._geom_sig(tiny_cfg)
+    cap = 4 * S._pad_to(len(tiny_txns["arrival"]))
+    monkeypatch.setitem(SP._CAP_SEEN, ("small", sig), cap)
+    designs = STATIC_DESIGNS + ("baseline",)  # 6 lanes: 3 a shard, no pads
+    B = len(designs)
+    g0 = len(bench.PERF["groups"])
+    b0 = bench.PERF["put_bytes"]
+    S.simulate_sweep(tiny_cfg, tiny_txns, designs, seeds=tuple(range(B)),
+                     decompose=False)
+    (g,) = bench.PERF["groups"][g0:]
+    assert (g["variant"], g["capacity"], g["lanes"]) == ("batched", cap, B)
+    lay = sweep_layout(tiny_cfg)
+    F0, R, N = lay.F_pad, lay.R_pad, lay.n_nodes
+    W = mask_words_per_row(R)
+    node_row = 4 * (F0 * 2 * W + F0 * 2 + F0 + 2) + 1  # + cand2 (bool)
+    scal = sum(np.dtype(S._TABLE_SCALAR_DTYPES[k]).itemsize
+               for k in S._PROMOTABLE) + F0 + R  # + fc_valid, res_dead
+    txn = 7 * 4 + 1  # seven int32 fields and the valid flag
+    want = B * (scal + cap * txn + N * node_row + 4)  # + n_chunks
+    assert g["put_bytes"] == want
+    assert bench.PERF["put_bytes"] - b0 == want
+    assert g["put_bytes"] < cap * B * node_row
+
+
+@pytest.mark.parametrize("cap_mult", [1, 4])
+def test_time_major_stack_equals_pad_and_stack(tiny_cfg, tiny_txns,
+                                               cap_mult):
+    """Both batched branches (static and scout) stack their lanes'
+    transactions time-major through ``_stack_txns_time_major``: the same
+    arrays, dtypes included, as padding a copy per lane with ``_pad_txns``
+    and stacking the copies on axis 1, for lanes of unequal length."""
+    full = S._pack_txns(tiny_cfg, tiny_txns,
+                        np.arange(len(tiny_txns["arrival"])), None)[0]
+    n = len(full.node)
+    lanes = [types.SimpleNamespace(txns=SP._slice_txns(full, np.arange(k)))
+             for k in (n, n // 2, 1, n)]
+    cap = cap_mult * S._pad_to(n)
+    got = SP._stack_txns_time_major(lanes, cap)
+    want = [np.stack(cols, axis=1) for cols in
+            zip(*(SP._pad_txns(ln.txns, cap) for ln in lanes))]
+    for f, g, w in zip(S.TxnArrays._fields, got, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w), f

@@ -5,8 +5,9 @@ ops on the CPU, so it cannot see what the TPU kernel compiler refuses:
 primitives Mosaic has no lowering for, integer matmuls and index
 reductions, block shapes it cannot lay out.  These tests compile the
 kernels and both batched runners for a described v5e chip at the paper's
-8x8 geometry, with argument shapes placed on one of its devices, and check
-that a Pallas kernel (``tpu_custom_call``) is in the compiled program.
+8x8 geometry, with argument shapes placed on one of its devices (the
+static runner also sharded over all four), and check that a Pallas kernel
+(``tpu_custom_call``) is in the compiled program.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.  JAX's persistent compilation
@@ -15,8 +16,9 @@ that is not attached cannot be read back here.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core.topology import build_mesh
 from repro.kernels.scout_step import (LINK_PAD, STATE_W, pack_tables,
@@ -75,20 +77,39 @@ def test_scout_kernel_compiles(one_chip, no_jax_cache,
     _assert_kernel(jax.jit(step).lower(*args).compile())
 
 
-@pytest.mark.parametrize("variant", ["batched", "bscout"])
-def test_batched_runner_compiles(one_chip, no_jax_cache, variant):
+@pytest.mark.parametrize("variant, n_shards", [
+    pytest.param("batched", 1, id="batched"),
+    pytest.param("batched", 4, id="batched-x4"),
+    pytest.param("bscout", 1, id="bscout"),
+])
+def test_batched_runner_compiles(topo, one_chip, no_jax_cache, monkeypatch,
+                                 variant, n_shards):
     """The batched static and scout runners the occupancy planner
-    dispatches on an accelerator, with the compiled Pallas backend."""
+    dispatches on an accelerator, with the compiled Pallas backend, in
+    the argument layout of ``sim._avatars_for_key`` (the static runner's
+    per-lane node tables included): on one chip, and the static runner
+    sharded over the four chips of a v5e 2x2."""
     cfg = perf_optimized()
     sig = S._geom_sig(cfg)
     per_shard = B // 2
     if variant == "batched":
         fixed = (None,) * len(S._PROMOTABLE)
-        key = S.batched_group_key(sig, CAPACITY, per_shard, fixed, 1,
+        key = S.batched_group_key(sig, CAPACITY, per_shard, fixed, n_shards,
                                   "pallas")
     else:
         fixed = S._promotions(lower_designs(cfg, ("venice",)))
         key = S.bscout_group_key(sig, CAPACITY, per_shard, 1, fixed, 1,
                                  "pallas")
-    avatars = _place(S._avatars_for_key(key), one_chip)
-    _assert_kernel(S._fn_for_key(key).lower(*avatars).compile())
+    if n_shards > 1:
+        # the lane mesh over the described chips, for the avatars'
+        # shardings and the runner's shard_map alike
+        mesh = Mesh(np.array(topo.devices[:n_shards]), ("lanes",))
+        monkeypatch.setattr(S, "_lane_mesh", lambda n: mesh)
+        S._build_batched_fn.cache_clear()
+        avatars = S._avatars_for_key(key)
+    else:
+        avatars = _place(S._avatars_for_key(key), one_chip)
+    try:
+        _assert_kernel(S._fn_for_key(key).lower(*avatars).compile())
+    finally:
+        S._build_batched_fn.cache_clear()
