@@ -18,12 +18,13 @@ import numpy as np
 from repro.core.topology import MeshTopology
 from repro.kernels.backend import default_interpret
 from repro.kernels.ref import scout_step_ref
-from repro.kernels.scout_step import LINK_PAD, STATE_W, pack_tables, scout_step_pallas
+from repro.kernels.scout_step import (B_TILE, STATE_W, link_pad, pack_tables,
+                                      scout_step_pallas)
 
 
 class BatchRouteOut(NamedTuple):
     success: jnp.ndarray  # bool [B]
-    path_mask: jnp.ndarray  # bool [B, LINK_PAD]
+    path_mask: jnp.ndarray  # bool [B, link_pad(L)]
     hops: jnp.ndarray  # int32 [B]
     steps: jnp.ndarray  # int32 [B]
     misroutes: jnp.ndarray  # int32 [B]
@@ -48,9 +49,10 @@ def route_dfs(step_fn, port_link, src, dst, busy0, seeds, *, n_pad, b_tile):
     embed it in a larger jitted program, as the batched scout lane runner
     does).
 
-    ``busy0`` is bool/int [B, L]; columns are padded to ``LINK_PAD`` when
-    narrower (wider maps pass through untouched), rows to a multiple of
-    ``b_tile`` with src == dst == 0 scouts that finish on the first step.
+    ``busy0`` is bool/int [B, L], L the topology's link count (or a
+    layout's link section); columns are padded to ``link_pad(L)``, whole
+    128-lane rows, and rows to a multiple of ``b_tile`` with
+    src == dst == 0 scouts that finish on the first step.
     ``n_pad`` is the packed-table row count (``pack_tables(topo).shape[0]``)
     sizing the tried bitmap.  Returned ``path_mask`` is the links this
     walk reserved (final busy minus initial busy), full padded width.
@@ -65,8 +67,9 @@ def route_dfs(step_fn, port_link, src, dst, busy0, seeds, *, n_pad, b_tile):
     state = state.at[:, 2].set(-1)
     state = state.at[:B, 3].set(seeds.astype(jnp.int32))
     busy = _pad_b(busy0.astype(jnp.int32), b_tile)
-    if busy.shape[1] < LINK_PAD:
-        busy = jnp.pad(busy, ((0, 0), (0, LINK_PAD - busy.shape[1])))
+    width = link_pad(busy.shape[1])
+    if busy.shape[1] < width:
+        busy = jnp.pad(busy, ((0, 0), (0, width - busy.shape[1])))
     busy0_p = busy.astype(bool)
     tried = jnp.zeros((Bp, 4 * n_pad), jnp.int32)
 
@@ -161,7 +164,7 @@ def make_route_batch(
     topo: MeshTopology,
     use_pallas: bool = True,
     interpret: bool | None = None,
-    b_tile: int = 256,
+    b_tile: int = B_TILE,
     allow_nonminimal: bool = True,
     dead_links=None,
 ):

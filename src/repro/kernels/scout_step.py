@@ -14,11 +14,14 @@ no gathers:
     reductions over the lane dimension.
 
 Layout: scout state is packed into an int32 ``[B, 8]`` array (cur, dst,
-entry, rng, 4 pad lanes); busy is ``[B, 128]`` (112 mesh links + pad) and
-tried is ``[B, 256]`` (64 nodes x 4 ports).  The batch is tiled over the grid
-with explicit VMEM BlockSpecs; one tile's working set at B_TILE=256 is
-256x(8+128+256+128+8)x4B ≈ 541 KiB < 1 MiB VMEM in fp32 words — comfortably
-resident, with the lane dimension 128-aligned for the VPU.
+entry, rng, 4 pad lanes); busy is ``[B, link_pad(n_links)]``, the mesh's
+link count rounded up to whole 128-lane vreg rows (128 for the 8x8 mesh's
+112 links, 256 for the 16x8 mesh's 232), and tried is ``[B, 4 * N_pad]``
+(4 ports per node; 256 on 8x8, 512 on 16x8).  The batch is tiled over the
+grid with explicit VMEM BlockSpecs of ``B_TILE`` lanes: a step's state,
+busy and tried blocks, in and out and double-buffered, must fit
+``VMEM_BUDGET`` (2.1 MB on 8x8, 3.7 MB on 16x8, 6.8 MB on 16x16; a 32x16
+mesh's 13.1 MB at 256 lanes is past it, and Mosaic refuses that tile).
 
 The kernel computes the *decision* of Algorithm 1 (minimal-adaptive with
 random tie-break, else misroute, else backtrack) plus the state advance;
@@ -40,9 +43,23 @@ from repro.core.topology import MeshTopology
 from repro.kernels.backend import default_interpret
 
 RIGHT, UP, LEFT, DOWN = 0, 1, 2, 3
-LINK_PAD = 128  # lane-aligned link bitmap (8x8 mesh has 112 links)
+LANES = 128  # TPU vreg lane width: a row of int32 words pads to it in VMEM
 STATE_W = 8  # cur, dst, entry, rng, flags(out), pick(out), pad, pad
 B_TILE = 256
+# VMEM a grid step's blocks may take: half of the 16 MiB of scoped VMEM
+# Mosaic gives a kernel on a v5e, the rest left to its temporaries
+VMEM_BUDGET = 8 << 20
+
+
+def link_pad(n_links: int) -> int:
+    """Columns of the busy map: ``n_links`` rounded up to whole lane rows."""
+    return max(1, -(-n_links // LANES)) * LANES
+
+
+def block_bytes(b_tile: int, link_w: int, tried_w: int) -> int:
+    """VMEM of one grid step's state, busy and tried blocks at lane tile
+    ``b_tile``: in and out, double-buffered, each row lane-padded."""
+    return 16 * b_tile * (link_pad(STATE_W) + link_w + tried_w)
 
 
 def umod(x, m):
@@ -251,10 +268,11 @@ def scout_step_pallas(
 ):
     """Run one Algorithm-1 step for a batch of scouts via pallas_call.
 
-    state [B, 8] int32; busy [B, LINK_PAD] int32 (0/1); tried [B, 4*N_pad]
-    int32 (0/1); tables from ``pack_tables``.  B must be a multiple of
-    ``b_tile`` (pad with dummy scouts).  ``interpret=None`` resolves from
-    the actual JAX backend (compiled on GPU/TPU, interpreted on CPU).
+    state [B, 8] int32; busy [B, link_pad(n_links)] int32 (0/1); tried
+    [B, 4*N_pad] int32 (0/1); tables from ``pack_tables``.  B must be a
+    multiple of ``b_tile`` (pad with dummy scouts), whose blocks fit
+    ``VMEM_BUDGET``.  ``interpret=None`` resolves from the actual JAX
+    backend (compiled on GPU/TPU, interpreted on CPU).
 
     ``allow_vec`` (int32/bool [B] or [B, 1], traced) carries a per-scout
     ``allow_nonminimal`` flag for pools that mix routing modes; when given
@@ -265,6 +283,8 @@ def scout_step_pallas(
     B = state.shape[0]
     assert B % b_tile == 0, "pad the scout batch to a multiple of b_tile"
     T = tried.shape[1]
+    assert block_bytes(b_tile, busy.shape[1], T) <= VMEM_BUDGET, (
+        "the scout kernel's blocks outgrow VMEM: take a smaller b_tile")
     grid = (B // b_tile,)
     in_specs = [
         pl.BlockSpec((b_tile, STATE_W), lambda i: (i, 0)),

@@ -98,6 +98,11 @@ for _c in ("decomp_hits", "decomp_misses", "run_hits", "run_subset_hits",
            "run_misses", "run_prefetched", "lanes", "scan_steps_valid",
            "scan_steps_padded"):
     METRICS.counter(_c)
+# batched scout groups: the DFS steps of each lane's own walks (every try
+# and raced scout of its valid transactions), and the DFS while-loop's
+# iterations times the lanes stepped in lockstep with them
+for _c in ("dfs_steps_live", "dfs_steps_padded"):
+    METRICS.counter(_c)
 METRICS.gauge("devices_used", 0)
 METRICS.timer("compile_s")
 METRICS.timer("exec_s")

@@ -1288,6 +1288,9 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
     DFS is replaced by ``kernels.ops.route_dfs`` around the batched
     ``step_math`` decision (XLA) or ``scout_step_pallas`` (the promoted
     kernel) — the same Algorithm-1 decision procedure, pinned equivalent.
+    Returns ``(state', (StepOut, dfs))``: ``dfs`` is the DFS while-loop's
+    iterations in this step (every try and raced scout) and, per lane, the
+    steps its own walks took.
     """
     L0 = lay.L_pad
     n_fcs = lay.rows
@@ -1367,7 +1370,9 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
         """Batched ``scout_until_success``: every lane follows its own
         retry schedule (its links triple is lane-local), frozen lanes'
         (res, t, rng, tries) ride through the joint while_loop untouched —
-        per-lane bit-identity with the flat loop."""
+        per-lane bit-identity with the flat loop.  Also returns the DFS
+        counts of every try and raced scout: the while-loop's iterations,
+        and each lane's own walks' steps."""
         n_scouts = fx(sp, "n_scouts")
         dead_links = sp.res_dead[:, :L0]
         B = src.shape[0]
@@ -1384,6 +1389,7 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
         def try_once(t, rng, act):
             busy = _busy_at(links3, t[:, None], d_hold[:, None]) | dead_links
             best = None
+            trips, own = jnp.int32(0), jnp.zeros((B,), jnp.int32)
             for k in range(k_max):
                 rng_adv = (
                     rng * jnp.uint32(747796405) + jnp.uint32(2891336453)
@@ -1391,6 +1397,10 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
                 active = jnp.asarray(k < n_scouts)  # bool or traced [B]
                 rng = jnp.where(jnp.logical_and(act, active), rng_adv, rng)
                 res = route(busy, rng, act)
+                # every scout steps while not done, so the loop ran as
+                # many times as the longest walk of the batch
+                trips = trips + jnp.max(res.steps)
+                own = own + jnp.where(act & active, res.steps, 0)
                 res = res._replace(path_mask=res.path_mask[:, :L0])
                 if best is None:
                     best = res
@@ -1399,16 +1409,16 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
                         (~best.success) | (res.hops < best.hops)
                     )
                     best = _merge_b(take, res, best)
-            return best, rng
+            return best, rng, (trips, own)
 
-        res0, rng = try_once(t0, rng, valid)
+        res0, rng, dfs0 = try_once(t0, rng, valid)
 
         def cond(carry):
-            res, t, rng, tries = carry
+            res, t, rng, tries, _ = carry
             return jnp.any(valid & (~res.success) & (tries < _MAX_TRIES))
 
         def body(carry):
-            res, t, rng, tries = carry
+            res, t, rng, tries, dfs = carry
             live = valid & (~res.success) & (tries < _MAX_TRIES)
             free, gap_s, _ = links3
             ev = jnp.minimum(
@@ -1419,15 +1429,15 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
             t_next = jnp.where(tries + 1 >= _MAX_TRIES,
                                jnp.max(free, axis=1), t_next)
             t_next = jnp.where(live, t_next, t)
-            res2, rng2 = try_once(t_next, rng, live)
+            res2, rng2, dfs2 = try_once(t_next, rng, live)
             res = _merge_b(live, res2, res)
             rng = jnp.where(live, rng2, rng)
-            return res, t_next, rng, tries + live.astype(jnp.int32)
+            return (res, t_next, rng, tries + live.astype(jnp.int32),
+                    (dfs[0] + dfs2[0], dfs[1] + dfs2[1]))
 
-        res, t, rng, tries = jax.lax.while_loop(
-            cond, body, (res0, t0, rng, jnp.ones((B,), jnp.int32))
+        return jax.lax.while_loop(
+            cond, body, (res0, t0, rng, jnp.ones((B,), jnp.int32), dfs0)
         )
-        return res, t, rng, tries
 
     def step(sp: ScoutBatchScalars, state, xs):
         tx, tt = xs
@@ -1465,7 +1475,7 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
         )
         t_xfer_req = jnp.where(is_read, t_read, t_nonread)
         t_scout = jnp.where(hold, t0, t_xfer_req)
-        sres, t_resv, rng, tries = scout_until_success_b(
+        sres, t_resv, rng, tries, dfs = scout_until_success_b(
             links, sp, src, tx.node, t_scout, rng, d_est, valid
         )
         hops_o = sres.hops
@@ -1507,7 +1517,7 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
                                 hops_o * (commit_end - t_resv), 0),
             failed=valid & fail,
         )
-        return (plane_free, links, fcs, chips, rng), out
+        return (plane_free, links, fcs, chips, rng), (out, dfs)
 
     return step
 
@@ -1516,7 +1526,10 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
                             n_fcs: int, n_nodes: int):
     """Chunked batched scout scan — the scout-state analogue of
     :func:`_make_batched_run` (seeds ride as an argument; the scan state
-    mirrors the flat scout ``init_state`` with a leading lane axis)."""
+    mirrors the flat scout ``init_state`` with a leading lane axis).
+    Returns the time-major ``StepOut`` and the run's DFS counts, int32
+    [B, 2]: the while-loop's iterations (the same on every lane of a
+    shard) and the iterations of each lane's own walks."""
 
     def batch_run(scal, seeds, txns: TxnArrays, tt: ScoutBatchTxnTables,
                   n_chunks):
@@ -1531,24 +1544,31 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
             jnp.asarray(seeds, jnp.uint32),
         )
 
+        def scan_step(carry, x):
+            st, (trips, own) = carry
+            st, (out, (n, o)) = step(scal, st, x)
+            return (st, (trips + n, own + o)), out
+
         def chunk_body(c, carry):
-            st, buf = carry
+            st, dfs, buf = carry
             off = c * CHUNK
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, CHUNK, 0)
             xs = (jax.tree_util.tree_map(sl, txns),
                   jax.tree_util.tree_map(sl, tt))
-            st, outs = jax.lax.scan(lambda s, x: step(scal, s, x), st, xs)
+            (st, dfs), outs = jax.lax.scan(scan_step, (st, dfs), xs)
             buf = jax.tree_util.tree_map(
                 lambda b, o: jax.lax.dynamic_update_slice_in_dim(b, o, off, 0),
                 buf, outs,
             )
-            return st, buf
+            return st, dfs, buf
 
-        _, buf = jax.lax.fori_loop(
+        dfs0 = (jnp.int32(0), jnp.zeros((B,), jnp.int32))
+        _, (trips, own), buf = jax.lax.fori_loop(
             0, jnp.max(n_chunks), chunk_body,
-            (state, _zero_out_tm(capacity, B)),
+            (state, dfs0, _zero_out_tm(capacity, B)),
         )
-        return buf  # StepOut, time-major [capacity, B]
+        # StepOut, time-major [capacity, B]; the DFS counts [B, 2]
+        return buf, jnp.stack([jnp.full((B,), trips), own], axis=1)
 
     return batch_run
 
@@ -1570,7 +1590,8 @@ def _build_batched_scout_fn(sig: tuple, capacity: int, k_max: int,
         spec = (P("lanes"), P("lanes"), P(None, "lanes"), P(None, "lanes"),
                 P("lanes"))
         fn = jax.shard_map(brun, mesh=_lane_mesh(n_shards), in_specs=spec,
-                           out_specs=P(None, "lanes"), check_vma=False)
+                           out_specs=(P(None, "lanes"), P("lanes")),
+                           check_vma=False)
     else:
         fn = brun
     return jax.jit(fn)
@@ -1886,7 +1907,8 @@ def _put_args(args, specs, n_shards: int):
 
 def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
                   capacity: int, n_shards: int, has_scout: bool,
-                  steps: int, t_pack: float | None = None) -> tuple:
+                  steps: int, t_pack: float | None = None,
+                  aux: bool = False) -> tuple:
     """Shared execute-and-report body of the group runners: resolve the
     executable, place the arguments, dispatch, and record the per-group
     attribution (variant/cache source/compile-load-exec split; ``steps``
@@ -1897,7 +1919,8 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
     (``time.perf_counter``): packing ends here.  The put only enqueues
     the copies to the devices (``put_s``); the copy itself, the run until
     the outputs are ready, and the fetch of the outputs to the host make
-    up ``exec_s``."""
+    up ``exec_s``.  ``aux``: the executable returns ``(StepOut, extra)``,
+    and ``extra`` comes back as ``perf["aux"]`` for the caller to reduce."""
     t_enter = time.perf_counter()
     if t_pack is not None:
         obs_spans.interval("plan", "pack", t_pack, t_enter)
@@ -1928,6 +1951,8 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
         "exec_s": round(exec_s, 3),
         "put_bytes": put_bytes,
     }
+    if aux:
+        outs, perf["aux"] = outs
     from repro.ssd import bench
 
     # kernel-dispatch scoreboard: which backend ran, and how many
@@ -2096,13 +2121,15 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
                             n_shards: int, per_shard: int,
                             backend: str = "xla",
                             t_pack: float | None = None) -> tuple:
-    """Execute one batched scout group; returns (StepOut [cap, B], perf).
+    """Execute one batched scout group; returns (StepOut [cap, B], the
+    DFS steps of each lane's own walks [B], perf).
 
     Same layout contract as :func:`run_batched_group` plus the per-lane
     rng ``seeds`` [B] (the scout state's fifth leg) and ``k_max`` (the
     group's raced-scout ceiling — lanes below it are masked per their
     ``n_scouts``).  Every backend is bit-exact.  ``t_pack``: see
-    :func:`_run_compiled`.
+    :func:`_run_compiled`.  ``perf["dfs_steps_padded"]``: the DFS
+    while-loop's iterations times the lanes stepped in lockstep with them.
     """
     B = int(np.asarray(n_chunks).shape[0])
     capacity = int(np.asarray(txns.arrival).shape[0])
@@ -2111,15 +2138,18 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
         int(ncs[s * per_shard:(s + 1) * per_shard].max(initial=0))
         * per_shard for s in range(max(1, n_shards))
     )
-    return _run_compiled(
+    outs, perf = _run_compiled(
         bscout_group_key(sig, capacity, per_shard, k_max, fixed, n_shards,
                          backend),
         (scal, np.asarray(seeds, np.uint32), txns, tt, ncs),
         (P("lanes"), P("lanes"), P(None, "lanes"), P(None, "lanes"),
          P("lanes")),
         lanes=B, capacity=capacity, n_shards=n_shards, has_scout=True,
-        steps=shard_steps, t_pack=t_pack,
+        steps=shard_steps, t_pack=t_pack, aux=True,
     )
+    dfs = np.asarray(perf.pop("aux"))
+    perf["dfs_steps_padded"] = int(np.sum(dfs[:, 0], dtype=np.int64))
+    return outs, dfs[:, 1], perf
 
 
 class SimResult(NamedTuple):

@@ -729,6 +729,7 @@ def _dispatch(plan: _GroupPlan) -> dict:
     return."""
     t_pack = time.perf_counter()
     lanes, cap = plan.lanes, plan.cap
+    dfs_own = None  # a scout group's DFS steps of each lane's own walks
     if plan.variant in ("lane", "stack"):
         tables = LaneTables(
             *(np.stack([np.asarray(getattr(ln.tables_row, f))
@@ -776,7 +777,7 @@ def _dispatch(plan: _GroupPlan) -> dict:
                 )
             tt.dist[:ln.n, j] = done[key]["dist"]
         ncs = np.asarray([ln.n_chunks for ln in lanes], np.int32)
-        outs, perf = S.run_batched_scout_group(
+        outs, dfs_own, perf = S.run_batched_scout_group(
             plan.sig, scal, seeds, txns, tt, ncs, plan.k_max,
             plan.fixed, plan.n_shards, plan.per_shard, plan.backend,
             t_pack=t_pack,
@@ -811,11 +812,17 @@ def _dispatch(plan: _GroupPlan) -> dict:
     with bench.stage("unpack_s", "unpack"):
         fields = [np.moveaxis(np.asarray(a), lane_axis, 0) for a in outs]
         seen = set()
+        live = 0
         for j, ln in enumerate(lanes):
             if id(ln) in seen:  # padding duplicate — outputs discarded
                 continue
             seen.add(id(ln))
             ln.out = S.StepOut(*(f[j] for f in fields))
+            if dfs_own is not None:
+                live += int(dfs_own[j])
+        if dfs_own is not None:
+            # DFS steps of the lanes' own walks, padding lanes left out
+            perf["dfs_steps_live"] = live
     perf["lanes"] = len(seen)
     return perf
 
@@ -994,6 +1001,8 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
             perf.get("scan_steps_padded", 0) + g["steps"]
         )
         perf["exec_s"] = perf.get("exec_s", 0.0) + g["exec_s"]
+        for k in ("dfs_steps_live", "dfs_steps_padded"):
+            perf[k] = perf.get(k, 0) + g.get(k, 0)
     perf.setdefault("groups", []).extend(all_groups)
     # mirror the persistent-store telemetry (absolute, process-wide)
     for k, v in exec_cache.STATS.items():

@@ -11,14 +11,17 @@ promoted Pallas kernel (interpreter mode, so CI needs no accelerator).
 The planner's layout choice is pure policy; these tests force the bscout
 layouts regardless of the measured thresholds in ``sweep_plan``.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
-from repro.ssd import DESIGNS, bench, simulate
+from repro.ssd import DESIGNS, bench, decompose_trace, simulate
 from repro.ssd import sim as S
 from repro.ssd import sweep_plan as SP
 from repro.ssd.designs import REGISTRY, KIND_SCOUT, FaultSpec
 from repro.ssd.scalar_ref import simulate_ref
+from repro.traces.generator import gen_trace, to_pages
 
 PARITY_FIELDS = ("completion", "wait", "conflict", "hops", "tries",
                  "misroutes")
@@ -91,21 +94,38 @@ def test_bscout_faults_res_dead(tiny_cfg, tiny_txns, monkeypatch,
                            faults=spec), (spec_name, design))
 
 
+@pytest.fixture(scope="module")
+def two_die(tiny_cfg):
+    """The tiny mesh with two dies per package: pages stripe over dies."""
+    cfg = dataclasses.replace(tiny_cfg, dies_per_chip=2)
+    tr = dict(gen_trace("src2_1", 60, seed=3))
+    tr["arrival_us"] = tr["arrival_us"] / 16.0
+    pages = to_pages(tr, cfg.page_bytes)
+    txns = decompose_trace(cfg, pages,
+                           footprint_pages=int(pages["footprint_pages"]))
+    assert len(np.unique(np.asarray(txns["plane"]) // cfg.planes_per_die
+                         % cfg.dies_per_chip)) == 2  # both dies carry pages
+    return cfg, txns
+
+
+@pytest.mark.parametrize("dies", [1, 2])
 @pytest.mark.parametrize("design", SCOUT_DESIGNS)
-def test_bscout_scalar_ref_parity(tiny_cfg, tiny_txns, monkeypatch,
-                                  design):
+def test_bscout_scalar_ref_parity(tiny_cfg, tiny_txns, two_die, monkeypatch,
+                                  design, dies):
     """The batched path also matches the independent scalar reference —
     same parity bar the flat scan is held to (seeds go through the same
-    ``seed | 1`` lane transform on both sides)."""
+    ``seed | 1`` lane transform on both sides) — with one die per package
+    and with two."""
     _force_bscout(monkeypatch)
+    cfg, txns = (tiny_cfg, tiny_txns) if dies == 1 else two_die
     lanes = (design,) * 6
-    sweep = S.simulate_sweep(tiny_cfg, tiny_txns, lanes, seeds=(7,) * 6,
+    sweep = S.simulate_sweep(cfg, txns, lanes, seeds=(7,) * 6,
                              decompose=False)
-    ref = simulate_ref(tiny_cfg, tiny_txns, design, seed=7)
+    ref = simulate_ref(cfg, txns, design, seed=7)
     for lane in sweep:
         for f in PARITY_FIELDS:
             assert np.array_equal(np.asarray(getattr(lane, f)),
-                                  ref[f]), (design, f)
+                                  ref[f]), (design, dies, f)
 
 
 def test_bscout_kscout_race_masking(tiny_cfg, tiny_txns, monkeypatch):

@@ -5,8 +5,10 @@ ops on the CPU, so it cannot see what the TPU kernel compiler refuses:
 primitives Mosaic has no lowering for, integer matmuls and index
 reductions, block shapes it cannot lay out.  These tests compile the
 kernels and both batched runners for a described v5e chip at the paper's
-8x8 geometry, with argument shapes placed on one of its devices (the
-static runner also sharded over all four), and check that a Pallas kernel
+8x8 geometry and at a 16-channel 16x8 mesh with two dies a chip (232
+links: a busy map past one 128-lane row; the scout kernel also at
+16x16), with argument shapes placed on one of its devices (the runners
+also sharded over all four), and check that a Pallas kernel
 (``tpu_custom_call``) is in the compiled program.
 
 The topology is described inside a fixture, never at import: only one
@@ -21,14 +23,19 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core.topology import build_mesh
-from repro.kernels.scout_step import (LINK_PAD, STATE_W, pack_tables,
-                                      scout_step_pallas)
-from repro.ssd import perf_optimized
+from repro.kernels.scout_step import (B_TILE, STATE_W, link_pad,
+                                      pack_tables, scout_step_pallas)
+from repro.ssd import cost_optimized, perf_optimized
 from repro.ssd import sim as S
 from repro.ssd.designs import lower_designs
 
 B = 512  # two lane tiles of the kernels' largest block
 CAPACITY = S.CHUNK
+# the paper's 8x8 mesh, and cost-16ch's 16x8 mesh at two dies a chip
+GEOMETRIES = {
+    "8x8": lambda: perf_optimized(),
+    "16x8x2": lambda: cost_optimized(rows=16, cols=8, dies_per_chip=2),
+}
 
 
 @pytest.fixture(scope="module")
@@ -59,37 +66,46 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows, cols, b_tile", [
+    (8, 8, B_TILE), (16, 8, B_TILE), (16, 16, B_TILE), (16, 16, B_TILE // 2),
+], ids=["8x8", "16x8", "16x16", "16x16-half-tile"])
 @pytest.mark.parametrize("per_scout_allow", [False, True])
 def test_scout_kernel_compiles(one_chip, no_jax_cache,
-                               per_scout_allow):
+                               per_scout_allow, rows, cols, b_tile):
     """Both kernel variants: a compile-time and a per-scout
-    ``allow_nonminimal``."""
-    tables = pack_tables(build_mesh(8, 8))
+    ``allow_nonminimal``, at the busy map's width for the mesh, up to a
+    16x16 mesh at the full lane tile (within ``VMEM_BUDGET``) and half."""
+    mesh = build_mesh(rows, cols)
+    tables = pack_tables(mesh)
     args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-            for s in ((B, STATE_W), (B, LINK_PAD), (B, 4 * tables.shape[0]),
-                      tables.shape)]
+            for s in ((B, STATE_W), (B, link_pad(mesh.n_links)),
+                      (B, 4 * tables.shape[0]), tables.shape)]
     if per_scout_allow:
         args.append(jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip))
 
     def step(*a):
-        return scout_step_pallas(*a, cols=8, n_nodes=64, interpret=False)
+        return scout_step_pallas(*a, cols=cols, n_nodes=mesh.n_nodes,
+                                 interpret=False, b_tile=b_tile)
 
     _assert_kernel(jax.jit(step).lower(*args).compile())
 
 
-@pytest.mark.parametrize("variant, n_shards", [
-    pytest.param("batched", 1, id="batched"),
-    pytest.param("batched", 4, id="batched-x4"),
-    pytest.param("bscout", 1, id="bscout"),
+@pytest.mark.parametrize("variant, n_shards, geometry", [
+    pytest.param("batched", 1, "8x8", id="batched"),
+    pytest.param("batched", 4, "8x8", id="batched-x4"),
+    pytest.param("bscout", 1, "8x8", id="bscout"),
+    pytest.param("bscout", 4, "8x8", id="bscout-x4"),
+    pytest.param("batched", 1, "16x8x2", id="batched-16x8x2"),
+    pytest.param("bscout", 1, "16x8x2", id="bscout-16x8x2"),
 ])
 def test_batched_runner_compiles(topo, one_chip, no_jax_cache, monkeypatch,
-                                 variant, n_shards):
+                                 variant, n_shards, geometry):
     """The batched static and scout runners the occupancy planner
     dispatches on an accelerator, with the compiled Pallas backend, in
     the argument layout of ``sim._avatars_for_key`` (the static runner's
-    per-lane node tables included): on one chip, and the static runner
-    sharded over the four chips of a v5e 2x2."""
-    cfg = perf_optimized()
+    per-lane node tables included): on one chip, and sharded over the four
+    chips of a v5e 2x2 (the scout runner's DFS counts, too, by lane)."""
+    cfg = GEOMETRIES[geometry]()
     sig = S._geom_sig(cfg)
     per_shard = B // 2
     if variant == "batched":
@@ -98,14 +114,15 @@ def test_batched_runner_compiles(topo, one_chip, no_jax_cache, monkeypatch,
                                   "pallas")
     else:
         fixed = S._promotions(lower_designs(cfg, ("venice",)))
-        key = S.bscout_group_key(sig, CAPACITY, per_shard, 1, fixed, 1,
-                                 "pallas")
+        key = S.bscout_group_key(sig, CAPACITY, per_shard, 1, fixed,
+                                 n_shards, "pallas")
     if n_shards > 1:
         # the lane mesh over the described chips, for the avatars'
         # shardings and the runner's shard_map alike
         mesh = Mesh(np.array(topo.devices[:n_shards]), ("lanes",))
         monkeypatch.setattr(S, "_lane_mesh", lambda n: mesh)
         S._build_batched_fn.cache_clear()
+        S._build_batched_scout_fn.cache_clear()
         avatars = S._avatars_for_key(key)
     else:
         avatars = _place(S._avatars_for_key(key), one_chip)
@@ -113,3 +130,4 @@ def test_batched_runner_compiles(topo, one_chip, no_jax_cache, monkeypatch,
         _assert_kernel(S._fn_for_key(key).lower(*avatars).compile())
     finally:
         S._build_batched_fn.cache_clear()
+        S._build_batched_scout_fn.cache_clear()

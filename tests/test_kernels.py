@@ -9,8 +9,11 @@ from repro.core.rng import seed_for_scout
 from repro.kernels.ops import make_route_batch
 from repro.kernels.ref import scout_step_ref
 from repro.kernels.scout_step import (
-    LINK_PAD,
     STATE_W,
+    B_TILE,
+    VMEM_BUDGET,
+    block_bytes,
+    link_pad,
     pack_tables,
     scout_step_pallas,
     umod,
@@ -27,14 +30,16 @@ def _mk_batch(topo, B, density, seed):
     state[:, 1] = rs.randint(0, topo.n_nodes, B)  # dst
     state[:, 2] = rs.randint(-1, 4, B)  # entry
     state[:, 3] = rs.randint(1, 2**31 - 1, B)  # rng bits
-    busy = np.zeros((B, LINK_PAD), np.int32)
+    busy = np.zeros((B, link_pad(topo.n_links)), np.int32)
     busy[:, : topo.n_links] = rs.rand(B, topo.n_links) < density
     tried = np.zeros((B, 4 * n_pad), np.int32)
     tried[:, : 4 * topo.n_nodes] = rs.rand(B, 4 * topo.n_nodes) < density / 2
     return state, busy, tried
 
 
-@pytest.mark.parametrize("rows,cols", [(8, 8), (4, 16), (16, 4), (4, 4)])
+# 16x8: 232 links, past one 128-lane row of the busy map
+@pytest.mark.parametrize("rows,cols",
+                         [(8, 8), (4, 16), (16, 4), (4, 4), (16, 8)])
 @pytest.mark.parametrize("density", [0.0, 0.3, 0.8])
 def test_kernel_matches_ref_over_meshes(rows, cols, density):
     topo = build_mesh(rows, cols)
@@ -52,6 +57,27 @@ def test_kernel_matches_ref_over_meshes(rows, cols, density):
     )
     for g, w, name in zip(got, want, ["state", "busy", "tried"]):
         assert np.array_equal(np.asarray(g), np.asarray(w)), name
+
+
+@pytest.mark.parametrize("rows,cols,width,fits", [
+    (8, 8, 128, True), (16, 8, 256, True), (16, 16, 512, True),
+    (32, 16, 1024, False)])
+def test_link_map_width_and_tile_follow_the_topology(rows, cols, width, fits):
+    """The busy map is the mesh's links in whole 128-lane rows, and the
+    blocks of a ``B_TILE`` lane tile fit the stated VMEM budget up to a
+    16x16 mesh (Mosaic compiles it for a v5e, ``test_chip_compile.py``;
+    it refuses the 32x16 mesh's tile, which the kernel's assertion stops
+    first)."""
+    topo = build_mesh(rows, cols)
+    n_pad = pack_tables(topo).shape[0]
+    assert link_pad(topo.n_links) == width
+    assert (block_bytes(B_TILE, width, 4 * n_pad) <= VMEM_BUDGET) == fits
+    if not fits:
+        state, busy, tried = _mk_batch(topo, B_TILE, 0.3, 0)
+        with pytest.raises(AssertionError, match="outgrow VMEM"):
+            scout_step_pallas(state, busy, tried, pack_tables(topo),
+                              cols=cols, n_nodes=topo.n_nodes,
+                              interpret=True)
 
 
 @pytest.mark.parametrize("b_tile,B", [(128, 128), (128, 384), (256, 512)])
@@ -148,13 +174,15 @@ def test_kernel_degenerate_dst_eq_src_is_noop():
     assert np.array_equal(np.asarray(got[2]), tried)
 
 
+@pytest.mark.parametrize("rows,cols", [(8, 8), (16, 8)])
 @pytest.mark.parametrize("use_pallas", [False, True])
-def test_full_dfs_replay_matches_scalar_reference(use_pallas):
-    topo = build_mesh(8, 8)
+def test_full_dfs_replay_matches_scalar_reference(use_pallas, rows, cols):
+    topo = build_mesh(rows, cols)
     rs = np.random.RandomState(3)
     B = 48
-    src = np.array([int(topo.fc_node[rs.randint(8)]) for _ in range(B)], np.int32)
-    dst = rs.randint(0, 64, B).astype(np.int32)
+    src = np.array([int(topo.fc_node[rs.randint(rows)]) for _ in range(B)],
+                   np.int32)
+    dst = rs.randint(0, topo.n_nodes, B).astype(np.int32)
     busy = rs.rand(B, topo.n_links) < rs.uniform(0, 0.7, (B, 1))
     seeds = np.array([seed_for_scout(9, i) for i in range(B)], np.uint32)
     route = make_route_batch(topo, use_pallas=use_pallas, interpret=True)
