@@ -1,8 +1,9 @@
 """Pallas kernels for the simulator's two hot paths.
 
-- ``scout_step`` / ``ref`` / ``ops``: the Algorithm-1 scout routing step
-  (one DFS decision per scout per call) — Pallas kernel, gather-based
-  jnp oracle, and the jitted batched-DFS driver around them.
+- ``scout_step`` / ``ref`` / ``ops``: Algorithm-1 scout routing — the
+  decision step and the whole DFS walk around it (a Pallas kernel per
+  walk, the same walk as XLA ops), the gather-based jnp oracle of one
+  step, and the jitted batch router.
 - ``batched_step``: the lane-tiled wrapper that runs the batched static
   step from ``ssd.sim`` as a ``pl.pallas_call`` (lanes on the grid,
   each tick's gathered node tables in per-instance blocks).

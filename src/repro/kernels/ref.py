@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the batched scout-step kernel.
+"""Pure-jnp oracle for the batched scout decision step (``step_math``).
 
 Deliberately written WITHOUT the kernel's one-hot-matmul tricks: plain
 ``take``/indexing gathers, so a bug in the kernel's TPU-native formulation

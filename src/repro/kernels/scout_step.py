@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: batched Algorithm-1 scout routing step.
+"""Pallas TPU kernel: batched Algorithm-1 scout routing, one whole DFS
+walk per call.
 
 The paper's perf-critical compute is stepping many scout state machines
 against the link-occupancy map (§4.3: every in-flight I/O request runs the
@@ -18,17 +19,22 @@ entry, rng, 4 pad lanes); busy is ``[B, link_pad(n_links)]``, the mesh's
 link count rounded up to whole 128-lane vreg rows (128 for the 8x8 mesh's
 112 links, 256 for the 16x8 mesh's 232), and tried is ``[B, 4 * N_pad]``
 (4 ports per node; 256 on 8x8, 512 on 16x8).  The batch is tiled over the
-grid with explicit VMEM BlockSpecs of ``B_TILE`` lanes: a step's state,
-busy and tried blocks, in and out and double-buffered, must fit
-``VMEM_BUDGET`` (2.1 MB on 8x8, 3.7 MB on 16x8, 6.8 MB on 16x16; a 32x16
-mesh's 13.1 MB at 256 lanes is past it, and Mosaic refuses that tile).
+grid with explicit VMEM BlockSpecs: a grid step's blocks, the DFS stacks
+included, must fit ``VMEM_BUDGET`` (``block_bytes``).
 
-The kernel computes the *decision* of Algorithm 1 (minimal-adaptive with
-random tie-break, else misroute, else backtrack) plus the state advance;
-the DFS stack (backtracking memory) lives in the driver (``ops.py``), which
-is regular JAX.  ``ref.py`` is the pure-jnp oracle; tests sweep shapes,
-meshes and occupancy densities in ``interpret=True`` mode and also replay
-full DFS walks against ``repro.core.routing.scout_route_ref``.
+``step_math`` is the *decision* of Algorithm 1 (minimal-adaptive with
+random tie-break, else misroute, else backtrack) plus the state advance.
+``walk_lanes`` is the DFS around it: the backtracking stacks (node, entry
+port, reserved link and misroute flag of every hop), push on advance, pop
+and link release on backtrack, all as selects against broadcast iotas, in
+a ``while_loop`` until every scout of the batch has arrived or failed.
+``scout_walk_pallas`` runs that whole walk inside one ``pallas_call`` per
+lane tile, so state, busy map, tried bitmap and stacks stay in VMEM from
+the first step to the last; off the chip ``ops.route_dfs`` calls
+``walk_lanes`` directly, as XLA ops.  Tests hold ``step_math`` to
+``ref.py``, the pure-jnp oracle, over shapes, meshes and occupancy
+densities, the walk kernel (``interpret=True``) to the XLA walk, and
+replay full DFS walks against ``repro.core.routing.scout_route_ref``.
 """
 from __future__ import annotations
 
@@ -41,11 +47,11 @@ from jax.experimental import pallas as pl
 
 from repro.core.topology import MeshTopology
 from repro.kernels.backend import default_interpret
+from repro.kernels.batched_step import lane_tile
 
 RIGHT, UP, LEFT, DOWN = 0, 1, 2, 3
 LANES = 128  # TPU vreg lane width: a row of int32 words pads to it in VMEM
 STATE_W = 8  # cur, dst, entry, rng, flags(out), pick(out), pad, pad
-B_TILE = 256
 # VMEM a grid step's blocks may take: half of the 16 MiB of scoped VMEM
 # Mosaic gives a kernel on a v5e, the rest left to its temporaries
 VMEM_BUDGET = 8 << 20
@@ -56,10 +62,15 @@ def link_pad(n_links: int) -> int:
     return max(1, -(-n_links // LANES)) * LANES
 
 
-def block_bytes(b_tile: int, link_w: int, tried_w: int) -> int:
-    """VMEM of one grid step's state, busy and tried blocks at lane tile
-    ``b_tile``: in and out, double-buffered, each row lane-padded."""
-    return 16 * b_tile * (link_pad(STATE_W) + link_w + tried_w)
+def block_bytes(b_tile: int, link_w: int, n_nodes: int) -> int:
+    """VMEM of one walk-kernel grid step at lane tile ``b_tile``: the
+    state, busy and tried blocks, in and out, double-buffered, the four
+    DFS stacks and the row of per-lane counters.  The tried bitmap and
+    each stack are ``link_pad(4 * n_nodes)`` words, every row lane-padded."""
+    width = link_pad(4 * n_nodes)
+    words = (4 * (link_pad(STATE_W) + link_w + width) + 4 * width
+             + link_pad(STATE_W))
+    return 4 * b_tile * words
 
 
 def umod(x, m):
@@ -204,41 +215,87 @@ def step_math(state, busy, tried, port_link, port_neighbor, cols, allow_nonminim
     return state_out, busy_out, tried_out
 
 
-def _kernel(state_ref, busy_ref, tried_ref, tables_ref, state_o, busy_o, tried_o,
-            *, cols, n_nodes, allow_nonminimal):
-    state = state_ref[...]
-    busy = busy_ref[...]
-    tried = tried_ref[...]
-    tables = tables_ref[...]  # [N_pad, 128]: cols 0-3 port_link, 4-7 neighbor
-    port_link = tables[:n_nodes, 0:4]
-    port_neighbor = tables[:n_nodes, 4:8]
-    s, b, t = step_math(
-        state, busy, tried, port_link, port_neighbor, cols, allow_nonminimal
-    )
-    state_o[...] = s
-    busy_o[...] = b
-    tried_o[...] = t
+def walk_lanes(state, busy0, port_link, port_neighbor, cols, allow_nonminimal):
+    """Walk every scout of the batch to its destination or to failure.
 
+    The DFS loop around :func:`step_math`: one decision step, then the
+    backtracking memory, until every scout is done.  ``state`` [B, 8]
+    holds each scout's src (as cur), dst, entry -1 and rng seed; ``busy0``
+    [B, L] int32 0/1 is its occupancy map.  Four stacks of
+    ``link_pad(4 * N)`` words hold each hop's node, entry port, reserved
+    link and misroute flag; a push, a pop and the link release are selects
+    against broadcast iotas, never scatters or gathers, so the same code
+    runs as XLA ops and inside a Pallas kernel.  A done scout is frozen:
+    its state, maps and counters no longer change.
 
-def _kernel_vec(state_ref, busy_ref, tried_ref, tables_ref, allow_ref,
-                state_o, busy_o, tried_o, *, cols, n_nodes):
-    """Per-scout ``allow_nonminimal`` variant: the flag rides in as a
-    traced ``[B, 1]`` int32 operand instead of a compile-time constant —
-    one executable serves pools that mix minimal-only and adaptive
-    scouts (the batched scout lane runner batches across designs)."""
-    state = state_ref[...]
-    busy = busy_ref[...]
-    tried = tried_ref[...]
-    tables = tables_ref[...]
-    allow = allow_ref[...] != 0
-    port_link = tables[:n_nodes, 0:4]
-    port_neighbor = tables[:n_nodes, 4:8]
-    s, b, t = step_math(
-        state, busy, tried, port_link, port_neighbor, cols, allow
-    )
-    state_o[...] = s
-    busy_o[...] = b
-    tried_o[...] = t
+    Returns ``(res, path)``: res [B, 8] int32 with columns success, hops,
+    steps (decision steps taken while active), misroutes and the loop's
+    iterations (the batch's longest walk, on every lane); path [B, L]
+    int32, the links the walk reserved (final busy minus ``busy0``).
+    """
+    B = state.shape[0]
+    width = link_pad(4 * port_link.shape[0])
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (B, width), 1)
+    iota_l = jax.lax.broadcasted_iota(jnp.int32, busy0.shape, 1)
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (B, STATE_W), 1)
+
+    def one(flag):
+        return jnp.where(flag, 1, 0)
+
+    def columns(*values):
+        # per-lane counters ride in one [B, 8] array: a carried [B, 1]
+        # column is a layout Mosaic cannot keep across loop iterations
+        out = jnp.zeros((B, STATE_W), jnp.int32)
+        for k, col in enumerate(values):
+            out = jnp.where(iota_s == k, col, out)
+        return out
+
+    def cond(c):
+        return jnp.min(c[-1][:, 3:4]) == 0  # some scout is not done
+
+    def body(c):
+        state, busy, tried, s_node, s_entry, s_link, s_mis, ctl = c
+        depth, steps, success, done = (ctl[:, k:k + 1] for k in range(4))
+        s2, b2, t2 = step_math(state, busy, tried, port_link, port_neighbor,
+                               cols, allow_nonminimal)
+        act = done == 0
+        flags = s2[:, 4:5]
+        advanced = act & (flags == 1)
+        at_dst = act & (flags == 2)
+        backtrack = act & (flags == 0)
+        can_pop = backtrack & (depth > 0)
+        fail = backtrack & (depth == 0)
+        # push the hop just taken at ``depth``
+        push = advanced & (iota_w == depth)
+        s_node = jnp.where(push, state[:, 0:1], s_node)
+        s_entry = jnp.where(push, state[:, 2:3], s_entry)
+        s_link = jnp.where(push, s2[:, 7:8], s_link)
+        s_mis = jnp.where(push, s2[:, 6:7], s_mis)
+        # pop the top hop: back to its node and entry, its link released
+        top = iota_w == depth - 1
+
+        def pop(stack):
+            return jnp.max(jnp.where(top, stack, jnp.iinfo(jnp.int32).min),
+                           axis=1, keepdims=True)
+
+        pnode, pentry, plink = pop(s_node), pop(s_entry), pop(s_link)
+        b2 = jnp.where(can_pop & (iota_l == plink), 0, b2)
+        s2 = jnp.where(can_pop & (iota_s == 0), pnode, s2)
+        s2 = jnp.where(can_pop & (iota_s == 2), pentry, s2)
+        ctl = columns(depth + one(advanced) - one(can_pop), steps + one(act),
+                      jnp.where(at_dst, 1, success),
+                      jnp.where(at_dst | fail, 1, done))
+        return (jnp.where(act, s2, state), jnp.where(act, b2, busy),
+                jnp.where(act, t2, tried), s_node, s_entry, s_link, s_mis, ctl)
+
+    zeros = jnp.zeros((B, width), jnp.int32)
+    init = (state, busy0) + (zeros,) * 5 + (jnp.zeros((B, STATE_W), jnp.int32),)
+    _, busy, _, _, _, _, s_mis, ctl = jax.lax.while_loop(cond, body, init)
+    depth, steps, success = (ctl[:, k:k + 1] for k in range(3))
+    mis = jnp.sum(jnp.where(iota_w < depth, s_mis, 0), axis=1, keepdims=True)
+    # every scout steps until done, so the loop ran its longest walk
+    return (columns(success, depth, steps, mis, jnp.max(steps)),
+            one((busy != 0) & (busy0 == 0)))
 
 
 def pack_tables(topo: MeshTopology) -> np.ndarray:
@@ -249,14 +306,40 @@ def pack_tables(topo: MeshTopology) -> np.ndarray:
     return t
 
 
+def walk_tile(B: int, link_w: int, n_nodes: int) -> int:
+    """The walk kernel's lane tile for a batch of ``B`` scouts: the largest
+    :func:`~repro.kernels.batched_step.lane_tile` of ``B`` rounded up to
+    whole sublanes whose blocks, stacks included, fit ``VMEM_BUDGET``."""
+    bt = lane_tile(-(-B // 8) * 8)
+    while bt > 8 and block_bytes(bt, link_w, n_nodes) > VMEM_BUDGET:
+        bt //= 2
+    return bt
+
+
+def _walk_kernel(state_ref, busy_ref, tables_ref, *refs, cols, n_nodes,
+                 allow_nonminimal):
+    if allow_nonminimal is None:  # per-scout flag, a [b_tile, 1] block
+        allow_ref, res_o, path_o = refs
+        allow = allow_ref[...] != 0
+    else:
+        res_o, path_o = refs
+        allow = allow_nonminimal
+    tables = tables_ref[...]
+    res, path = walk_lanes(state_ref[...], busy_ref[...],
+                           tables[:n_nodes, 0:4], tables[:n_nodes, 4:8],
+                           cols, allow)
+    res_o[...] = res
+    path_o[...] = path
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("cols", "n_nodes", "allow_nonminimal", "interpret", "b_tile"),
+    static_argnames=("cols", "n_nodes", "allow_nonminimal", "interpret",
+                     "b_tile"),
 )
-def scout_step_pallas(
+def scout_walk_pallas(
     state,
     busy,
-    tried,
     tables,
     allow_vec=None,
     *,
@@ -264,59 +347,41 @@ def scout_step_pallas(
     n_nodes: int,
     allow_nonminimal: bool = True,
     interpret: bool | None = None,
-    b_tile: int = B_TILE,
+    b_tile: int,
 ):
-    """Run one Algorithm-1 step for a batch of scouts via pallas_call.
+    """Walk a batch of scouts to their destinations, one ``pallas_call``
+    per lane tile: :func:`walk_lanes` inside the kernel.
 
-    state [B, 8] int32; busy [B, link_pad(n_links)] int32 (0/1); tried
-    [B, 4*N_pad] int32 (0/1); tables from ``pack_tables``.  B must be a
-    multiple of ``b_tile`` (pad with dummy scouts), whose blocks fit
-    ``VMEM_BUDGET``.  ``interpret=None`` resolves from the actual JAX
-    backend (compiled on GPU/TPU, interpreted on CPU).
-
-    ``allow_vec`` (int32/bool [B] or [B, 1], traced) carries a per-scout
-    ``allow_nonminimal`` flag for pools that mix routing modes; when given
-    it supersedes the static ``allow_nonminimal`` constant (which stays
-    the cheaper choice for uniform pools — no extra operand to stream).
+    state [B, 8] int32 (src as cur, dst, entry -1, rng seed); busy
+    [B, link_pad(n_links)] int32 0/1; tables from ``pack_tables``.  B must
+    be a multiple of ``b_tile`` (:func:`walk_tile`), whose blocks fit
+    ``VMEM_BUDGET``.  ``allow_vec`` (int32/bool [B], traced) carries a
+    per-scout ``allow_nonminimal`` flag and then supersedes the static one.
+    Returns ``walk_lanes``'s ``(res [B, 8], path [B, link_pad(n_links)])``,
+    each tile walked apart: its loop-iterations column is its own tile's.
     """
     interpret = default_interpret(interpret)
-    B = state.shape[0]
+    B, W = busy.shape
     assert B % b_tile == 0, "pad the scout batch to a multiple of b_tile"
-    T = tried.shape[1]
-    assert block_bytes(b_tile, busy.shape[1], T) <= VMEM_BUDGET, (
-        "the scout kernel's blocks outgrow VMEM: take a smaller b_tile")
-    grid = (B // b_tile,)
-    in_specs = [
-        pl.BlockSpec((b_tile, STATE_W), lambda i: (i, 0)),
-        pl.BlockSpec((b_tile, busy.shape[1]), lambda i: (i, 0)),
-        pl.BlockSpec((b_tile, T), lambda i: (i, 0)),
-        pl.BlockSpec((tables.shape[0], 128), lambda i: (0, 0)),
-    ]
-    if allow_vec is None:
-        kernel = functools.partial(
-            _kernel, cols=cols, n_nodes=n_nodes,
-            allow_nonminimal=allow_nonminimal,
-        )
-        operands = (state, busy, tried, tables)
-    else:
-        kernel = functools.partial(_kernel_vec, cols=cols, n_nodes=n_nodes)
-        in_specs.append(pl.BlockSpec((b_tile, 1), lambda i: (i, 0)))
-        operands = (state, busy, tried, tables,
-                    allow_vec.astype(jnp.int32).reshape(B, 1))
+    assert block_bytes(b_tile, W, n_nodes) <= VMEM_BUDGET, (
+        "the walk kernel's blocks outgrow VMEM: take a smaller b_tile")
+    lanes = lambda w: pl.BlockSpec((b_tile, w), lambda i: (i, 0))
+    in_specs = [lanes(STATE_W), lanes(W),
+                pl.BlockSpec(tables.shape, lambda i: (0, 0))]
+    operands = [state, busy, tables]
+    if allow_vec is not None:
+        allow_nonminimal = None
+        in_specs.append(lanes(1))
+        operands.append(allow_vec.astype(jnp.int32).reshape(B, 1))
+    kernel = functools.partial(_walk_kernel, cols=cols, n_nodes=n_nodes,
+                               allow_nonminimal=allow_nonminimal)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B // b_tile,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((b_tile, STATE_W), lambda i: (i, 0)),
-            pl.BlockSpec((b_tile, busy.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((b_tile, T), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, STATE_W), jnp.int32),
-            jax.ShapeDtypeStruct((B, busy.shape[1]), jnp.int32),
-            jax.ShapeDtypeStruct((B, T), jnp.int32),
-        ],
+        out_specs=[lanes(STATE_W), lanes(W)],
+        out_shape=[jax.ShapeDtypeStruct((B, STATE_W), jnp.int32),
+                   jax.ShapeDtypeStruct((B, W), jnp.int32)],
         interpret=interpret,
-        name="scout_step_pallas",
+        name="scout_walk",
     )(*operands)

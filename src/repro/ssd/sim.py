@@ -58,9 +58,9 @@ from repro.core.scout import make_tables, scout_route
 from repro.core.topology import build_mesh
 from repro.obs import spans as obs_spans
 from repro.kernels import onehot
-from repro.kernels.batched_step import lane_tile, lanes_2d, lane_tiled_step
+from repro.kernels.batched_step import lanes_2d, lane_tiled_step
 from repro.kernels.ops import route_dfs
-from repro.kernels.scout_step import pack_tables, scout_step_pallas, step_math
+from repro.kernels.scout_step import pack_tables
 from repro.ssd.config import SSDConfig, TICK_NS
 from repro.ssd.designs import (
     DESIGNS,
@@ -1206,15 +1206,15 @@ def _build_batched_fn(sig: tuple, capacity: int, fixed: tuple,
 # machines in lockstep — the per-step decision is ``kernels.scout_step``'s
 # one-hot compare-and-reduce math (the [B,N]x[N,4] port-table matmul,
 # lane-aligned busy/tried bitmaps), the backtracking memory is
-# ``kernels.ops.route_dfs``'s driver-resident stacks, and each lane routes
-# against its OWN link-occupancy map (one [B, L0] busy row per lane — the
-# lanes are independent simulations, not one mesh).  Divergence cost is
-# max-over-B steps per retry, which amortizes the per-op XLA CPU dispatch
-# overhead exactly like the static batch; every decision, rng draw, retry
-# schedule and k-scout race stays bit-exact vs the flat scan (pinned in
+# ``kernels.scout_step.walk_lanes``'s iota-select stacks, and each lane
+# routes against its OWN link-occupancy map (one [B, L0] busy row per lane
+# — the lanes are independent simulations, not one mesh).  Divergence cost
+# is max-over-B steps per walk; every decision, rng draw, retry schedule
+# and k-scout race stays bit-exact vs the flat scan (pinned in
 # tests/test_batched_scout.py against both ``simulate`` and
-# ``scalar_ref``).  ``backend`` promotes ``scout_step_pallas`` into the
-# DFS inner loop (compiled on GPU/TPU, interpret on CPU) — same math, so
+# ``scalar_ref``).  ``backend`` runs each whole walk inside the Pallas walk
+# kernel, one call per walk and lane tile (compiled on GPU/TPU, interpret
+# on CPU), and ``xla`` runs the same walk as XLA ops — same math, so
 # bit-exact by construction.
 # ---------------------------------------------------------------------------
 
@@ -1285,21 +1285,18 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
     operation for operation with a leading lane axis (consult those for
     the modeling semantics); all arithmetic is int32 one-hot/masked-select
     work, so batched == unbatched bit-for-bit.  The flat ``scout_route``
-    DFS is replaced by ``kernels.ops.route_dfs`` around the batched
-    ``step_math`` decision (XLA) or ``scout_step_pallas`` (the promoted
-    kernel) — the same Algorithm-1 decision procedure, pinned equivalent.
-    Returns ``(state', (StepOut, dfs))``: ``dfs`` is the DFS while-loop's
-    iterations in this step (every try and raced scout) and, per lane, the
-    steps its own walks took.
+    DFS is replaced by ``kernels.ops.route_dfs``: the batched ``step_math``
+    decision and its stacks, walked in the Pallas walk kernel or as XLA
+    ops — the same Algorithm-1 decision procedure, pinned equivalent.
+    Returns ``(state', (StepOut, dfs))``: ``dfs`` is, per lane, the DFS
+    loop's iterations in its lane tile in this step (every try and raced
+    scout) and the steps its own walks took.
     """
     L0 = lay.L_pad
     n_fcs = lay.rows
     n_nodes = lay.n_nodes
     fixed = dict(zip(_PROMOTABLE, fixed))
     tables_dev = jnp.asarray(pack_tables(topo))
-    n_pad = tables_dev.shape[0]
-    pl_, pn_ = tables_dev[:n_nodes, 0:4], tables_dev[:n_nodes, 4:8]
-    port_link_dev = jnp.asarray(topo.port_link, jnp.int32)
     cols = topo.cols
 
     def fx(sp, name):
@@ -1332,64 +1329,32 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
             a, b,
         )
 
-    def make_step_fn(sp, B):
-        """The per-DFS-iteration decision step for this batch, honoring a
-        promoted-static or per-lane-traced ``allow_nonmin``."""
-        allow = fx(sp, "allow_nonmin")
-        if backend == "xla":
-            b_tile = B
-
-            def step_fn(state, busy, tried):
-                return step_math(state, busy, tried, pl_, pn_, cols, allow)
-
-            return step_fn, b_tile
-        b_tile = lane_tile(-(-B // 8) * 8)
-        interpret = backend != "pallas"
-        if isinstance(allow, (bool, np.bool_)):
-            def step_fn(state, busy, tried):
-                return scout_step_pallas(
-                    state, busy, tried, tables_dev,
-                    cols=cols, n_nodes=n_nodes,
-                    allow_nonminimal=bool(allow),
-                    interpret=interpret, b_tile=b_tile,
-                )
-        else:
-            Bp = B + ((-B) % b_tile)
-            allow_p = jnp.zeros((Bp,), jnp.int32).at[:B].set(
-                jnp.asarray(allow).astype(jnp.int32))
-
-            def step_fn(state, busy, tried):
-                return scout_step_pallas(
-                    state, busy, tried, tables_dev, allow_p,
-                    cols=cols, n_nodes=n_nodes,
-                    interpret=interpret, b_tile=b_tile,
-                )
-        return step_fn, b_tile
-
     def scout_until_success_b(links3, sp, src, dst, t0, rng, d_hold, valid):
         """Batched ``scout_until_success``: every lane follows its own
         retry schedule (its links triple is lane-local), frozen lanes'
         (res, t, rng, tries) ride through the joint while_loop untouched —
         per-lane bit-identity with the flat loop.  Also returns the DFS
-        counts of every try and raced scout: the while-loop's iterations,
-        and each lane's own walks' steps."""
+        counts of every try and raced scout, per lane: the DFS loop's
+        iterations in the lane's tile, and the lane's own walks' steps."""
         n_scouts = fx(sp, "n_scouts")
         dead_links = sp.res_dead[:, :L0]
         B = src.shape[0]
-        step_fn, b_tile = make_step_fn(sp, B)
+        allow = fx(sp, "allow_nonmin")  # promoted-static or per-lane
 
         def route(busy, rngs, act):
-            # non-participating lanes route a src==dst==0 dummy scout
-            # (finishes in one step); their results are never merged
-            src_e = jnp.where(act, src, 0)
-            dst_e = jnp.where(act, dst, 0)
-            return route_dfs(step_fn, port_link_dev, src_e, dst_e, busy,
-                             rngs, n_pad=n_pad, b_tile=b_tile)
+            # one DFS walk per lane, in the walk kernel (Pallas backends)
+            # or as XLA ops; non-participating lanes route a src==dst==0
+            # dummy scout (finishes in one step), never merged
+            return route_dfs(tables_dev, jnp.where(act, src, 0),
+                             jnp.where(act, dst, 0), busy, rngs, allow,
+                             cols=cols, n_nodes=n_nodes,
+                             use_pallas=backend != "xla",
+                             interpret=backend != "pallas")
 
         def try_once(t, rng, act):
             busy = _busy_at(links3, t[:, None], d_hold[:, None]) | dead_links
             best = None
-            trips, own = jnp.int32(0), jnp.zeros((B,), jnp.int32)
+            trips = own = jnp.zeros((B,), jnp.int32)
             for k in range(k_max):
                 rng_adv = (
                     rng * jnp.uint32(747796405) + jnp.uint32(2891336453)
@@ -1397,9 +1362,9 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
                 active = jnp.asarray(k < n_scouts)  # bool or traced [B]
                 rng = jnp.where(jnp.logical_and(act, active), rng_adv, rng)
                 res = route(busy, rng, act)
-                # every scout steps while not done, so the loop ran as
-                # many times as the longest walk of the batch
-                trips = trips + jnp.max(res.steps)
+                # the walk's loop ran its tile's longest walk, on every
+                # lane of the tile
+                trips = trips + res.tile_steps
                 own = own + jnp.where(act & active, res.steps, 0)
                 res = res._replace(path_mask=res.path_mask[:, :L0])
                 if best is None:
@@ -1528,8 +1493,8 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
     :func:`_make_batched_run` (seeds ride as an argument; the scan state
     mirrors the flat scout ``init_state`` with a leading lane axis).
     Returns the time-major ``StepOut`` and the run's DFS counts, int32
-    [B, 2]: the while-loop's iterations (the same on every lane of a
-    shard) and the iterations of each lane's own walks."""
+    [B, 2]: the DFS loop's iterations in each lane's tile (the same on
+    every lane of a tile) and the iterations of each lane's own walks."""
 
     def batch_run(scal, seeds, txns: TxnArrays, tt: ScoutBatchTxnTables,
                   n_chunks):
@@ -1562,13 +1527,13 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
             )
             return st, dfs, buf
 
-        dfs0 = (jnp.int32(0), jnp.zeros((B,), jnp.int32))
+        dfs0 = (jnp.zeros((B,), jnp.int32),) * 2
         _, (trips, own), buf = jax.lax.fori_loop(
             0, jnp.max(n_chunks), chunk_body,
             (state, dfs0, _zero_out_tm(capacity, B)),
         )
         # StepOut, time-major [capacity, B]; the DFS counts [B, 2]
-        return buf, jnp.stack([jnp.full((B,), trips), own], axis=1)
+        return buf, jnp.stack([trips, own], axis=1)
 
     return batch_run
 
@@ -2128,8 +2093,9 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
     rng ``seeds`` [B] (the scout state's fifth leg) and ``k_max`` (the
     group's raced-scout ceiling — lanes below it are masked per their
     ``n_scouts``).  Every backend is bit-exact.  ``t_pack``: see
-    :func:`_run_compiled`.  ``perf["dfs_steps_padded"]``: the DFS
-    while-loop's iterations times the lanes stepped in lockstep with them.
+    :func:`_run_compiled`.  ``perf["dfs_steps_padded"]``: the DFS loop's
+    iterations in each lane tile times the lanes stepped in lockstep with
+    them.
     """
     B = int(np.asarray(n_chunks).shape[0])
     capacity = int(np.asarray(txns.arrival).shape[0])

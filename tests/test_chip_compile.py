@@ -6,10 +6,11 @@ primitives Mosaic has no lowering for, integer matmuls and index
 reductions, block shapes it cannot lay out.  These tests compile the
 kernels and both batched runners for a described v5e chip at the paper's
 8x8 geometry and at a 16-channel 16x8 mesh with two dies a chip (232
-links: a busy map past one 128-lane row; the scout kernel also at
+links: a busy map past one 128-lane row; the walk kernel also at
 16x16), with argument shapes placed on one of its devices (the runners
 also sharded over all four), and check that a Pallas kernel
-(``tpu_custom_call``) is in the compiled program.
+(``tpu_custom_call``) is in the compiled program: in the scout runner the
+walk kernel.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.  JAX's persistent compilation
@@ -23,13 +24,14 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core.topology import build_mesh
-from repro.kernels.scout_step import (B_TILE, STATE_W, link_pad,
-                                      pack_tables, scout_step_pallas)
+from repro.kernels.batched_step import B_TILE
+from repro.kernels.scout_step import (STATE_W, link_pad, pack_tables,
+                                      scout_walk_pallas, walk_tile)
 from repro.ssd import cost_optimized, perf_optimized
 from repro.ssd import sim as S
 from repro.ssd.designs import lower_designs
 
-B = 512  # two lane tiles of the kernels' largest block
+B = 512  # two lane tiles of the static kernel's largest block
 CAPACITY = S.CHUNK
 # the paper's 8x8 mesh, and cost-16ch's 16x8 mesh at two dies a chip
 GEOMETRIES = {
@@ -66,28 +68,46 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows, cols, b_tile", [
-    (8, 8, B_TILE), (16, 8, B_TILE), (16, 16, B_TILE), (16, 16, B_TILE // 2),
-], ids=["8x8", "16x8", "16x16", "16x16-half-tile"])
-@pytest.mark.parametrize("per_scout_allow", [False, True])
-def test_scout_kernel_compiles(one_chip, no_jax_cache,
-                               per_scout_allow, rows, cols, b_tile):
-    """Both kernel variants: a compile-time and a per-scout
-    ``allow_nonminimal``, at the busy map's width for the mesh, up to a
-    16x16 mesh at the full lane tile (within ``VMEM_BUDGET``) and half."""
+@pytest.mark.parametrize("rows, cols, lanes, per_scout_allow", [
+    (8, 8, 16, False), (8, 8, 16, True), (16, 8, 16, False),
+    (16, 8, 16, True), (16, 16, B, False), (16, 16, B, True),
+], ids=["8x8", "8x8-per-scout", "16x8", "16x8-per-scout", "16x16",
+        "16x16-per-scout"])
+def test_walk_kernel_compiles(one_chip, no_jax_cache, rows, cols, lanes,
+                              per_scout_allow):
+    """The walk kernel at the lane tile the shapes pick: one 16-lane tile
+    for a Fig. 9 group at 8x8 and 16x8, with a compile-time and a
+    per-scout ``allow_nonminimal``; at 16x16 a tile below ``B_TILE``,
+    since the DFS stacks would push the full tile past ``VMEM_BUDGET``."""
     mesh = build_mesh(rows, cols)
     tables = pack_tables(mesh)
+    W = link_pad(mesh.n_links)
+    b_tile = walk_tile(lanes, W, mesh.n_nodes)
+    assert b_tile == (B_TILE // 2 if rows == cols == 16 else lanes)
     args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-            for s in ((B, STATE_W), (B, link_pad(mesh.n_links)),
-                      (B, 4 * tables.shape[0]), tables.shape)]
+            for s in ((lanes, STATE_W), (lanes, W), tables.shape)]
     if per_scout_allow:
-        args.append(jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip))
+        args.append(jax.ShapeDtypeStruct((lanes,), jnp.int32,
+                                         sharding=one_chip))
 
-    def step(*a):
-        return scout_step_pallas(*a, cols=cols, n_nodes=mesh.n_nodes,
+    def walk(*a):
+        return scout_walk_pallas(*a, cols=cols, n_nodes=mesh.n_nodes,
                                  interpret=False, b_tile=b_tile)
 
-    _assert_kernel(jax.jit(step).lower(*args).compile())
+    _assert_kernel(jax.jit(walk).lower(*args).compile())
+
+
+def test_walk_kernel_refuses_a_tile_past_the_budget():
+    """A 256-lane tile at 16x16 outgrows ``VMEM_BUDGET`` once the stacks
+    are counted: the kernel's assertion stops it before Mosaic sees it."""
+    mesh = build_mesh(16, 16)
+    tables = pack_tables(mesh)
+    state = jnp.zeros((B_TILE, STATE_W), jnp.int32)
+    busy = jnp.zeros((B_TILE, link_pad(mesh.n_links)), jnp.int32)
+    with pytest.raises(AssertionError, match="outgrow VMEM"):
+        scout_walk_pallas(state, busy, tables, cols=16,
+                          n_nodes=mesh.n_nodes, interpret=True,
+                          b_tile=B_TILE)
 
 
 @pytest.mark.parametrize("variant, n_shards, geometry", [
@@ -127,7 +147,10 @@ def test_batched_runner_compiles(topo, one_chip, no_jax_cache, monkeypatch,
     else:
         avatars = _place(S._avatars_for_key(key), one_chip)
     try:
-        _assert_kernel(S._fn_for_key(key).lower(*avatars).compile())
+        text = S._fn_for_key(key).lower(*avatars).compile().as_text()
+        assert "tpu_custom_call" in text
+        if variant == "bscout":
+            assert "%scout_walk" in text
     finally:
         S._build_batched_fn.cache_clear()
         S._build_batched_scout_fn.cache_clear()

@@ -1,22 +1,29 @@
-"""Pallas scout-step kernel vs pure-jnp oracle: shape/mesh/density sweeps,
-plus full-DFS replay against the scalar Algorithm-1 reference."""
+"""The scout decision step vs the pure-jnp oracle: shape/mesh/density
+sweeps; the walk kernel vs the same walk as XLA ops; full-DFS replay
+against the scalar Algorithm-1 reference."""
+import functools
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import build_mesh, scout_route_ref
 from repro.core.rng import seed_for_scout
-from repro.kernels.ops import make_route_batch
+from repro.kernels.batched_step import B_TILE
+from repro.kernels.ops import make_route_batch, route_dfs
 from repro.kernels.ref import scout_step_ref
 from repro.kernels.scout_step import (
     STATE_W,
-    B_TILE,
     VMEM_BUDGET,
     block_bytes,
     link_pad,
     pack_tables,
-    scout_step_pallas,
+    scout_walk_pallas,
+    step_math,
     umod,
+    walk_lanes,
+    walk_tile,
     xorshift32_i32,
 )
 from repro.ssd.designs import DESIGNS, KIND_SCOUT, REGISTRY
@@ -37,6 +44,15 @@ def _mk_batch(topo, B, density, seed):
     return state, busy, tried
 
 
+@functools.partial(jax.jit, static_argnames=("cols", "n_nodes",
+                                             "allow_nonminimal"))
+def _step(state, busy, tried, tables, *, cols, n_nodes,
+          allow_nonminimal=True):
+    """``step_math``, the decision the walk kernel runs every iteration."""
+    return step_math(state, busy, tried, tables[:n_nodes, 0:4],
+                     tables[:n_nodes, 4:8], cols, allow_nonminimal)
+
+
 # 16x8: 232 links, past one 128-lane row of the busy map
 @pytest.mark.parametrize("rows,cols",
                          [(8, 8), (4, 16), (16, 4), (4, 4), (16, 8)])
@@ -46,9 +62,9 @@ def test_kernel_matches_ref_over_meshes(rows, cols, density):
     tables = jnp.asarray(pack_tables(topo))
     B = 256
     state, busy, tried = _mk_batch(topo, B, density, rows * 31 + cols)
-    got = scout_step_pallas(
+    got = _step(
         jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried), tables,
-        cols=cols, n_nodes=topo.n_nodes, interpret=True, b_tile=128,
+        cols=cols, n_nodes=topo.n_nodes,
     )
     n = topo.n_nodes
     want = scout_step_ref(
@@ -64,45 +80,54 @@ def test_kernel_matches_ref_over_meshes(rows, cols, density):
     (32, 16, 1024, False)])
 def test_link_map_width_and_tile_follow_the_topology(rows, cols, width, fits):
     """The busy map is the mesh's links in whole 128-lane rows, and the
-    blocks of a ``B_TILE`` lane tile fit the stated VMEM budget up to a
-    16x16 mesh (Mosaic compiles it for a v5e, ``test_chip_compile.py``;
-    it refuses the 32x16 mesh's tile, which the kernel's assertion stops
-    first)."""
+    walk kernel's blocks, stacks included, fit the stated VMEM budget at
+    a 128-lane tile (the tile a 16x16 mesh takes) up to a 16x16 mesh; the
+    32x16 mesh's do not, and the kernel's assertion stops them before
+    Mosaic sees them."""
     topo = build_mesh(rows, cols)
-    n_pad = pack_tables(topo).shape[0]
+    tile = B_TILE // 2
     assert link_pad(topo.n_links) == width
-    assert (block_bytes(B_TILE, width, 4 * n_pad) <= VMEM_BUDGET) == fits
+    assert (block_bytes(tile, width, topo.n_nodes) <= VMEM_BUDGET) == fits
     if not fits:
-        state, busy, tried = _mk_batch(topo, B_TILE, 0.3, 0)
+        state, busy, _ = _mk_batch(topo, tile, 0.3, 0)
         with pytest.raises(AssertionError, match="outgrow VMEM"):
-            scout_step_pallas(state, busy, tried, pack_tables(topo),
-                              cols=cols, n_nodes=topo.n_nodes,
-                              interpret=True)
+            scout_walk_pallas(state, busy, pack_tables(topo), cols=cols,
+                              n_nodes=topo.n_nodes, interpret=True,
+                              b_tile=tile)
 
 
 @pytest.mark.parametrize("b_tile,B", [(128, 128), (128, 384), (256, 512)])
 def test_kernel_tile_shapes(b_tile, B):
+    """The walk kernel over one and several lane tiles walks every scout
+    as the XLA walk of the whole batch does, and counts each tile's own
+    loop: its longest walk."""
     topo = build_mesh(8, 8)
     tables = jnp.asarray(pack_tables(topo))
-    state, busy, tried = _mk_batch(topo, B, 0.4, B)
-    got = scout_step_pallas(
-        jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried), tables,
-        cols=8, n_nodes=64, interpret=True, b_tile=b_tile,
-    )
-    want = scout_step_ref(
-        jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried),
-        tables[:64, 0:4], tables[:64, 4:8], 8,
-    )
-    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    src, dst, busy, seeds = _walk_batch(topo, B, 0.4, B)
+    state = np.zeros((B, STATE_W), np.int32)
+    state[:, 0], state[:, 1], state[:, 2] = src, dst, -1
+    state[:, 3] = seeds.view(np.int32)
+    busy = np.pad(busy, ((0, 0), (0, link_pad(topo.n_links) - topo.n_links)))
+    state, busy = jnp.asarray(state), jnp.asarray(busy, jnp.int32)
+    res, path = scout_walk_pallas(state, busy, tables, cols=8, n_nodes=64,
+                                  interpret=True, b_tile=b_tile)
+    want_res, want_path = walk_lanes(state, busy, tables[:64, 0:4],
+                                     tables[:64, 4:8], 8, True)
+    res, want_res = np.asarray(res), np.asarray(want_res)
+    assert np.array_equal(res[:, :4], want_res[:, :4])
+    assert np.array_equal(np.asarray(path), np.asarray(want_path))
+    tiles = res[:, 2].reshape(-1, b_tile).max(axis=1)
+    assert np.array_equal(res[:, 4], np.repeat(tiles, b_tile))
+    assert (want_res[:, 4] == want_res[:, 2].max()).all()
 
 
 def test_kernel_minimal_only_mode():
     topo = build_mesh(8, 8)
     tables = jnp.asarray(pack_tables(topo))
     state, busy, tried = _mk_batch(topo, 128, 0.6, 5)
-    got = scout_step_pallas(
+    got = _step(
         jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried), tables,
-        cols=8, n_nodes=64, interpret=True, b_tile=128, allow_nonminimal=False,
+        cols=8, n_nodes=64, allow_nonminimal=False,
     )
     # in minimal-only mode no step may be a misroute
     assert int(np.asarray(got[0])[:, 6].sum()) == 0
@@ -127,7 +152,7 @@ def test_xorshift_matches_python():
 
 @pytest.mark.parametrize("design", DESIGNS)
 def test_kernel_ref_parity_per_design(design):
-    """jnp-reference vs Pallas-interpret parity under each registered
+    """jnp-reference vs ``step_math`` parity under each registered
     design's routing knobs.  Statically-routed designs never walk the
     mesh — their scout degenerates to a dst == src (zero-length) walk —
     so their batches pin the degenerate path; scout designs pin their
@@ -139,10 +164,9 @@ def test_kernel_ref_parity_per_design(design):
     state, busy, tried = _mk_batch(topo, B, 0.5, DESIGNS.index(design) + 11)
     if spec.kind != KIND_SCOUT:
         state[:, 1] = state[:, 0]  # degenerate walk: already at destination
-    got = scout_step_pallas(
+    got = _step(
         jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried), tables,
         cols=8, n_nodes=64, allow_nonminimal=spec.allow_nonminimal,
-        interpret=True, b_tile=64,
     )
     want = scout_step_ref(
         jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried),
@@ -162,9 +186,9 @@ def test_kernel_degenerate_dst_eq_src_is_noop():
     tables = jnp.asarray(pack_tables(topo))
     state, busy, tried = _mk_batch(topo, 64, 0.7, 21)
     state[:, 1] = state[:, 0]
-    got = scout_step_pallas(
+    got = _step(
         jnp.asarray(state), jnp.asarray(busy), jnp.asarray(tried), tables,
-        cols=4, n_nodes=16, interpret=True, b_tile=64,
+        cols=4, n_nodes=16,
     )
     s = np.asarray(got[0])
     assert (s[:, 4] == 2).all()  # flags: arrived
@@ -174,7 +198,82 @@ def test_kernel_degenerate_dst_eq_src_is_noop():
     assert np.array_equal(np.asarray(got[2]), tried)
 
 
-@pytest.mark.parametrize("rows,cols", [(8, 8), (16, 8)])
+@pytest.mark.parametrize("rows,cols,B,tile", [
+    (8, 8, 16, 16), (16, 8, 16, 16), (8, 8, 512, 256), (16, 8, 512, 256),
+    (16, 16, 512, 128), (8, 8, 37, 8)])
+def test_walk_tile_follows_the_shapes(rows, cols, B, tile):
+    """The walk kernel's lane tile is the batch's largest lane tile whose
+    blocks, the DFS stacks included, fit ``VMEM_BUDGET``: one 16-lane tile
+    for a Fig. 9 group on 8x8 and 16x8; a 16x16 mesh's 256-lane tile would
+    outgrow the budget with its stacks, so it shrinks to 128."""
+    topo = build_mesh(rows, cols)
+    W = link_pad(topo.n_links)
+    assert walk_tile(B, W, topo.n_nodes) == tile
+    assert block_bytes(tile, W, topo.n_nodes) <= VMEM_BUDGET
+    if tile < min(B, B_TILE) and B % (2 * tile) == 0:
+        assert block_bytes(2 * tile, W, topo.n_nodes) > VMEM_BUDGET
+
+
+def _walk_batch(topo, B, density, seed):
+    rs = np.random.RandomState(seed)
+    src = np.array([int(topo.fc_node[rs.randint(topo.rows)])
+                    for _ in range(B)], np.int32)
+    dst = rs.randint(0, topo.n_nodes, B).astype(np.int32)
+    busy = rs.rand(B, topo.n_links) < density
+    seeds = np.array([seed_for_scout(seed, i) for i in range(B)], np.uint32)
+    return src, dst, busy, seeds
+
+
+def _assert_same_walks(got, want, ctx):
+    for f in got._fields:
+        if f != "tile_steps":  # each backend tiles its own loop
+            assert np.array_equal(np.asarray(getattr(got, f)),
+                                  np.asarray(getattr(want, f))), (ctx, f)
+
+
+# B = 37 pads to 40: five 8-lane tiles, the last part padding
+@pytest.mark.parametrize("rows,cols", [(8, 8), (16, 8), (16, 5)])
+@pytest.mark.parametrize("density", [0.0, 0.35, 0.7])
+@pytest.mark.parametrize("allow", ["adaptive", "minimal", "per-lane"])
+def test_walk_kernel_matches_xla_walk(rows, cols, density, allow):
+    """The walk kernel (interpret mode) and XLA ops run the same
+    walk: every ``BatchRouteOut`` field equal, element by element, with
+    two dead links, a static or a per-lane ``allow_nonminimal``, and a
+    batch that is not a multiple of the lane tile."""
+    topo = build_mesh(rows, cols)
+    B = 37
+    src, dst, busy, seeds = _walk_batch(topo, B, density, rows * 7 + cols)
+    dead = np.zeros(topo.n_links, bool)
+    dead[[0, topo.n_links // 2]] = True
+    args = tuple(jnp.asarray(a) for a in (src, dst, busy, seeds))
+    if allow == "per-lane":
+        flags = jnp.asarray(np.random.RandomState(B).rand(B) < 0.5)
+        tables = jnp.asarray(pack_tables(topo))
+
+        def run(use_pallas):
+            fn = functools.partial(route_dfs, cols=cols,
+                                   n_nodes=topo.n_nodes,
+                                   use_pallas=use_pallas, interpret=True)
+            return jax.jit(fn)(tables, *args[:2], args[2] | dead, args[3],
+                               flags)
+    else:
+        def run(use_pallas):
+            return make_route_batch(
+                topo, use_pallas=use_pallas, interpret=True,
+                allow_nonminimal=allow == "adaptive", dead_links=dead)(*args)
+    got, want = run(True), run(False)
+    _assert_same_walks(got, want, (rows, cols, density, allow))
+    # each 8-lane tile loops to its own longest walk, the XLA walk to
+    # the batch's; the padding lanes take one step
+    steps = np.asarray(got.steps)
+    tiles = np.pad(steps, (0, 3), constant_values=1).reshape(-1, 8).max(1)
+    assert np.array_equal(np.asarray(got.tile_steps),
+                          np.repeat(tiles, 8)[:B])
+    assert (np.asarray(want.tile_steps) == steps.max()).all()
+    assert not np.asarray(got.path_mask)[:, :topo.n_links][:, dead].any()
+
+
+@pytest.mark.parametrize("rows,cols", [(8, 8), (16, 8), (16, 5)])
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_full_dfs_replay_matches_scalar_reference(use_pallas, rows, cols):
     topo = build_mesh(rows, cols)
