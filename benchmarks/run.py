@@ -474,7 +474,7 @@ def main() -> None:
     if args.trace_out:
         from repro import obs
 
-        obs.enable_tracing(xc_sidecar=args.trace_out + ".xc.jsonl")
+        obs.enable_tracing()
     from repro.obs import spans as obs_spans
 
     bench.FTL_ENGINE = args.ftl_engine
@@ -503,11 +503,9 @@ def main() -> None:
 
     # ---- cross-phase compile prefetch (overlapped pipeline, DESIGN §2.2):
     # the planner knows every phase's request shapes up front, so the whole
-    # preset's missing executables start compiling/loading NOW — the first
-    # phase's two gating programs synchronously in-process, the rest on
-    # the out-of-process compile server — while the early phases execute.
-    # A hint only — a stale list just means the compile happens at first
-    # use.
+    # preset's missing executables start compiling NOW, on the background
+    # compile pool, while the early phases execute.  A hint only — a stale
+    # list just means the compile happens at first use.
     pre = []
     if want("fig4_9_10_13"):
         pre += [RunRequest(wl, cfg, designs, n_req)
@@ -600,11 +598,6 @@ def main() -> None:
           f"engine={args.ftl_engine}); CSVs in {args.csv}/")
 
     if args.json is not None:
-        from repro.ssd import exec_cache
-
-        exec_cache.flush()  # queued stores land before telemetry export
-        bench.PERF.update({f"xc_{k}": v for k, v in
-                           exec_cache.STATS.items()})
         path = args.json or os.path.join(
             args.csv, f"BENCH_{time.strftime('%Y%m%d_%H%M%S')}.json"
         )
@@ -624,17 +617,7 @@ def main() -> None:
             "cache": {k: bench.PERF[k] for k in
                       ("decomp_hits", "decomp_misses", "run_hits",
                        "run_subset_hits", "run_misses", "run_prefetched")},
-            # warm-path backend: persistent-executable store telemetry and
             # the overlapped compile/execute pipeline split
-            "exec_cache": {
-                "hits": bench.PERF["xc_hits"],
-                "misses": bench.PERF["xc_misses"],
-                "errors": bench.PERF["xc_errors"],
-                "stores": bench.PERF["xc_stores"],
-                "tombstones": bench.PERF["xc_tombstones"],
-                "load_s": round(bench.PERF["xc_load_s"], 3),
-                "dir": os.environ.get("REPRO_XC_DIR", ""),
-            },
             "compile_overlap_s": round(
                 bench.PERF["compile_overlap_s"], 3),
             "compile_wait_s": round(bench.PERF["compile_wait_s"], 3),
@@ -679,17 +662,6 @@ def main() -> None:
             # QoS surface: per-design p50/p95/p99 + per-tenant fairness
             # from the tail phase's scenarios
             "tail": tail_records,
-            # self-healing compile pipeline + store health (ISSUE 8): the
-            # persistent-store counters again (including tombstones and
-            # version-skew-induced misses) next to the compile-server
-            # watchdog's trip/fallback accounting
-            "xc_health": {
-                **{k: int(exec_cache.STATS[k]) for k in
-                   ("hits", "misses", "errors", "stores", "tombstones")},
-                "watchdog_trips": bench.PERF["xc_watchdog_trips"],
-                "watchdog_fallbacks": bench.PERF["xc_watchdog_fallbacks"],
-                "watchdog_reason": bench.PERF["xc_watchdog_reason"],
-            },
             # degraded-mode fault sweep: per-design throughput retention
             # under growing per-channel link faults
             "faults": fault_record,

@@ -70,13 +70,12 @@ def rows_for(arts: list) -> tuple:
     phases = [p for p in PHASE_ORDER
               if any(p in (a.get("phases") or {}) for _, a in arts)]
     header = (["artifact", "total_s", "ftl_s", "sim_s", "compile_s",
-               "exec_s", "cwait_s", "covl_s", "groups", "cache_hits(xc)",
-               "batched%", "scout%", "kernels"]
+               "exec_s", "cwait_s", "covl_s", "groups", "batched%", "scout%",
+               "kernels"]
               + [f"{p}_s" for p in phases])
     rows = []
     for name, art in arts:
         ph = art.get("phases") or {}
-        xc = art.get("exec_cache") or {}
         groups = art.get("groups")
         share_s, sshare_s, be_s = _kernel_split(art)
         rows.append(
@@ -88,7 +87,7 @@ def rows_for(arts: list) -> tuple:
              _fmt(art.get("compile_wait_s"), 2),
              _fmt(art.get("compile_overlap_s"), 2),
              str(len(groups)) if isinstance(groups, list) else "-",
-             str(xc.get("hits", "-")), share_s, sshare_s, be_s]
+             share_s, sshare_s, be_s]
             + [_fmt((ph.get(p) or {}).get("s")) for p in phases]
         )
     return header, rows
@@ -103,9 +102,8 @@ def render(results_dir: str) -> str:
     lines = ["# Perf trajectory (committed BENCH_*.json artifacts)", ""]
     lines.append("Regenerate: `PYTHONPATH=src python -m "
                  "benchmarks.trajectory`.  Ordering: `generated_at`, then "
-                 "file mtime, then name.  Wall-clock fields are seconds; "
-                 "`cache_hits(xc)` counts executables served from the "
-                 "persistent AOT store (warm runs); `cwait_s`/`covl_s` split "
+                 "file mtime, then name.  Wall-clock fields are seconds "
+                 "of CPU host time; `cwait_s`/`covl_s` split "
                  "background compilation into dispatcher stall vs time "
                  "hidden behind execution; `batched%` is the share "
                  "of static lane-steps run by the batched static step, "

@@ -93,7 +93,6 @@ def sweep_slice(n_chips: int) -> list:
     check("pallas-interpret" not in backends, f"interpreted: {backends}")
     check(perf["steps_batched"] > 0, "no static lane-steps batched")
     check(perf["steps_scout_batched"] > 0, "no scout lane-steps batched")
-    check(sweep_plan._PROC is None, "a compile-server process started")
     check(perf["devices_used"] == n_chips,
           f"devices_used {perf['devices_used']} != {n_chips}")
     for cfg in cfgs:

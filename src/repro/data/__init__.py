@@ -1,6 +1,4 @@
-"""Data pipeline: deterministic synthetic token stream (sharded by host) and
-the Venice-scheduled conflict-free parallel shard-read planner."""
-from repro.data.pipeline import SyntheticTokens, make_batch_iterator
+"""The Venice-scheduled conflict-free parallel shard-read planner."""
 from repro.data.venice_io import IOPlan, plan_reads
 
-__all__ = ["SyntheticTokens", "make_batch_iterator", "IOPlan", "plan_reads"]
+__all__ = ["IOPlan", "plan_reads"]

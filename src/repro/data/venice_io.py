@@ -10,8 +10,8 @@ conflict problem (§1) when several hosts pull from the same storage channel.
 mesh machinery (hosts = flash controllers on the west edge; storage nodes =
 flash nodes) and runs scout-based path reservation round by round: each round
 is a set of transfers whose paths are mutually conflict-free; transfers that
-fail reservation wait for the next round.  The checkpoint loader consumes the
-plan to order its reads.
+fail reservation wait for the next round.  A loader reads the rounds in
+order.
 """
 from __future__ import annotations
 

@@ -22,8 +22,6 @@ the stdlib — never jax — so hooking it into the hot modules is free.
 """
 from __future__ import annotations
 
-import os
-
 from repro.obs import events as _events
 from repro.obs import heatmap as _heatmap
 from repro.obs import spans as _spans
@@ -34,26 +32,17 @@ __all__ = [
     "export_trace", "validate_trace", "TraceBuilder",
 ]
 
-# Environment handshake with the out-of-process compile server: when the
-# parent is tracing, it points this variable at a sidecar file and the
-# worker (which cannot share the parent's tracer) appends epoch-stamped
-# span records there; ``export_trace`` merges them onto an "xc_worker"
-# track.  See ``ssd/xc_worker.py``.
-XC_SPANS_ENV = "REPRO_XC_SPANS"
-
 
 def enable_tracing(max_txn_events: int | None = None,
-                   xc_sidecar: str | None = None,
                    recorder: bool = True) -> _spans.SpanTracer:
     """Arm both layers: install the global SSD recorder and span tracer,
     and return the span tracer.
 
     ``max_txn_events`` caps the number of per-transaction SSD events
     retained (runs past the cap are recorded as dropped, never silently
-    truncated mid-run).  ``xc_sidecar`` (a file path) additionally asks any
-    compile server spawned after this call to log its compile spans there.
-    ``recorder=False`` arms the span tracer alone: timing a sweep against
-    a profiler trace must not also pay for per-transaction recording.
+    truncated mid-run).  ``recorder=False`` arms the span tracer alone:
+    timing a sweep against a profiler trace must not also pay for
+    per-transaction recording.
     """
     if recorder:
         kwargs = {}
@@ -61,8 +50,6 @@ def enable_tracing(max_txn_events: int | None = None,
             kwargs["max_txns"] = max_txn_events
         _events.RECORDER = _events.DeviceRecorder(**kwargs)
     _spans.TRACER = _spans.SpanTracer()
-    if xc_sidecar is not None:
-        os.environ[XC_SPANS_ENV] = xc_sidecar
     return _spans.TRACER
 
 
@@ -70,7 +57,6 @@ def disable_tracing() -> None:
     """Disarm both layers (hook sites return to the no-op path)."""
     _events.RECORDER = None
     _spans.TRACER = None
-    os.environ.pop(XC_SPANS_ENV, None)
 
 
 def tracing_enabled() -> bool:
@@ -90,9 +76,6 @@ def export_trace(path: str, heatmap_csv: str | None = None,
     tracer = _spans.TRACER
     if tracer is not None:
         builder.add_harness_spans(tracer.drain())
-    sidecar = os.environ.get(XC_SPANS_ENV)
-    if sidecar and tracer is not None:
-        builder.add_xc_sidecar(sidecar, tracer.t0_wall)
     recorder = _events.RECORDER
     runs = recorder.finalized_runs() if recorder is not None else []
     for run in runs:

@@ -3,9 +3,8 @@
 One file carries both layers:
 
 * **pid 1 "harness"** — span tracks (one trace row per ``(track, thread)``
-  so B/E pairs nest properly): plan/compile/dispatch/stream/scenario/
-  watchdog events, plus compile-server spans merged from the ``xc_worker``
-  sidecar (epoch-stamped, rebased onto the tracer's wall-clock anchor).
+  so B/E pairs nest properly): plan/compile/dispatch/stream/scenario
+  events.
 * **pid 10+k, one per simulated SSD run** (``ssd: <design>``) — the
   flight recorder's reconstruction: per-plane transaction slices
   (``tid = plane``; the scan serializes each plane, so slices never
@@ -117,34 +116,6 @@ class TraceBuilder:
                                     "cat": "harness",
                                     "ts": round(float(ts + dur), 3),
                                     "_k": (0, dur, -seq)})
-
-    def add_xc_sidecar(self, path: str, t0_wall: float) -> int:
-        """Merge the compile server's epoch-stamped span log (JSON lines
-        ``{"name", "t0_epoch", "dur_s", ...extras}``) onto an
-        ``xc_worker`` track; returns the number of spans merged."""
-        try:
-            with open(path) as fh:
-                lines = fh.readlines()
-        except OSError:
-            return 0
-        n = 0
-        for line in lines:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rec = json.loads(line)
-                ts = (float(rec["t0_epoch"]) - t0_wall) * 1e6
-                dur = float(rec["dur_s"]) * 1e6
-            except (ValueError, KeyError, TypeError):
-                continue
-            tid = self._harness_tid("xc_worker", -1)
-            args = {k: v for k, v in rec.items()
-                    if k not in ("t0_epoch", "dur_s")}
-            self._x(HARNESS_PID, tid, rec.get("name", "compile"),
-                    max(ts, 0.0), dur, "xc_worker", args or None)
-            n += 1
-        return n
 
     # ---- layer 1: SSD runs ------------------------------------------
     def add_ssd_run(self, run: dict) -> None:

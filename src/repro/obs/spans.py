@@ -9,8 +9,7 @@ thread)`` against a monotonic clock anchored at the tracer's creation.
 The anchor is read from both clocks at one instant: ``t0_perf``
 (``time.perf_counter``) and ``t0_wall_ns`` (``time.time_ns``), so
 :meth:`SpanTracer.epoch_ns` puts a span on the epoch clock a profiler
-trace stamps its own start with, and out-of-process sidecar events (the
-``xc_worker`` compile server) land on the same timeline.
+trace stamps its own start with.
 
 Spans recorded inside a :func:`batch` block carry its id as a ``batch``
 argument, so the spans of one planned batch can be pulled out together.

@@ -116,24 +116,11 @@ METRICS.object("groups", [])
 for _t in ("lower_s", "pack_s", "put_s", "fetch_s", "unpack_s"):
     METRICS.timer(_t)
 METRICS.counter("put_bytes")
-# warm-path execution backend (DESIGN.md §2.2): persistent-executable
-# store telemetry (hits/misses/errors/stores mirrored from
-# ``exec_cache.STATS``, plus deserialize wall-clock) and the overlapped
-# compile/execute pipeline split — background compile time hidden
-# behind execution vs time the dispatcher actually stalled
-for _c in ("xc_hits", "xc_misses", "xc_errors", "xc_stores",
-           "xc_tombstones"):
-    METRICS.counter(_c)
-METRICS.timer("xc_load_s")
+# the overlapped compile/execute pipeline split (DESIGN.md §2.2):
+# background compile time hidden behind execution vs time the dispatcher
+# actually stalled
 METRICS.timer("compile_overlap_s")
 METRICS.timer("compile_wait_s")
-# self-healing compile pipeline (ISSUE 8): compile-server watchdog trips
-# (heartbeat loss / straggler / crash — see ``sweep_plan._ServerWatchdog``),
-# the reason of the last trip, and how many delegated keys fell back to
-# in-process compilation
-METRICS.counter("xc_watchdog_trips")
-METRICS.gauge("xc_watchdog_reason", None)
-METRICS.counter("xc_watchdog_fallbacks")
 # streaming engine (repro.ssd.stream): windows replayed and wall-clock
 # spent in the overlapped prep stage (decompose + order + pack) — prep
 # that hides behind execution shows up here but not in compile_wait_s

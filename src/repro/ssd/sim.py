@@ -1563,17 +1563,17 @@ def _build_batched_scout_fn(sig: tuple, capacity: int, k_max: int,
 
 
 # ---------------------------------------------------------------------------
-# executable store: logical keys, shape avatars, compile-or-load
+# executables: logical keys, shape avatars, compile
 #
 # Every program variant has a *logical key* — everything its machine code
 # depends on besides the source (geometry sig, capacity bucket, lane
-# layout, cost class, promotions, shard count).  Keys index three tiers:
-# the in-process ``_EXEC_CACHE``, the on-disk AOT store
-# (``repro.ssd.exec_cache`` — loading skips tracing+lowering+compile), and
-# a fresh compile.  Compilation happens from ShapeDtypeStruct avatars, so
-# the sweep planner can compile executables on a background thread before
-# the group's data is even stacked (the overlapped compile/execute
-# pipeline in ``sweep_plan``).
+# layout, cost class, promotions, shard count, and for the batched
+# variants the lane-step backend, always last).  A key resolves through
+# the in-process ``_EXEC_CACHE``, else a fresh compile (JAX's persistent
+# compilation cache serves repeats across processes).  Compilation
+# happens from ShapeDtypeStruct avatars, so the sweep planner can compile
+# executables on a background thread before the group's data is even
+# stacked (the overlapped compile/execute pipeline in ``sweep_plan``).
 # ---------------------------------------------------------------------------
 
 _EXEC_CACHE: dict = {}
@@ -1600,34 +1600,23 @@ def stack_group_key(sig, capacity, K, k_max, has_scout, fixed, n_shards):
 
 def batched_group_key(sig, capacity, per_shard, fixed, n_shards,
                       backend: str = "xla"):
-    # the default XLA backend keeps the historical 6-tuple so warm-path
-    # store entries stay stable; pallas variants are distinct programs
-    # and carry the backend as a 7th element
-    if backend == "xla":
-        return ("batched", sig, capacity, per_shard, fixed, n_shards)
     return ("batched", sig, capacity, per_shard, fixed, n_shards, backend)
 
 
 def bscout_group_key(sig, capacity, per_shard, k_max, fixed, n_shards,
                      backend: str = "xla"):
-    """Batched scout group.  Same convention as ``batched_group_key``:
-    the default XLA backend key is the plain tuple (byte-stable in the
-    AOT store), pallas variants append the backend."""
-    if backend == "xla":
-        return ("bscout", sig, capacity, per_shard, k_max, fixed, n_shards)
+    """Batched scout group."""
     return ("bscout", sig, capacity, per_shard, k_max, fixed, n_shards,
             backend)
 
 
 def kernel_backend_of_key(key: tuple) -> str:
     """Which lane-step kernel a group key dispatches to: "xla" for all
-    unbatched variants and the default batched programs, else the pallas
-    flavor recorded in the key ("pallas-compiled" / "pallas-interpret")."""
-    if key[0] == "batched" and len(key) > 6:
-        return "pallas-compiled" if key[6] == "pallas" else key[6]
-    if key[0] == "bscout" and len(key) > 7:
-        return "pallas-compiled" if key[7] == "pallas" else key[7]
-    return "xla"
+    unbatched variants, else the batched key's backend, with "pallas"
+    reported as "pallas-compiled"."""
+    if key[0] not in ("batched", "bscout"):
+        return "xla"
+    return "pallas-compiled" if key[-1] == "pallas" else key[-1]
 
 
 _TABLE_SCALAR_DTYPES = dict(
@@ -1714,7 +1703,7 @@ def _avatars_for_key(key: tuple):
             _sds((G,), np.int32, P("lanes"), n_shards),
         )
     if kind == "bscout":
-        _, sig, capacity, per_shard, k_max, fixed, n_shards = key[:7]
+        _, sig, capacity, per_shard, k_max, fixed, n_shards, _ = key
         B = per_shard * n_shards
         lay = sweep_layout_geom(sig[0], sig[1])
         F0, R = lay.F_pad, lay.R_pad
@@ -1734,7 +1723,7 @@ def _avatars_for_key(key: tuple):
                 dist=_sds((capacity, B, F0), np.int32, T, n_shards)),
             _sds((B,), np.int32, L, n_shards),
         )
-    _, sig, capacity, per_shard, fixed, n_shards = key[:6]
+    _, sig, capacity, per_shard, fixed, n_shards, _ = key
     B = per_shard * n_shards
     lay = sweep_layout_geom(sig[0], sig[1])
     F0, R, N = lay.F_pad, lay.R_pad, lay.n_nodes
@@ -1761,21 +1750,6 @@ def _avatars_for_key(key: tuple):
     )
 
 
-def _key_devices(key: tuple) -> list:
-    """The devices the program of ``key`` runs on, in mesh order: its
-    shard count's lane mesh, or the default device for one shard."""
-    kind = key[0]
-    if kind in ("lane", "lanec", "stack"):
-        n_shards = key[7]
-    elif kind == "bscout":
-        n_shards = key[6]
-    else:
-        n_shards = key[5]
-    if n_shards > 1:
-        return list(_lane_mesh(n_shards).devices.flat)
-    return jax.devices()[:1]
-
-
 def _fn_for_key(key: tuple):
     kind = key[0]
     if kind == "lane":
@@ -1791,12 +1765,10 @@ def _fn_for_key(key: tuple):
         return _build_stack_fn(sig, capacity, K, k_max, has_scout, fixed,
                                n_shards)
     if kind == "bscout":
-        _, sig, capacity, per_shard, k_max, fixed, n_shards = key[:7]
-        backend = key[7] if len(key) > 7 else "xla"
+        _, sig, capacity, per_shard, k_max, fixed, n_shards, backend = key
         return _build_batched_scout_fn(sig, capacity, k_max, fixed,
                                        n_shards, per_shard, backend)
-    _, sig, capacity, per_shard, fixed, n_shards = key[:6]
-    backend = key[6] if len(key) > 6 else "xla"
+    _, sig, capacity, per_shard, fixed, n_shards, backend = key
     return _build_batched_fn(sig, capacity, fixed, n_shards, per_shard,
                              backend)
 
@@ -1807,53 +1779,43 @@ def lower_for_key(key: tuple):
     Tracing/lowering is Python-heavy (GIL-bound), so the overlapped
     pipeline runs it on the MAIN thread during planning; the XLA backend
     compile (``.compile()``, releases the GIL) is what goes to the worker
-    threads.  Returns None when a lowering isn't needed (already in the
-    in-process cache, or the persistent store has the executable)."""
+    threads.  Returns None when the executable is already in the
+    in-process cache."""
     if key in _EXEC_CACHE:
         return None
     return _fn_for_key(key).lower(*_avatars_for_key(key))
 
 
 def ensure_compiled(key: tuple, lowered=None):
-    """Resolve ``key`` to a loaded executable: in-process cache, then the
-    persistent AOT store, then compile (persisting the result).
+    """Resolve ``key`` to a loaded executable: the in-process cache, else
+    compile.
 
-    Returns ``(compiled, seconds, source)`` with source in
-    ``{"mem", "disk", "build"}`` — ``seconds`` is the load or compile
-    wall-clock (0 for "mem").  Thread-safe for distinct keys (the
-    overlapped pipeline compiles on worker threads); ``lowered`` is the
-    optional pre-traced module from :func:`lower_for_key`.
+    Returns ``(compiled, seconds, source)`` with source ``"mem"`` or
+    ``"build"`` — ``seconds`` is the compile wall-clock (0 for "mem").
+    Thread-safe for distinct keys (the overlapped pipeline compiles on
+    worker threads); ``lowered`` is the optional pre-traced module from
+    :func:`lower_for_key`.
     """
     hit = _EXEC_CACHE.get(key)
     if hit is not None:
         return hit, 0.0, "mem"
-    from repro.ssd import bench, exec_cache
+    from repro.ssd import bench
 
-    t0 = time.perf_counter()
-    devices = _key_devices(key)
-    compiled = exec_cache.lookup(key, devices)
-    if compiled is not None:
-        _EXEC_CACHE[key] = compiled
-        dt = time.perf_counter() - t0
-        # tallied here, not from dispatched groups: background
-        # compiles/loads kicked off by ``sweep_plan.precompile`` count
-        # even when they finish before any group adopts them (the lock:
-        # compile-pool workers tally concurrently)
-        with _TALLY_LOCK:
-            bench.PERF["xc_load_s"] += dt
-        return compiled, dt, "disk"
     if lowered is None:
         lowered = _fn_for_key(key).lower(*_avatars_for_key(key))
     t0 = time.perf_counter()
     compiled = lowered.compile()  # JAX's persistent cache serves repeats
     dt = time.perf_counter() - t0
+    # tallied here, not from dispatched groups: background compiles kicked
+    # off by ``sweep_plan.precompile`` count even when they finish before
+    # any group adopts them (the lock: compile-pool workers tally
+    # concurrently)
     with _TALLY_LOCK:
         bench.PERF["compile_s"] += dt
     tr = obs_spans.TRACER
     if tr is not None:
         tr.complete("compile", f"compile:{key[0]}", tr.now_us() - dt * 1e6,
                     dt * 1e6, {"source": "build"})
-    exec_cache.store(key, compiled, devices)
     _EXEC_CACHE[key] = compiled
     return compiled, dt, "build"
 
@@ -1876,7 +1838,7 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
                   aux: bool = False) -> tuple:
     """Shared execute-and-report body of the group runners: resolve the
     executable, place the arguments, dispatch, and record the per-group
-    attribution (variant/cache source/compile-load-exec split; ``steps``
+    attribution (variant/cache source/compile-exec split; ``steps``
     is the executed-step count incl. padding waste; ``put_bytes`` the
     bytes placed on the devices).
 
@@ -1911,8 +1873,7 @@ def _run_compiled(key: tuple, args: tuple, specs: tuple, *, lanes: int,
         "shards": n_shards, "scout": has_scout,
         "steps": steps * CHUNK, "cache": src,
         "kernel_backend": kb,
-        "compile_s": round(dt if src == "build" else 0.0, 3),
-        "load_s": round(dt if src == "disk" else 0.0, 3),
+        "compile_s": round(dt, 3),
         "exec_s": round(exec_s, 3),
         "put_bytes": put_bytes,
     }

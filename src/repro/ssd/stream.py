@@ -36,11 +36,10 @@ carried state crosses the boundary explicitly:
 
 The steady state is **execution-bound**: while window N executes, a
 single-worker prep thread decomposes window N+1 and routes any missing
-executables through ``sweep_plan``'s compile pipeline (background thread
-pool or the ``xc_worker`` out-of-process compile server).  Windows share
-one ``lanec`` executable per (geometry, capacity bucket, cost class,
+executables through ``sweep_plan``'s background compile pool.  Windows
+share one ``lanec`` executable per (geometry, capacity bucket, cost class,
 promotions) — the capacity bucket is a running high-water mark — so after
-window 1 on a warm store the per-window compile wait is ~0.
+window 1 the per-window compile wait is ~0.
 
 Bit-exactness contract (pinned by ``tests/test_stream.py``): a windowed
 replay of any prefix that fits one window is bit-identical to
@@ -224,16 +223,14 @@ def _finish_stream(cfg: SSDConfig, design: str, agg: dict,
 def _resolve_executable(key: tuple) -> float:
     """Block until ``key``'s executable is loaded; returns the main-thread
     stall seconds (mirrors ``sweep_plan._execute_plans``'s wait pattern —
-    an in-flight background compile is adopted, a compile-server key is
-    polled, anything else resolves through the three-tier store)."""
+    an in-flight background compile is adopted, anything else is compiled
+    here)."""
     if key in S._EXEC_CACHE:
         return 0.0
     t0 = time.perf_counter()
     fut = SP._INFLIGHT.pop(key, None)
     if fut is not None:
         fut.result()
-    elif key in SP._PROC_KEYS and SP._proc_alive():
-        SP._await_server(key)
     else:
         S.ensure_compiled(key)
     return time.perf_counter() - t0

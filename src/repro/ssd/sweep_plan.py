@@ -50,7 +50,6 @@ import concurrent.futures
 import dataclasses
 import itertools
 import os
-import threading
 import time
 from typing import Sequence
 
@@ -59,7 +58,6 @@ import numpy as np
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
 from repro.ssd import bench
-from repro.ssd import exec_cache
 from repro.ssd import sim as S
 from repro.ssd.config import SSDConfig
 from repro.ssd.designs import (
@@ -180,224 +178,20 @@ def planner_profile() -> str:
 # already-compiled groups (XLA compilation releases the GIL).
 _COMPILE_POOL = None
 
-# executable compiles/loads already in flight (cross-phase: ``precompile``
+# executable compiles already in flight (cross-phase: ``precompile``
 # submits a whole preset's worth before the first phase executes; the
 # dispatch loop adopts the futures instead of resubmitting)
 _INFLIGHT: dict = {}
 
-# keys delegated to the out-of-process compile server (repro.ssd.xc_worker)
-# and the server process handle.  Process mode needs the persistent store
-# (the server publishes through it) and is the default when one is
-# configured; REPRO_COMPILE_PROC=0 forces in-process threads.
-_PROC_KEYS: set = set()
-_PROC = None
-
-# ---- self-healing compile backend (ISSUE 8) ------------------------------
-# The compile server is a scheduling hint with no correctness surface, but
-# a hint that HANGS (wedged process, SIGSTOP, swap death) used to cost the
-# 600s poll deadline per delegated key.  A _ServerWatchdog built on the
-# runtime fault-tolerance primitives closes that: the worker's heartbeat
-# thread touches a file ~1/s, a silent worker past REPRO_XC_WATCHDOG_S is
-# declared dead, and an alive-but-pathologically-slow worker is abandoned
-# by the straggler rule.  Either way every delegated key falls back to the
-# in-process compile path and the run completes — counted in
-# ``bench.PERF["xc_watchdog_trips"/"xc_watchdog_fallbacks"]``.
-_WATCHDOG_TIMEOUT_S = float(os.environ.get("REPRO_XC_WATCHDOG_S", "20.0"))
-_WATCHDOG = None
-_WD_LOCK = threading.Lock()
-
-
-class _ServerWatchdog:
-    """Liveness + progress tracking for one compile-server process.
-
-    ``HeartbeatMonitor`` consumes the worker's heartbeat file (mtime
-    changes become beats); ``StragglerDetector`` watches the wait time of
-    each delegated key relative to the median wait of the keys currently
-    being awaited, so one wedged key among progressing ones is flagged
-    after ``patience`` strikes even while heartbeats continue."""
-
-    # straggler observations are taken at this cadence, not per 50ms poll
-    # tick, so ``patience`` means "straggling for patience * period"
-    OBSERVE_PERIOD_S = 5.0
-
-    def __init__(self, hb_path: str, timeout_s: float = None, clock=None):
-        from repro.runtime.fault_tolerance import (HeartbeatMonitor,
-                                                   StragglerDetector)
-
-        self.hb_path = hb_path
-        self._clock = clock or time.monotonic
-        self.mon = HeartbeatMonitor(
-            ["xc_worker"],
-            timeout_s=(_WATCHDOG_TIMEOUT_S if timeout_s is None
-                       else timeout_s),
-            clock=clock,
-        )
-        self.strag = StragglerDetector(k=4.0, deadline_floor_s=60.0,
-                                       patience=3)
-        self.waits: dict = {}  # key -> wait start (perf_counter)
-        self._mtime = None
-        self._next_observe = self._clock() + self.OBSERVE_PERIOD_S
-        self.reason = None
-
-    def track(self, key: tuple) -> None:
-        with _WD_LOCK:
-            self.waits[key] = time.perf_counter()
-
-    def untrack(self, key: tuple) -> None:
-        with _WD_LOCK:
-            self.waits.pop(key, None)
-
-    def healthy(self) -> bool:
-        """Poll the heartbeat file + straggler clock; False once the
-        server should be abandoned (sticky)."""
-        with _WD_LOCK:
-            if self.reason is not None:
-                return False
-            try:
-                m = os.path.getmtime(self.hb_path)
-            except OSError:
-                m = None
-            if m is not None and m != self._mtime:
-                self._mtime = m
-                self.mon.beat("xc_worker")
-            if self.mon.dead_hosts():
-                self.reason = "heartbeat"
-                return False
-            now = self._clock()
-            if now >= self._next_observe and self.waits:
-                self._next_observe = now + self.OBSERVE_PERIOD_S
-                t = time.perf_counter()
-                durs = {str(k): t - t0 for k, t0 in self.waits.items()}
-                if self.strag.observe_step(durs):
-                    self.reason = "straggler"
-                    return False
-            return True
-
-
-def _fail_server(reason: str) -> int:
-    """Abandon the compile server: reclaim every delegated key for the
-    in-process compile path.  Idempotent; returns reclaimed-key count."""
-    global _PROC, _WATCHDOG
-    with _WD_LOCK:
-        n = len(_PROC_KEYS)
-        if n == 0 and _PROC is None:
-            return 0
-        _PROC_KEYS.clear()
-        proc, _PROC = _PROC, None
-        _WATCHDOG = None
-    if proc is not None and proc.poll() is None:
-        try:
-            proc.kill()
-        except OSError:
-            pass
-    perf = bench.PERF
-    perf["xc_watchdog_trips"] = perf.get("xc_watchdog_trips", 0) + 1
-    perf["xc_watchdog_reason"] = reason
-    obs_spans.instant("watchdog", "server_abandoned", reason=reason,
-                      reclaimed_keys=n)
-    return n
-
-
-def _proc_mode() -> bool:
-    """Compile on the out-of-process server?  Only with the AOT store
-    opted into, and only on a CPU host: an accelerator belongs to one
-    process, so a second process importing JAX there would fail or hang."""
-    import jax
-
-    return (exec_cache.cache_dir() is not None
-            and os.environ.get("REPRO_COMPILE_PROC", "1") != "0"
-            and jax.default_backend() not in S._ACCEL_BACKENDS)
-
-
-def _proc_alive() -> bool:
-    return _PROC is not None and _PROC.poll() is None
-
 
 def _schedule_compiles(keys: list) -> None:
-    """Route missing executables to the compile server (process mode) or
-    the background thread pool."""
-    keys = [k for k in keys
-            if k not in S._EXEC_CACHE and k not in _INFLIGHT
-            and k not in _PROC_KEYS and not exec_cache.has(k)]
-    if not keys:
-        return
-    # keys arrive in need order (pool insertion follows run order, i.e.
-    # phase order) — the compile stream publishes what the dispatcher
-    # will ask for first
-    if _proc_mode():
-        global _PROC
-        import subprocess
-        import sys
-        import tempfile
-
-        # the first two programs gate the first phase, and nothing can
-        # execute until they exist — compile them HERE, synchronously and
-        # at full speed, while the server boots (its jax import alone is
-        # ~3s) and works through the rest of the preset
-        local, remote = keys[:2], keys[2:]
-        if remote:
-            global _WATCHDOG
-            fd, path = tempfile.mkstemp(suffix=".xckeys")
-            with os.fdopen(fd, "wb") as f:
-                import pickle
-
-                pickle.dump(remote, f)
-            # heartbeat file: the worker's beat thread touches it ~1/s
-            # from process start (before its jax import), the watchdog
-            # turns mtime changes into HeartbeatMonitor beats
-            hb_path = path + ".hb"
-            with open(hb_path, "w"):
-                pass
-            env = dict(os.environ, REPRO_XC_HEARTBEAT=hb_path)
-            _PROC = subprocess.Popen(
-                [sys.executable, "-m", "repro.ssd.xc_worker", path],
-                env=env,
-            )
-            _PROC_KEYS.update(remote)
-            _WATCHDOG = _ServerWatchdog(hb_path)
-            obs_spans.instant("compile", "xc_server_launched",
-                              delegated_keys=len(remote))
-        for k in local:
-            S.ensure_compiled(k)
-    else:
-        for k in keys:
-            _INFLIGHT[k] = _compile_pool().submit(S.ensure_compiled, k,
-                                                  None)
-
-
-def _await_server(key: tuple):
-    """Poll-future body: wait for the compile server to publish ``key``,
-    then load it; compile locally (in-process) if the server dies, hangs
-    past the heartbeat deadline, or straggles — the watchdog abandons the
-    server once, and every still-delegated key falls back immediately."""
-    wd = _WATCHDOG
-    if wd is not None:
-        wd.track(key)
-    tr = obs_spans.TRACER
-    t_span = tr.now_us() if tr is not None else 0.0
-    deadline = time.perf_counter() + 600.0
-    try:
-        while (_proc_alive() and not exec_cache.has(key)
-               and time.perf_counter() < deadline):
-            if wd is not None and not wd.healthy():
-                _fail_server(wd.reason or "unhealthy")
-                break
-            time.sleep(0.05)
-    finally:
-        if wd is not None:
-            wd.untrack(key)
-    if not exec_cache.has(key):
-        # the server never published this key — in-process fallback
-        if _PROC is not None and not _proc_alive() and _PROC.returncode != 0:
-            _fail_server("crashed")
-        perf = bench.PERF
-        perf["xc_watchdog_fallbacks"] = (
-            perf.get("xc_watchdog_fallbacks", 0) + 1
-        )
-    if tr is not None:
-        tr.complete("compile", "await_xc_server", t_span,
-                    tr.now_us() - t_span)
-    return S.ensure_compiled(key)
+    """Start compiling every missing executable of ``keys`` on the
+    background thread pool.  Keys arrive in need order (pool insertion
+    follows run order, i.e. phase order), so the pool builds first what
+    the dispatcher will ask for first."""
+    for k in keys:
+        if k not in S._EXEC_CACHE and k not in _INFLIGHT:
+            _INFLIGHT[k] = _compile_pool().submit(S.ensure_compiled, k)
 
 
 def _compile_pool():
@@ -708,7 +502,7 @@ def _plan_pool_cpu(sig: tuple, lanes: list, has_scout: bool) -> list:
         if G < n_shards:
             # opportunistic width padding: a pool smaller than the device
             # count compiles at its own size UNLESS the full-width
-            # executable already exists (memory or store) — duplicate
+            # executable already exists in memory — duplicate
             # lanes run on otherwise-idle shards, so reusing the wide
             # program is free and saves the narrow compile
             p = plans[-1]
@@ -716,7 +510,7 @@ def _plan_pool_cpu(sig: tuple, lanes: list, has_scout: bool) -> list:
                 p, lanes=_pad_block(p.lanes, n_shards),
                 n_shards=n_shards,
             ).finalize()
-            if wide.key in S._EXEC_CACHE or exec_cache.has(wide.key):
+            if wide.key in S._EXEC_CACHE:
                 plans[-1] = wide
     return [p.finalize() for p in plans]
 
@@ -830,13 +624,12 @@ def _dispatch(plan: _GroupPlan) -> dict:
 def _execute_plans(plans: list) -> list:
     """The overlapped compile/execute pipeline.
 
-    Missing executables are resolved on the background pool — persistent-
-    store loads and XLA backend compiles both release the GIL — while the
-    main thread dispatches groups whose executables are ready.  The
-    GIL-bound half of a compile (tracing + lowering) would fight the
-    dispatching main thread for the interpreter, so it happens HERE, on
-    the main thread, before the dispatch loop (``sim.lower_for_key``);
-    keys the store already holds skip it entirely.  Orders are the cost
+    Missing executables are compiled on the background pool — XLA backend
+    compiles release the GIL — while the main thread dispatches groups
+    whose executables are ready.  The GIL-bound half of a compile
+    (tracing + lowering) would fight the dispatching main thread for the
+    interpreter, so it happens HERE, on the main thread, before the
+    dispatch loop (``sim.lower_for_key``).  Orders are the cost
     model's: lowering/compile submission longest-compile-first (the
     cold-path critical path), dispatch longest-estimated-execute first
     (warm-path order: big groups keep the devices busy while stragglers'
@@ -848,18 +641,15 @@ def _execute_plans(plans: list) -> list:
     c0 = perf.get("compile_s", 0.0)
     futures = {}
     for p in sorted(plans, key=lambda p: -p.est_compile):
-        if p.key in futures or p.key in S._EXEC_CACHE:
+        if p.key in S._EXEC_CACHE:
+            _INFLIGHT.pop(p.key, None)  # a precompile that has finished
+            continue
+        if p.key in futures:
             continue
         fut = _INFLIGHT.get(p.key)
         if fut is None:
-            if p.key in _PROC_KEYS and _proc_alive():
-                # delegated to the compile server: poll for its entry
-                fut = _compile_pool().submit(_await_server, p.key)
-            else:
-                lowered = (None if exec_cache.has(p.key)
-                           else S.lower_for_key(p.key))
-                fut = _compile_pool().submit(S.ensure_compiled, p.key,
-                                             lowered)
+            fut = _compile_pool().submit(S.ensure_compiled, p.key,
+                                         S.lower_for_key(p.key))
             _INFLIGHT[p.key] = fut
         futures[p.key] = fut
     pending = sorted(plans, key=lambda p: -p.est_exec)
@@ -895,8 +685,6 @@ def _execute_plans(plans: list) -> list:
             g["cache"] = src
             if src == "build":
                 g["compile_s"] = round(dt, 3)
-            elif src == "disk":
-                g["load_s"] = round(dt, 3)
             rec[1] = "claimed"
         perf_groups.append(g)
     # attribute the pipeline: compile wall-clock that accrued during this
@@ -992,7 +780,7 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
     if all_groups:  # devices actually used, not merely available
         perf["devices_used"] = max(perf.get("devices_used", 0),
                                    max(g["shards"] for g in all_groups))
-    # compile_s / xc_load_s accumulate inside ``sim.ensure_compiled`` (a
+    # compile_s accumulates inside ``sim.ensure_compiled`` (a
     # background compile counts even if it finishes before any group
     # adopts its future); groups carry per-group attribution only
     for g in all_groups:
@@ -1004,9 +792,6 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
         for k in ("dfs_steps_live", "dfs_steps_padded"):
             perf[k] = perf.get(k, 0) + g.get(k, 0)
     perf.setdefault("groups", []).extend(all_groups)
-    # mirror the persistent-store telemetry (absolute, process-wide)
-    for k, v in exec_cache.STATS.items():
-        perf[f"xc_{k}"] = v
     with bench.stage("unpack_s", "unpack"):
         return _merge_runs(runs, prepared, pools)
 
@@ -1152,18 +937,14 @@ def prefetch(requests: Sequence[RunRequest]) -> None:
 def precompile(requests: Sequence[RunRequest],
                extra_keys: Sequence[tuple] = ()) -> None:
     """Plan a request batch WITHOUT executing it and start compiling every
-    missing executable — on the out-of-process compile server when the
-    persistent store is configured (in-process background compilation
-    measured a ~2.3x GIL/core-contention tax on small hosts), else on the
-    background thread pool.
+    missing executable on the background thread pool.
 
     The cross-phase half of the overlapped pipeline: ``benchmarks/run.py``
     hands the whole preset over before the first phase runs, so a late
     phase's programs (fig15's fresh geometries, the tail's small-lane
     layouts via ``extra_keys``) compile while early phases execute.  Costs
     one planning pass (decompositions land in the shared LRU the phases
-    reuse); dispatch later adopts in-flight futures / published store
-    entries.  Purely a scheduling hint — a wrong or stale hint only means
+    reuse); dispatch later adopts the in-flight futures.  Purely a scheduling hint — a wrong or stale hint only means
     the compile happens at first use, as without it."""
     pending, seen = [], set()
     for rq in requests:

@@ -9,8 +9,7 @@ default run (see pytest.ini).
 # planner's sharded (shard_map) execution path — the multi-core layout the
 # benchmarks use.  The single-device environment is covered by the
 # subprocess parity test in tests/test_sweep_plan.py.  MUST run before any
-# jax import: jax reads these on first init.  Tests that exercise the AOT
-# executable store opt in with their own REPRO_XC_DIR (tests/test_exec_cache).
+# jax import: jax reads these on first init.
 from repro.xla_env import configure as _configure_xla
 
 _configure_xla(device_count=2)
@@ -24,11 +23,10 @@ from repro.traces.generator import gen_trace, to_pages
 
 @pytest.fixture()
 def no_jax_cache():
-    """JAX's persistent compilation cache off for one test: the AOT
-    executable store (``repro.ssd.exec_cache``) only runs without it, and
-    a compile for a TPU that is not attached cannot be read back.  JAX's
-    in-memory caches are dropped first: an executable an earlier test
-    loaded from the persistent cache would otherwise be handed out again."""
+    """JAX's persistent compilation cache off for one test: a compile for
+    a TPU that is not attached cannot be read back.  JAX's in-memory
+    caches are dropped first: an executable an earlier test loaded from
+    the persistent cache would otherwise be handed out again."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
 

@@ -81,7 +81,7 @@ def test_lane_backend_resolution():
         S.resolve_lane_backend("cuda-graphs")
     # key -> backend attribution used by the PERF counters
     base = ("batched", (2, 2, 2, 2, 64), 1024, 2, (None,), 2)
-    assert S.kernel_backend_of_key(base) == "xla"
+    assert S.kernel_backend_of_key(base + ("xla",)) == "xla"
     assert S.kernel_backend_of_key(base + ("pallas",)) == "pallas-compiled"
     assert (S.kernel_backend_of_key(base + ("pallas-interpret",))
             == "pallas-interpret")

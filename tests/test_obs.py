@@ -86,7 +86,7 @@ class TestRegistry:
         # the historical keys survive the registry conversion — the
         # BENCH_*.json schema reads these directly
         for key in ("ftl_s", "sim_s", "compile_s", "exec_s", "groups",
-                    "xc_hits", "stream_windows", "kernel_backends",
+                    "stream_windows", "kernel_backends",
                     "phase", "accel", "lower_s", "pack_s", "put_s",
                     "fetch_s", "unpack_s", "put_bytes"):
             assert key in bench.PERF, key
