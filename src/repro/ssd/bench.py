@@ -100,8 +100,11 @@ for _c in ("decomp_hits", "decomp_misses", "run_hits", "run_subset_hits",
     METRICS.counter(_c)
 # batched scout groups: the DFS steps of each lane's own walks (every try
 # and raced scout of its valid transactions), and the DFS while-loop's
-# iterations times the lanes stepped in lockstep with them
-for _c in ("dfs_steps_live", "dfs_steps_padded"):
+# iterations times the lanes stepped in lockstep with them; the retry
+# loop's rounds (walk calls) and the scan steps, each per shard, and the
+# tries walked, speculative ones included, of the lanes that are not padding
+for _c in ("dfs_steps_live", "dfs_steps_padded", "scout_walk_calls",
+           "scout_scan_steps", "scout_tries_walked"):
     METRICS.counter(_c)
 METRICS.gauge("devices_used", 0)
 METRICS.timer("compile_s")

@@ -59,8 +59,8 @@ from repro.core.topology import build_mesh
 from repro.obs import spans as obs_spans
 from repro.kernels import onehot
 from repro.kernels.batched_step import lanes_2d, lane_tiled_step
-from repro.kernels.ops import route_dfs
-from repro.kernels.scout_step import pack_tables
+from repro.kernels.ops import BatchRouteOut, route_dfs
+from repro.kernels.scout_step import link_pad, pack_tables, walk_tile
 from repro.ssd.config import SSDConfig, TICK_NS
 from repro.ssd.designs import (
     DESIGNS,
@@ -1277,8 +1277,64 @@ class ScoutBatchTxnTables(NamedTuple):
     dist: jnp.ndarray  # int32 [cap, B, F_pad]
 
 
+# The largest walk tile the batched scout runner fills with retries
+# (PERF.md section 6: the walk kernel's cost per loop iteration by tile)
+_TRIES_TILE = 16
+
+
+def try_slots(B: int, k_max: int, link_w: int, n_nodes: int) -> int:
+    """Tries the batched scout runner walks in one call: as many as one
+    walk tile of at most ``_TRIES_TILE`` scouts, whose blocks fit
+    ``VMEM_BUDGET``, holds at ``k_max`` scouts a try; at least one a lane."""
+    return max(B, walk_tile(_TRIES_TILE, link_w, n_nodes) // k_max)
+
+
+def _try_times(links3, t0):
+    """Each lane's retry schedule, int32 [B, _MAX_TRIES], from its links
+    triple [B, L] alone: try 0 at ``t0``; try i+1 at the next potential
+    link-state change after try i (the next distinct free_at or idle gap
+    start above it, one tick later each once none is left); the last try
+    once every link is free — the flat ``scout_until_success``'s rule."""
+    free, gap_s, _ = links3
+    v = jnp.concatenate([free, gap_s], axis=1)  # [B, V]
+    V = v.shape[1]
+    up = v > t0[:, None]
+    earlier = np.arange(V)[None, :] < np.arange(V)[:, None]
+    dup = jnp.any((v[:, :, None] == v[:, None, :]) & up[:, None, :]
+                  & earlier, axis=2)
+    first = up & ~dup  # one entry per distinct event above t0
+    below = jnp.sum((v[:, None, :] < v[:, :, None]) & first[:, None, :],
+                    axis=2)  # distinct events below each entry
+    n_ev = jnp.sum(first, axis=1)
+    i = np.arange(1, _MAX_TRIES - 1)  # try i waits for the i-th event
+    ev = jnp.min(jnp.where(
+        first[:, None, :] & (below[:, None, :] == i[None, :, None] - 1),
+        v[:, None, :], _BIG), axis=2)
+    ev = jnp.where(i <= n_ev[:, None], ev,
+                   _BIG + i - 1 - n_ev[:, None])
+    return jnp.concatenate(
+        [t0[:, None], ev, jnp.max(free, axis=1)[:, None]], axis=1)
+
+
+def _rng_advances(rng, n: int):
+    """uint32 [B, n + 1]: a lane's scout rng after 0 .. n advances
+    ``x -> (x * 747796405 + 2891336453) | 1``, in one vector op: from the
+    first advance on the state is odd, and on odd states the advance is
+    the affine ``x * a + (c + 1)``, whose powers are constants mod 2^32."""
+    a, c = 747796405, 2891336453
+    first = (rng * jnp.uint32(a) + jnp.uint32(c)) | jnp.uint32(1)
+    mul, add = [1], [0]
+    for _ in range(n - 1):
+        mul.append(mul[-1] * a % 2**32)
+        add.append((add[-1] * a + c + 1) % 2**32)
+    later = (first[:, None] * jnp.asarray(mul, jnp.uint32)
+             + jnp.asarray(add, jnp.uint32))
+    return jnp.concatenate([rng[:, None], later], axis=1)
+
+
 def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
-                             k_max: int, fixed: tuple, backend: str):
+                             k_max: int, fixed: tuple, backend: str,
+                             slots: int | None = None):
     """The scout-routed scan step over a lane batch [B].
 
     Mirrors ``scout_step`` + ``scout_until_success`` in ``_make_step``
@@ -1288,13 +1344,16 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
     DFS is replaced by ``kernels.ops.route_dfs``: the batched ``step_math``
     decision and its stacks, walked in the Pallas walk kernel or as XLA
     ops — the same Algorithm-1 decision procedure, pinned equivalent.
-    Returns ``(state', (StepOut, dfs))``: ``dfs`` is, per lane, the DFS
-    loop's iterations in its lane tile in this step (every try and raced
-    scout) and the steps its own walks took.
+    ``slots`` fixes the tries walked per call, at least one a lane
+    (tests); None takes :func:`try_slots` of the batch.  Returns ``(state', (StepOut,
+    dfs))``: ``dfs`` is, per lane, this step's DFS loop iterations in its
+    walk tiles, the steps its own walks took, the tries it walked and the
+    retry loop's rounds (see ``scout_until_success_b``).
     """
     L0 = lay.L_pad
     n_fcs = lay.rows
     n_nodes = lay.n_nodes
+    width = link_pad(L0)
     fixed = dict(zip(_PROMOTABLE, fixed))
     tables_dev = jnp.asarray(pack_tables(topo))
     cols = topo.cols
@@ -1331,78 +1390,122 @@ def _make_batched_scout_step(lay, topo, scout_hop_ns: int, n_planes: int,
 
     def scout_until_success_b(links3, sp, src, dst, t0, rng, d_hold, valid):
         """Batched ``scout_until_success``: every lane follows its own
-        retry schedule (its links triple is lane-local), frozen lanes'
-        (res, t, rng, tries) ride through the joint while_loop untouched —
-        per-lane bit-identity with the flat loop.  Also returns the DFS
-        counts of every try and raced scout, per lane: the DFS loop's
-        iterations in the lane's tile, and the lane's own walks' steps."""
+        retry schedule (its links triple is lane-local), bit-identical to
+        the flat loop.  The schedule does not depend on the walks
+        (:func:`_try_times`; ``links3`` is committed only after the loop),
+        so each round of the loop walks the next R tries of each of its L
+        live lanes side by side, in one ``route_dfs`` call of S x k_max
+        scouts — S try slots (:func:`try_slots`, or ``slots``), R = S // L
+        — and keeps each lane's first success, or its last try, exactly as
+        the sequential loop leaves them; every round walks the same tile,
+        so fewer live lanes walk more tries each.  Unfilled slots walk
+        src==dst==0 dummies; the first round always runs.  Also returns,
+        per lane: the DFS loop's iterations of every call (each of its
+        walk tiles once), the steps of the walks the sequential schedule
+        takes (every raced scout of every try up to the first success),
+        the tries walked, speculative ones included, and the rounds."""
         n_scouts = fx(sp, "n_scouts")
         dead_links = sp.res_dead[:, :L0]
         B = src.shape[0]
         allow = fx(sp, "allow_nonmin")  # promoted-static or per-lane
+        S = max(B, slots or try_slots(B, k_max, width, n_nodes))
+        b_tile = (walk_tile(S * k_max, width, n_nodes) if backend != "xla"
+                  else S * k_max)
+        tile_first = np.arange(S * k_max) % b_tile == 0
+        s_iota = np.arange(S)
+        # scouts raced per try, and the rng after every advance of the
+        # step's tries (a try advances each raced scout's rng once)
+        n_act = jnp.broadcast_to(
+            jnp.minimum(jnp.asarray(n_scouts, jnp.int32), k_max), (B,))
+        rngs = _rng_advances(rng, _MAX_TRIES * k_max)
+        sched = _try_times(links3, t0)  # [B, _MAX_TRIES]
 
-        def route(busy, rngs, act):
-            # one DFS walk per lane, in the walk kernel (Pallas backends)
-            # or as XLA ops; non-participating lanes route a src==dst==0
-            # dummy scout (finishes in one step), never merged
-            return route_dfs(tables_dev, jnp.where(act, src, 0),
-                             jnp.where(act, dst, 0), busy, rngs, allow,
-                             cols=cols, n_nodes=n_nodes,
-                             use_pallas=backend != "xla",
-                             interpret=backend != "pallas")
+        def gather(sel, x):  # x[lane of slot] for each slot: [S, ...]
+            m = sel.reshape(sel.shape + (1,) * (x.ndim - 1))
+            if x.dtype == jnp.bool_:
+                return jnp.any(m & x[None], axis=1)
+            return jnp.sum(jnp.where(m, x[None], 0), axis=1).astype(x.dtype)
 
-        def try_once(t, rng, act):
-            busy = _busy_at(links3, t[:, None], d_hold[:, None]) | dead_links
+        def round_(carry):
+            res, tries_, (trips, own, walked), rounds = carry
+            live = valid & (~res.success) & (tries_ < _MAX_TRIES)
+            n_live = jnp.sum(live.astype(jnp.int32))
+            j = jnp.max(jnp.where(live, tries_, 0))  # the same on live lanes
+            R = jnp.minimum(S // jnp.maximum(n_live, 1), _MAX_TRIES - j)
+            # slot s holds try j + s % R of the (s // R)-th live lane
+            pos = jnp.cumsum(live.astype(jnp.int32)) - 1
+            sel = live[None, :] & (pos[None, :] == (s_iota // R)[:, None])
+            filled = jnp.any(sel, axis=1)
+            idx = jnp.minimum(j + s_iota % R, _MAX_TRIES - 1)
+            t_s = onehot.take(gather(sel, sched), idx)
+            busy = _busy_at(tuple(gather(sel, a) for a in links3),
+                            t_s[:, None], gather(sel, d_hold)[:, None])
+            busy = busy | gather(sel, dead_links)
+            na_s = gather(sel, n_act)
+            rng_s = gather(sel, rngs)
+            act = [filled & (k < na_s) for k in range(k_max)]
+            seeds = [onehot.take(rng_s, idx * na_s + k + 1)
+                     for k in range(k_max)]
+            rows = lambda x: jnp.stack(x, axis=1).reshape(
+                (S * k_max,) + x[0].shape[1:])
+            src_s, dst_s = gather(sel, src), gather(sel, dst)
+            out = route_dfs(
+                tables_dev, rows([jnp.where(a, src_s, 0) for a in act]),
+                rows([jnp.where(a, dst_s, 0) for a in act]),
+                jnp.repeat(busy, k_max, axis=0), rows(seeds),
+                allow if isinstance(allow, (bool, np.bool_))
+                else jnp.repeat(gather(sel, jnp.asarray(allow)), k_max),
+                cols=cols, n_nodes=n_nodes, use_pallas=backend != "xla",
+                interpret=backend != "pallas")
+            # every lane waits on every walk tile of the call
+            trips = trips + jnp.sum(jnp.where(tile_first, out.tile_steps, 0))
+            out = out._replace(path_mask=out.path_mask[:, :L0])
+            out = jax.tree_util.tree_map(
+                lambda x: x.reshape((S, k_max) + x.shape[1:]), out)
+            # each try raced its scouts: the fewest-hop success won
             best = None
-            trips = own = jnp.zeros((B,), jnp.int32)
+            steps = jnp.zeros((S,), jnp.int32)
             for k in range(k_max):
-                rng_adv = (
-                    rng * jnp.uint32(747796405) + jnp.uint32(2891336453)
-                ) | jnp.uint32(1)
-                active = jnp.asarray(k < n_scouts)  # bool or traced [B]
-                rng = jnp.where(jnp.logical_and(act, active), rng_adv, rng)
-                res = route(busy, rng, act)
-                # the walk's loop ran its tile's longest walk, on every
-                # lane of the tile
-                trips = trips + res.tile_steps
-                own = own + jnp.where(act & active, res.steps, 0)
-                res = res._replace(path_mask=res.path_mask[:, :L0])
+                rk = jax.tree_util.tree_map(lambda x: x[:, k], out)
+                steps = steps + jnp.where(act[k], rk.steps, 0)
                 if best is None:
-                    best = res
+                    best = rk
                 else:
-                    take = res.success & active & (
-                        (~best.success) | (res.hops < best.hops)
-                    )
-                    best = _merge_b(take, res, best)
-            return best, rng, (trips, own)
-
-        res0, rng, dfs0 = try_once(t0, rng, valid)
+                    win = rk.success & act[k] & (
+                        (~best.success) | (rk.hops < best.hops))
+                    best = _merge_b(win, rk, best)
+            # each live lane keeps its first success, else its last try:
+            # the tries the sequential loop would have taken
+            mine = sel.T  # [B, S]
+            first = jnp.min(jnp.where(mine & best.success, s_iota, S), axis=1)
+            last = jnp.max(jnp.where(mine, s_iota, -1), axis=1)
+            kept = jnp.where(first < S, first, last)
+            pick = onehot.onehot(kept, S)
+            res = _merge_b(live, jax.tree_util.tree_map(
+                lambda x: gather(pick, x), best), res)
+            taken = mine & (s_iota <= kept[:, None])
+            own = own + jnp.sum(jnp.where(taken, steps, 0), axis=1)
+            tries_ = jnp.where(live, j + kept - pos * R + 1, tries_)
+            walked = walked + jnp.where(live, R, 0)
+            return res, tries_, (trips, own, walked), rounds + 1
 
         def cond(carry):
-            res, t, rng, tries, _ = carry
-            return jnp.any(valid & (~res.success) & (tries < _MAX_TRIES))
+            res, tries_, _, rounds = carry
+            return (rounds == 0) | jnp.any(
+                valid & (~res.success) & (tries_ < _MAX_TRIES))
 
-        def body(carry):
-            res, t, rng, tries, dfs = carry
-            live = valid & (~res.success) & (tries < _MAX_TRIES)
-            free, gap_s, _ = links3
-            ev = jnp.minimum(
-                jnp.min(jnp.where(free > t[:, None], free, _BIG), axis=1),
-                jnp.min(jnp.where(gap_s > t[:, None], gap_s, _BIG), axis=1),
-            )
-            t_next = jnp.maximum(ev, t + 1)
-            t_next = jnp.where(tries + 1 >= _MAX_TRIES,
-                               jnp.max(free, axis=1), t_next)
-            t_next = jnp.where(live, t_next, t)
-            res2, rng2, dfs2 = try_once(t_next, rng, live)
-            res = _merge_b(live, res2, res)
-            rng = jnp.where(live, rng2, rng)
-            return (res, t_next, rng, tries + live.astype(jnp.int32),
-                    (dfs[0] + dfs2[0], dfs[1] + dfs2[1]))
-
-        return jax.lax.while_loop(
-            cond, body, (res0, t0, rng, jnp.ones((B,), jnp.int32), dfs0)
-        )
+        zero = jnp.zeros((B,), jnp.int32)
+        res0 = BatchRouteOut(
+            success=jnp.zeros((B,), bool),
+            path_mask=jnp.zeros((B, L0), bool),
+            hops=zero, steps=zero, misroutes=zero, tile_steps=zero)
+        res, tries_, dfs, rounds = jax.lax.while_loop(
+            cond, round_, (res0, zero, (zero,) * 3, jnp.int32(0)))
+        # the last try's time and the rng after it (none: t0, rng as given)
+        t = jnp.where(tries_ > 0,
+                      onehot.take(sched, jnp.maximum(tries_ - 1, 0)), t0)
+        rng = onehot.take(rngs, tries_ * n_act)
+        return res, t, rng, tries_, dfs + (jnp.broadcast_to(rounds, (B,)),)
 
     def step(sp: ScoutBatchScalars, state, xs):
         tx, tt = xs
@@ -1493,8 +1596,9 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
     :func:`_make_batched_run` (seeds ride as an argument; the scan state
     mirrors the flat scout ``init_state`` with a leading lane axis).
     Returns the time-major ``StepOut`` and the run's DFS counts, int32
-    [B, 2]: the DFS loop's iterations in each lane's tile (the same on
-    every lane of a tile) and the iterations of each lane's own walks."""
+    [B, 4]: the DFS loop's iterations in each lane's walk tiles, the
+    iterations of each lane's own walks, the tries it walked and the
+    retry loop's rounds (the same on every lane of a shard)."""
 
     def batch_run(scal, seeds, txns: TxnArrays, tt: ScoutBatchTxnTables,
                   n_chunks):
@@ -1510,9 +1614,9 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
         )
 
         def scan_step(carry, x):
-            st, (trips, own) = carry
-            st, (out, (n, o)) = step(scal, st, x)
-            return (st, (trips + n, own + o)), out
+            st, dfs = carry
+            st, (out, d) = step(scal, st, x)
+            return (st, tuple(a + b for a, b in zip(dfs, d))), out
 
         def chunk_body(c, carry):
             st, dfs, buf = carry
@@ -1527,13 +1631,13 @@ def _make_batched_scout_run(step, capacity: int, n_planes: int, L0: int,
             )
             return st, dfs, buf
 
-        dfs0 = (jnp.zeros((B,), jnp.int32),) * 2
-        _, (trips, own), buf = jax.lax.fori_loop(
+        dfs0 = (jnp.zeros((B,), jnp.int32),) * 4
+        _, dfs, buf = jax.lax.fori_loop(
             0, jnp.max(n_chunks), chunk_body,
             (state, dfs0, _zero_out_tm(capacity, B)),
         )
-        # StepOut, time-major [capacity, B]; the DFS counts [B, 2]
-        return buf, jnp.stack([trips, own], axis=1)
+        # StepOut, time-major [capacity, B]; the DFS counts [B, 4]
+        return buf, jnp.stack(dfs, axis=1)
 
     return batch_run
 
@@ -2047,16 +2151,18 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
                             n_shards: int, per_shard: int,
                             backend: str = "xla",
                             t_pack: float | None = None) -> tuple:
-    """Execute one batched scout group; returns (StepOut [cap, B], the
-    DFS steps of each lane's own walks [B], perf).
+    """Execute one batched scout group; returns (StepOut [cap, B], per
+    lane the DFS steps of its own walks and the tries it walked [B, 2],
+    perf).
 
     Same layout contract as :func:`run_batched_group` plus the per-lane
     rng ``seeds`` [B] (the scout state's fifth leg) and ``k_max`` (the
     group's raced-scout ceiling — lanes below it are masked per their
     ``n_scouts``).  Every backend is bit-exact.  ``t_pack``: see
-    :func:`_run_compiled`.  ``perf["dfs_steps_padded"]``: the DFS loop's
-    iterations in each lane tile times the lanes stepped in lockstep with
-    them.
+    :func:`_run_compiled`.  ``perf``: ``dfs_steps_padded``, the DFS loop's
+    iterations in each lane's walk tiles summed over lanes;
+    ``scout_walk_calls``, the retry loop's rounds, and
+    ``scout_scan_steps``, the scan steps, each summed over shards.
     """
     B = int(np.asarray(n_chunks).shape[0])
     capacity = int(np.asarray(txns.arrival).shape[0])
@@ -2076,7 +2182,11 @@ def run_batched_scout_group(sig: tuple, scal: ScoutBatchScalars, seeds,
     )
     dfs = np.asarray(perf.pop("aux"))
     perf["dfs_steps_padded"] = int(np.sum(dfs[:, 0], dtype=np.int64))
-    return outs, dfs[:, 1], perf
+    starts = range(0, B, per_shard)
+    perf["scout_walk_calls"] = int(sum(int(dfs[s, 3]) for s in starts))
+    perf["scout_scan_steps"] = CHUNK * int(
+        sum(int(ncs[s:s + per_shard].max(initial=0)) for s in starts))
+    return outs, dfs[:, 1:3], perf
 
 
 class SimResult(NamedTuple):

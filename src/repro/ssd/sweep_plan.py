@@ -523,7 +523,7 @@ def _dispatch(plan: _GroupPlan) -> dict:
     return."""
     t_pack = time.perf_counter()
     lanes, cap = plan.lanes, plan.cap
-    dfs_own = None  # a scout group's DFS steps of each lane's own walks
+    dfs_own = None  # a scout group's per-lane own DFS steps and tries walked
     if plan.variant in ("lane", "stack"):
         tables = LaneTables(
             *(np.stack([np.asarray(getattr(ln.tables_row, f))
@@ -606,17 +606,20 @@ def _dispatch(plan: _GroupPlan) -> dict:
     with bench.stage("unpack_s", "unpack"):
         fields = [np.moveaxis(np.asarray(a), lane_axis, 0) for a in outs]
         seen = set()
-        live = 0
+        live = walked = 0
         for j, ln in enumerate(lanes):
             if id(ln) in seen:  # padding duplicate — outputs discarded
                 continue
             seen.add(id(ln))
             ln.out = S.StepOut(*(f[j] for f in fields))
             if dfs_own is not None:
-                live += int(dfs_own[j])
+                live += int(dfs_own[j, 0])
+                walked += int(dfs_own[j, 1])
         if dfs_own is not None:
-            # DFS steps of the lanes' own walks, padding lanes left out
+            # DFS steps of the lanes' own walks and the tries they walked,
+            # padding lanes left out
             perf["dfs_steps_live"] = live
+            perf["scout_tries_walked"] = walked
     perf["lanes"] = len(seen)
     return perf
 
@@ -789,7 +792,8 @@ def execute_sim_runs(runs: Sequence[tuple]) -> list:
             perf.get("scan_steps_padded", 0) + g["steps"]
         )
         perf["exec_s"] = perf.get("exec_s", 0.0) + g["exec_s"]
-        for k in ("dfs_steps_live", "dfs_steps_padded"):
+        for k in ("dfs_steps_live", "dfs_steps_padded", "scout_walk_calls",
+                  "scout_tries_walked", "scout_scan_steps"):
             perf[k] = perf.get(k, 0) + g.get(k, 0)
     perf.setdefault("groups", []).extend(all_groups)
     with bench.stage("unpack_s", "unpack"):
